@@ -189,7 +189,9 @@ def main(argv=None):
     import jax
 
     from mx_rcnn_tpu.parallel import make_mesh
+    from mx_rcnn_tpu.utils.compile_cache import configure_cache
 
+    configure_cache()
     mesh = (
         make_mesh(model_parallel=cfg.train.spatial_partition)
         if jax.device_count() > 1

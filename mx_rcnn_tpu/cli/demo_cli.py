@@ -112,7 +112,9 @@ def main(argv=None):
     import jax
 
     from mx_rcnn_tpu.parallel.step import eval_variables
+    from mx_rcnn_tpu.utils.compile_cache import configure_cache
 
+    configure_cache()
     if args.random_params:
         from mx_rcnn_tpu.detection import TwoStageDetector, init_detector
 

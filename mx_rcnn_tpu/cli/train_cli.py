@@ -59,8 +59,10 @@ def main(argv=None) -> dict:
 
     from mx_rcnn_tpu.parallel import initialize, make_mesh
     from mx_rcnn_tpu.train.loop import train
+    from mx_rcnn_tpu.utils.compile_cache import configure_cache
 
     initialize()  # multi-host runtime (no-op single-process)
+    configure_cache()  # after initialize(): this starts the backend
     mesh = (
         make_mesh(model_parallel=cfg.train.spatial_partition)
         if jax.device_count() > 1

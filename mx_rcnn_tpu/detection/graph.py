@@ -263,11 +263,10 @@ def _propose_one(cfg: ModelConfig, train: bool):
     backend: the fused Pallas kernel (ops/pallas/middle.py — decode ->
     clip -> snap -> NMS VMEM-resident, bit-identical to the dense chain),
     the pallas keep-mask sweep under the dense decode, or the all-XLA
-    oracle.  Same fallback discipline as ``_pool_rois_impl``: pallas
-    backends need a TPU or MX_RCNN_PALLAS_INTERPRET=1; anything else
-    quietly drops to the XLA path (the knobs are default-off, so a
-    fallback can only happen when explicitly requested — warn on TPU,
-    debug-log off it).
+    oracle.  On a TPU a Pallas backend that was asked for is what runs —
+    a kernel Mosaic refuses fails the compile, nothing stands in for it.
+    Off-TPU the Pallas backends need MX_RCNN_PALLAS_INTERPRET=1; without
+    it the XLA chain runs (the design for CPU tests; debug-logged).
     """
     global LAST_MIDDLE_IMPL
     rpn_cfg = cfg.rpn
@@ -280,15 +279,14 @@ def _propose_one(cfg: ModelConfig, train: bool):
         )
     interpret = _pallas_interpret()
     can_pallas = jax.default_backend() == "tpu" or interpret
-    want_pallas = rpn_cfg.fused_middle or rpn_cfg.nms_impl == "pallas"
-    if want_pallas and not can_pallas:
+    if not can_pallas and (
+        rpn_cfg.fused_middle or rpn_cfg.nms_impl == "pallas"
+    ):
         import logging
 
-        lg = logging.getLogger("mx_rcnn_tpu")
-        (lg.warning if jax.default_backend() == "tpu" else lg.debug)(
-            "rpn fused_middle/nms_impl='pallas' unavailable (backend=%s) "
-            "— using the XLA detection middle",
-            jax.default_backend(),
+        logging.getLogger("mx_rcnn_tpu").debug(
+            "rpn fused_middle/nms_impl='pallas' off-TPU without "
+            "MX_RCNN_PALLAS_INTERPRET=1 — using the XLA detection middle"
         )
     fused = rpn_cfg.fused_middle and can_pallas
     nms_impl = rpn_cfg.nms_impl if can_pallas else "xla"
@@ -379,13 +377,17 @@ def _pool_rois_impl(cfg: ModelConfig, feats, rois, pooled_size: int,
     """ROIAlign over the batch. rois: (B, R, 4) -> (B, R, S, S, C).
 
     ``cfg.rcnn.roi_align_impl`` picks the backend: "pallas" (default — ONE
-    batch-folded kernel launch per step; measured 83.1 -> 77.6 ms on the
-    full R50-FPN train step, 219.5 -> 118.8 ms on the batch-8 eval step)
-    or "xla" (flattened-pyramid gather — the oracle and the automatic
-    fallback off-TPU, on single-level C4 pyramids, and on unsupported
-    layouts).  Since r3 the pallas path's backward is a Pallas window-RMW
-    kernel too (ops/pallas/roi_align.py::_bwd_kernel; MX_RCNN_POOL_BWD=xla
-    restores the autodiff-of-XLA backward).
+    batch-folded kernel launch per step) or "xla" (flattened-pyramid
+    gather — the oracle).  "pallas" still takes the XLA gather in two
+    cases, both decided from what the code can see and neither a failure:
+    off-TPU without MX_RCNN_PALLAS_INTERPRET=1 (the design for CPU tests),
+    and on a single-level C4 pyramid (the kernel's window bounds roi
+    extent through FPN level reassignment, which one level cannot do).
+    On a TPU, a multi-level pyramid whose layout the kernel cannot slice
+    (:func:`pallas_supported`) RAISES: a TPU path was asked for, and
+    nothing quietly stands in for it.  The pallas path's backward is a
+    Pallas window-RMW kernel too (ops/pallas/roi_align.py::_bwd_kernel;
+    MX_RCNN_POOL_BWD=xla restores the autodiff-of-XLA backward).
 
     ``mesh``: a >1-data-axis mesh wraps the kernel in ``shard_map`` so each
     chip pools its own images (the kernel's per-shard contract) instead of
@@ -404,29 +406,20 @@ def _pool_rois_impl(cfg: ModelConfig, feats, rois, pooled_size: int,
             f"got {cfg.rcnn.roi_align_bwd_impl!r}"
         )
     levels = sorted(feats)
-    want_pallas = cfg.rcnn.roi_align_impl == "pallas"
-    roi_levels = {l: f for l, f in feats.items() if l in roi_level_set}
-    interpret = _pallas_interpret()
-    can_pallas = (
-        len(levels) > 1
-        and (jax.default_backend() == "tpu" or interpret)
-        and pallas_supported(roi_levels)
-    )
-    if want_pallas and not can_pallas:
-        import logging
+    if len(levels) == 1:
+        lvl = levels[0]
+        LAST_POOL_IMPL = "xla"
+        return jax.vmap(
+            lambda f, r: roi_align(
+                f, r, pooled_size, 1.0 / (2**lvl), cfg.rcnn.sampling_ratio
+            )
+        )(feats[lvl], rois)
 
-        # Expected fallbacks (off-TPU; single-level C4 pyramid) are quiet —
-        # pallas is the config default.  A genuinely unsupported LAYOUT on
-        # a multi-level TPU pyramid is worth a warning.
-        lg = logging.getLogger("mx_rcnn_tpu")
-        unexpected = jax.default_backend() == "tpu" and len(levels) > 1
-        (lg.warning if unexpected else lg.debug)(
-            "roi_align_impl='pallas' unavailable "
-            "(levels=%d, backend=%s) — using the XLA path",
-            len(levels), jax.default_backend(),
-        )
-    if len(levels) > 1:
-        if want_pallas and can_pallas:
+    roi_levels = {l: f for l, f in feats.items() if l in roi_level_set}
+    on_tpu = jax.default_backend() == "tpu"
+    interpret = _pallas_interpret()
+    if cfg.rcnn.roi_align_impl == "pallas" and (on_tpu or interpret):
+        if pallas_supported(roi_levels):
             from mx_rcnn_tpu.parallel.mesh import DATA_AXIS
 
             if mesh is not None and mesh.shape.get(DATA_AXIS, 1) > 1:
@@ -443,19 +436,21 @@ def _pool_rois_impl(cfg: ModelConfig, feats, rois, pooled_size: int,
                 roi_levels, rois, pooled_size, cfg.rcnn.sampling_ratio,
                 POOL_WINDOW, interpret, cfg.rcnn.roi_align_bwd_impl,
             )
-        LAST_POOL_IMPL = "xla"
-        return jax.vmap(
-            lambda fs, r: multilevel_roi_align(
-                fs, r, output_size=pooled_size, sampling_ratio=cfg.rcnn.sampling_ratio
+        if on_tpu:
+            raise ValueError(
+                "rcnn.roi_align_impl='pallas' on a TPU, but the kernel "
+                "cannot slice this pyramid (levels "
+                f"{ {l: tuple(f.shape) for l, f in sorted(roi_levels.items())} }: "
+                "it needs more than one level and channels in multiples "
+                "of 128).  Set rcnn.roi_align_impl='xla' to take the XLA "
+                "gather by name — it is not chosen silently"
             )
-        )(roi_levels, rois)
-    lvl = levels[0]
     LAST_POOL_IMPL = "xla"
     return jax.vmap(
-        lambda f, r: roi_align(
-            f, r, pooled_size, 1.0 / (2**lvl), cfg.rcnn.sampling_ratio
+        lambda fs, r: multilevel_roi_align(
+            fs, r, output_size=pooled_size, sampling_ratio=cfg.rcnn.sampling_ratio
         )
-    )(feats[lvl], rois)
+    )(roi_levels, rois)
 
 
 # ---------------------------------------------------------------------------

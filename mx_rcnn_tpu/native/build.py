@@ -2,17 +2,30 @@
 
 Usage: ``python -m mx_rcnn_tpu.native.build``; the test suite and package
 import both tolerate an un-built tree (numpy fallbacks take over).
+
+The library is NAMED by the hash of its source (``_native.<hash>.so``), so
+the one that gets loaded is provably the build of the ``src/native.cc``
+next to it: a library left behind by another checkout state (the file is
+git-ignored, and a tool that copies the tree copies it too) has another
+name, is never opened, and is removed by the next build.
 """
 
 from __future__ import annotations
 
+import hashlib
 import os
 import subprocess
 import sys
 
 PKG_DIR = os.path.dirname(os.path.abspath(__file__))
 SRC = os.path.join(PKG_DIR, "src", "native.cc")
-OUT = os.path.join(PKG_DIR, "_native.so")
+
+
+def so_path() -> str:
+    """Where the build of the CURRENT source lives (it may not exist)."""
+    with open(SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:12]
+    return os.path.join(PKG_DIR, f"_native.{digest}.so")
 
 
 def build(verbose: bool = True) -> str:
@@ -22,7 +35,8 @@ def build(verbose: bool = True) -> str:
     # Compile to a temp path + atomic rename so concurrent builders
     # (multi-process loaders, parallel test workers) never dlopen a
     # half-written file.
-    tmp = f"{OUT}.tmp.{os.getpid()}"
+    out = so_path()
+    tmp = f"{out}.tmp.{os.getpid()}"
     cmd = [
         "g++", "-O3", "-shared", "-fPIC", "-std=c++17", SRC, "-o", tmp,
     ]
@@ -30,11 +44,19 @@ def build(verbose: bool = True) -> str:
         print(" ".join(cmd), file=sys.stderr)
     try:
         subprocess.run(cmd, check=True)
-        os.replace(tmp, OUT)
+        os.replace(tmp, out)
     finally:
         if os.path.exists(tmp):
             os.unlink(tmp)
-    return OUT
+    # Builds of other sources are dead weight that travels with the tree.
+    for name in os.listdir(PKG_DIR):
+        path = os.path.join(PKG_DIR, name)
+        if name.startswith("_native.") and name.endswith(".so") and path != out:
+            try:
+                os.unlink(path)
+            except OSError:
+                pass
+    return out
 
 
 if __name__ == "__main__":

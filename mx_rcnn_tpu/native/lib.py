@@ -10,31 +10,35 @@ from __future__ import annotations
 
 import ctypes
 import os
+import subprocess
 from typing import Optional
 
 import numpy as np
 
 _LIB = None  # None = not attempted; False = failed (don't retry); CDLL = loaded
-_SO = os.path.join(os.path.dirname(os.path.abspath(__file__)), "_native.so")
 
 
 def _load() -> Optional[ctypes.CDLL]:
     global _LIB
     if _LIB is not None:
         return _LIB or None  # False (cached failure) -> None
-    if not os.path.exists(_SO):
+    from mx_rcnn_tpu.native.build import build, so_path
+
+    # The library is named by its source's hash (native/build.py): only the
+    # build of the current native.cc is ever opened; anything else lying in
+    # the package directory is stale and gets rebuilt, not loaded.
+    so = so_path()
+    if not os.path.exists(so):
         # Build lazily when a toolchain is present (dev/CI convenience).
         try:
-            from mx_rcnn_tpu.native.build import build
-
             build(verbose=False)
-        except Exception:
+        except (OSError, subprocess.CalledProcessError):
             # Cache the failure: these entry points sit on the per-image
             # loader hot path — one g++ attempt per process, not per call.
             _LIB = False
             return None
     try:
-        lib = ctypes.CDLL(_SO)
+        lib = ctypes.CDLL(so)
     except OSError:
         _LIB = False
         return None

@@ -539,10 +539,10 @@ def _bwd_kernel(
     # strictly tighter than the bf16-ACCUMULATING XLA scatter-add this
     # kernel replaced (hundreds of bf16 += per P2 cell).  On-chip check
     # (the off-TPU interpret tests can't see MXU truncation): max
-    # |pallas - xla-autodiff| feature-grad diff at R101 train shapes is
+    # |pallas - xla-autodiff| feature-grad diff at train shapes is
     # within bf16 output granularity — gated by the opt-in
-    # RUN_POOL_BWD_TPU=1 test (tests/test_pool_bwd_tpu.py; r5 recorded
-    # worst_rel 0.0092 ~ 2.4 ulps).  Measured 10.7 -> 6.1 ms at R101
+    # RUN_KERNELS_TPU=1 probe (tests/test_kernels_tpu.py; r5 recorded
+    # worst_rel 0.0092 ~ 2.4 roundings).  Measured 10.7 -> 6.1 ms at R101
     # train shapes vs HIGHEST.  f32 cotangents (CPU-recipe tests, golden
     # paths) keep the exact HIGHEST dot.  The FORWARD stays HIGHEST always:
     # weight truncation there shifts where features are SAMPLED (a
@@ -794,8 +794,17 @@ def sharded_multilevel_roi_align(
     batch indices are computed from local shapes — so the wrap needs no
     collectives; it only stops GSPMD from replicating the opaque kernel
     call (gathering every image's pyramid to every chip), which is what a
-    bare pallas_call under a sharded jit would get.  Axes other than
-    ``data_axis`` stay under GSPMD (partial-manual shard_map).
+    bare pallas_call under a sharded jit would get.
+
+    Manual over EVERY mesh axis, with specs that name only ``data_axis``:
+    jax refuses to lower a Mosaic kernel under a shard_map that leaves any
+    axis to GSPMD ("Mosaic kernels cannot be automatically partitioned"),
+    even an axis of size 1 — which a partial-manual wrap over the
+    (data, model) mesh did until PR 21, unseen because interpret mode has
+    no Mosaic call to refuse (tests/test_pallas.py now lowers this wrap
+    for the TPU platform from the CPU).  Callers only take this path on a
+    pure data-parallel mesh (spatial partitioning keeps the XLA form), so
+    the other axes have size 1 and replicate nothing.
     ``check_vma=False``: the pallas out_shape carries no varying-mesh-axes
     annotation.  The custom_vjp rides inside, so the backward (the Pallas
     window-RMW kernel by default since r3; autodiff-of-XLA under
@@ -809,27 +818,11 @@ def sharded_multilevel_roi_align(
             bwd_impl,
         )
 
-    if hasattr(jax, "shard_map"):
-        wrapped = jax.shard_map(
-            fn,
-            mesh=mesh,
-            in_specs=(P(data_axis), P(data_axis)),
-            out_specs=P(data_axis),
-            axis_names={data_axis},
-            check_vma=False,
-        )
-    else:
-        # jax < 0.6: shard_map lives in jax.experimental; "manual over
-        # data_axis only" is spelled as auto=<every other axis>, and the
-        # vma check is the old check_rep flag.
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        wrapped = _shard_map(
-            fn,
-            mesh=mesh,
-            in_specs=(P(data_axis), P(data_axis)),
-            out_specs=P(data_axis),
-            auto=frozenset(mesh.axis_names) - {data_axis},
-            check_rep=False,
-        )
+    wrapped = jax.shard_map(
+        fn,
+        mesh=mesh,
+        in_specs=(P(data_axis), P(data_axis)),
+        out_specs=P(data_axis),
+        check_vma=False,
+    )
     return wrapped(feature_pyramid, rois)

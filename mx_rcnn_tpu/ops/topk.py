@@ -49,12 +49,15 @@ from jax import lax
 def _floor_value(dtype):
     """Value that sorts (weakly) below every element of ``dtype``.
 
-    Static dtype dispatch on the host (numpy, not jnp — keeps the traced
-    function free of python branches on jax expressions).
+    Static dtype dispatch on the host — a branch on the dtype, never on a
+    traced value.  Through jax's dtype lattice, not numpy's: bfloat16 (the
+    RPN head's output dtype under the mixed precision policy, so the dtype
+    of the scores the recipe-width R50-FPN step ranks) is a float to jax
+    and an opaque 'V' kind to numpy.
     """
-    if np.issubdtype(np.dtype(dtype), np.inexact):
+    if jax.dtypes.issubdtype(dtype, np.inexact):
         return -np.inf
-    return np.iinfo(np.dtype(dtype)).min
+    return jnp.iinfo(dtype).min
 
 
 def hierarchical_top_k(scores: jnp.ndarray, k: int, block: int = 32768):

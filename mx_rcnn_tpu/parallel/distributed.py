@@ -14,9 +14,9 @@ Launch parity:
 
   reference: python tools/launch.py -n 4 ... python train_end2end.py --kv-store dist_sync
   here:      srun/gcloud per host: python train.py --config r101_coco
-             (TPU pods: the runtime's env markers trigger autodetecting
-             jax.distributed.initialize(); CPU/GPU clusters: pass
-             coordinator/rank/count explicitly or via
+             (TPU pods: env markers naming several hosts trigger
+             autodetecting jax.distributed.initialize(); CPU/GPU clusters:
+             pass coordinator/rank/count explicitly or via
              JAX_COORDINATOR_ADDRESS / JAX_PROCESS_ID / JAX_NUM_PROCESSES)
 
 The data path is the GLOBAL-schedule design (data/loader.py): every host
@@ -45,12 +45,21 @@ def initialize(
     num_processes: Optional[int] = None,
     process_id: Optional[int] = None,
 ) -> None:
-    """Join the multi-host runtime (no-op for single-process runs).
+    """Join the multi-host runtime — and on one host, return at once.
 
-    On TPU pods all arguments autodetect from the TPU runtime metadata.
-    Elsewhere pass them explicitly or via JAX_COORDINATOR_ADDRESS /
-    JAX_NUM_PROCESSES / JAX_PROCESS_ID.  Must run before the first device
-    query in the process.
+    Joins when told to (arguments, or JAX_COORDINATOR_ADDRESS /
+    JAX_NUM_PROCESSES / JAX_PROCESS_ID) or when the environment names
+    SEVERAL hosts (``TPU_WORKER_HOSTNAMES`` with more than one entry, or a
+    ``MEGASCALE_COORDINATOR_ADDRESS``); then every failure is fatal —
+    swallowing one would split-brain the job into N independent
+    "process 0" runs clobbering one shared workdir.
+
+    A single host never calls ``jax.distributed.initialize()``: a TPU VM
+    sets ``CLOUD_TPU_TASK_ID`` / a one-entry ``TPU_WORKER_HOSTNAMES`` on
+    single-host machines too, and jax's argument-less autodetection then
+    asks the cloud metadata server — on a machine without network that
+    is a wait or an error before the first step, for nothing.  Must run
+    before the first device query in the process.
     """
     coordinator_address = coordinator_address or os.environ.get(
         "JAX_COORDINATOR_ADDRESS"
@@ -64,39 +73,20 @@ def initialize(
         int(env_id) if env_id else None
     )
     explicit = coordinator_address is not None or (n is not None and n > 1)
-    # Multi-host TPU pods carry runtime metadata jax autodetects from; these
-    # markers are how we know to join without explicit configuration.
-    tpu_pod = any(
-        os.environ.get(k)
-        for k in ("TPU_WORKER_HOSTNAMES", "MEGASCALE_COORDINATOR_ADDRESS",
-                  "CLOUD_TPU_TASK_ID")
+    hosts = [
+        h for h in os.environ.get("TPU_WORKER_HOSTNAMES", "").split(",")
+        if h.strip()
+    ]
+    tpu_pod = len(hosts) > 1 or bool(
+        os.environ.get("MEGASCALE_COORDINATOR_ADDRESS")
     )
     if not explicit and not tpu_pod:
         return
-    try:
-        jax.distributed.initialize(
-            coordinator_address=coordinator_address,
-            num_processes=n,
-            process_id=pid,
-        )
-    except ValueError as e:
-        # Only the stale-marker case is benign: a dev box carrying garbage
-        # TPU env markers that don't actually name multiple worker hosts.
-        # On anything that looks like a real pod (several hostnames in
-        # TPU_WORKER_HOSTNAMES) every failure must stay fatal: swallowing
-        # it would split-brain the job into N independent "process 0" runs
-        # clobbering one shared workdir.
-        hosts = [
-            h for h in os.environ.get("TPU_WORKER_HOSTNAMES", "").split(",")
-            if h.strip()
-        ]
-        if explicit or len(hosts) > 1 or "coordinator_address" not in str(e):
-            raise
-        log.warning(
-            "TPU pod markers present but no coordinator address could be "
-            "derived (%s); continuing single-process", e,
-        )
-        return
+    jax.distributed.initialize(
+        coordinator_address=coordinator_address,
+        num_processes=n,
+        process_id=pid,
+    )
     log.info(
         "distributed runtime up: process %d/%d, %d local + %d global devices",
         jax.process_index(), jax.process_count(),

@@ -21,7 +21,7 @@ production endpoint needs:
   ladder (serve/degrade.py) so tight deadlines get a cheaper program
   instead of a guaranteed miss.
 * **Watchdog** — a monitor thread detects a device call that stopped
-  returning (hung runtime, wedged tunnel) and fails the engine to DEAD
+  returning (a hung runtime) and fails the engine to DEAD
   so supervisors replace the process instead of black-holing traffic.
 * **Continuous batching** (``batch_size > 1`` + ``pack``) — pending
   requests from different callers pack into every bucket slot of each

@@ -1,23 +1,33 @@
-"""Shared persistent-compile-cache wiring for CPU-mesh harnesses.
+"""Where the persistent compile cache lives — one rule for every entry point.
 
-The test suite (``tests/conftest.py``) and the driver's multichip dryrun
-(``__graft_entry__._dryrun_multichip_impl``) both jit full sharded train
-steps on a fake CPU mesh — minutes of XLA:CPU compilation that a
-persistent cache turns into seconds on re-runs.  Both MUST key the cache
-directory the same way or they silently stop sharing it, so the keying
-lives here once.
+:func:`configure_cache` is the only place in the repo that points jax's
+persistent compilation cache anywhere, and every entry point that compiles
+calls it once, after its platform is decided:
 
-The key is a host-CPU-feature fingerprint: XLA:CPU AOT executables are
-codegen'd for the COMPILING machine, and loading another machine's blobs
-both risks SIGILL and silently changes numerics (an r3 bisect found a
-recorded golden that only reproduced because the cache replayed the
-recording machine's executables).
+- ``JAX_COMPILATION_CACHE_DIR`` set: jax reads the variable itself, at
+  import.  The directory is used exactly as given — no subdirectory is
+  added, nothing in it is pruned, nothing is written anywhere else.  This
+  is how a caller that starts a fresh machine per run (the chip tool)
+  hands the program a cache that outlives the run.
+- unset, accelerator backend: ``<checkout>/.jax_cache``.  No device
+  fingerprint in the path: jax's own cache key already carries the
+  backend, the device kind and the compiler version.
+- unset, CPU backend (the test suite's fake mesh and the CPU-only
+  harnesses): ``<checkout>/tests/.jax_cache/<cpu fingerprint>``.  The
+  fingerprint stays because XLA:CPU executables are codegen'd for the
+  COMPILING machine — loading another machine's blobs risks SIGILL and
+  silently changes numerics (a recorded golden once reproduced only
+  because the cache replayed the recording machine's executables) — and
+  jax's key does not separate two x86 hosts.
+
+Both default paths are fixed: nothing in them comes from a temporary
+name, a pid, the clock or a boot id, because jax only hits entries it
+finds under the same directory on the next run.
 
 Import note: this module's own imports are stdlib, but importing it pulls
-in the ``mx_rcnn_tpu`` package whose ``utils.__init__`` imports jax at
-module level.  That is backend-safe (importing jax does not initialize a
-backend) but means platform env vars (``JAX_PLATFORMS``, ``XLA_FLAGS``)
-must be pinned BEFORE this import — both current callers do so.
+in the ``mx_rcnn_tpu`` package, which imports jax.  That initialises no
+backend, but platform variables (``JAX_PLATFORMS``, ``XLA_FLAGS``) must
+be pinned BEFORE the import.
 """
 
 from __future__ import annotations
@@ -25,6 +35,12 @@ from __future__ import annotations
 import hashlib
 import os
 import re
+
+REPO_ROOT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+)
+DEVICE_CACHE_DIR = os.path.join(REPO_ROOT, ".jax_cache")
+CPU_CACHE_ROOT = os.path.join(REPO_ROOT, "tests", ".jax_cache")
 
 # Comma-joined run of LLVM ±feature tokens, e.g.
 # "+64bit,+avx2,...,+prefer-no-scatter,+prefer-no-gather,-amx-fp16,..."
@@ -35,13 +51,12 @@ def _features_from_blob(blob: bytes) -> str:
     """Cache-key material from a serialized AOT probe executable.
 
     Preferred: the longest ``+feat,-feat,...`` run — the human-auditable
-    LLVM target-feature string itself.  When the blob format stops
-    embedding it verbatim (jaxlib 0.9.0's serialization does not carry a
-    recognizable run, observed on the bench host), hash the WHOLE blob
-    instead: the codegen'd bytes necessarily differ wherever the target
-    features differ, so the key keeps discriminating exactly the failure
-    mode instead of silently degrading to the cpuinfo proxy that
-    MULTICHIP_r04 showed can collide.
+    LLVM target-feature string itself.  The installed jaxlib (0.9.0) does
+    not embed a recognizable run, so the WHOLE blob is hashed instead: the
+    codegen'd bytes differ wherever the target features differ, so the key
+    discriminates exactly the failure mode that a /proc/cpuinfo proxy was
+    seen to miss (two hosts with identical kernel-reported flags and
+    different LLVM preference features).
     """
     runs = [m.group(0) for m in _FEATURE_RUN.finditer(blob)]
     if runs:
@@ -50,158 +65,41 @@ def _features_from_blob(blob: bytes) -> str:
 
 
 def llvm_target_features() -> str | None:
-    """The LLVM target-feature string XLA:CPU actually compiles with.
+    """Fingerprint of the code XLA:CPU generates on this host.
 
-    Extracted from a tiny AOT probe: serialize a trivial compiled
-    executable and pull the longest ``+feat,-feat,...`` run out of its
-    bytes.  This is the string whose cross-host mismatch produced the r3
-    golden drift and the r4 ``cpu_aot_loader.cc`` errors
-    (``+prefer-no-scatter,+prefer-no-gather`` present on one host, absent
-    on the other) — r4's /proc/cpuinfo proxy demonstrably still collided
-    (MULTICHIP_r04 tail), so r5 keys on the decision itself instead of
-    its inputs.  Verified present in the serialized blob on jaxlib 0.8.x
-    (3.4 KB probe, feature run embedded verbatim); jaxlib 0.9.0 blobs no
-    longer embed the run, so ``_features_from_blob`` falls back to a hash
-    of the entire blob — still a fingerprint of the codegen decision, not
-    of its cpuinfo inputs.
-
-    Requires an initialized XLA:CPU backend — both callers pin
-    ``jax_platforms`` to cpu before calling.  Returns None only if the
-    probe path itself is unavailable (caller falls back to cpuinfo).
+    Serializes a trivial AOT-compiled executable and reduces it with
+    :func:`_features_from_blob`.  Requires the CPU backend (returns None
+    on any other — the caller then keys on /proc/cpuinfo alone).
     """
-    try:
-        import jax
-
-        if jax.default_backend() != "cpu":
-            return None
-        blob = _probe_blob()
-        if blob != _probe_blob():
-            # A cache key must be stable across processes; a serializer
-            # that embeds compile-varying bytes (observed on jaxlib
-            # 0.4.x: two fresh compiles of the same program serialize
-            # differently — module ids) would key every run separately
-            # and the cache would never warm.  Only then fall back to
-            # the cpuinfo proxy.
-            return None
-        return _features_from_blob(blob)
-    except Exception:
-        return None
-
-
-def _probe_blob() -> bytes:
-    """Compile a fresh trivial executable and serialize it.  A new lambda
-    each call defeats jax's jit cache, so two calls exercise two full
-    compile+serialize rounds — the determinism check above needs that."""
     import jax
     import jax.numpy as jnp
 
+    if jax.default_backend() != "cpu":
+        return None
     probe = (
         jax.jit(lambda x: x @ x)
         .lower(jnp.zeros((4, 4), jnp.float32))
         .compile()
     )
-    ex = probe.runtime_executable()
-    if hasattr(ex, "serialize"):
-        return ex.serialize()
-    # Older jaxlibs (0.4.x) expose serialization on the client.
-    return ex.client.serialize_executable(ex)
+    return _features_from_blob(probe.runtime_executable().serialize())
 
 
-def host_identity() -> str:
-    """A stable per-machine identifier, most-durable source first.
+def cpu_fingerprint() -> str:
+    """Hash of this host's CPU identity and the compiler stack.
 
-    ``/etc/machine-id`` survives reboots; the kernel's ``boot_id`` at
-    least separates machines (it rotates per boot, costing warm-cache
-    reuse across reboots but never correctness); the hostname is the
-    last resort.  Used ONLY by strict-host mode below — it deliberately
-    over-separates (two genuinely identical hosts get distinct keys,
-    losing safe sharing), which is the right trade for harnesses that
-    spawn subprocess workers and cannot afford a foreign-blob replay.
-    """
-    for path in ("/etc/machine-id", "/var/lib/dbus/machine-id"):
-        try:
-            with open(path) as f:
-                mid = f.read().strip()
-            if mid:
-                return "machine-id:" + mid
-        except OSError:
-            pass
-    try:
-        with open("/proc/sys/kernel/random/boot_id") as f:
-            bid = f.read().strip()
-        if bid:
-            return "boot-id:" + bid
-    except OSError:
-        pass
-    import socket
-
-    return "hostname:" + socket.gethostname()
-
-
-def _strict_host_env() -> bool:
-    return os.environ.get("MX_RCNN_CACHE_STRICT_HOST", "") not in ("", "0")
-
-
-def cpu_fingerprint(strict_host: bool = False) -> str:
-    """Stable-ish hash of this host's CPU identity and the compiler stack.
-
-    The key mixes, in order of specificity:
-
-    - every distinct ``flags`` / ``Features`` line from ``/proc/cpuinfo``
-      (sorted union, not just the first — heterogeneous ARM big.LITTLE
-      cores report differing Features lines and core enumeration order is
-      not stable);
-    - every distinct CPUID identity line (``vendor_id``, ``cpu family``,
-      ``model``, ``stepping``, ``model name``): r3 observed two hosts
-      whose kernel-reported flags were IDENTICAL while LLVM's target
-      features differed (``+prefer-no-scatter,+prefer-no-gather`` on one
-      side), so flags alone demonstrably CAN collapse two hosts to one
-      key (the foreign-AOT-blob replay in BASELINE.md's round-3
-      close-out).  XLA does not expose its LLVM host target-feature
-      string in-process (probed r4: ``backend.platform_version`` is just
-      ``"cpu"``), but LLVM *derives* those preference flags from CPUID
-      family/model/stepping — hashing them keys on the input to the
-      decision that actually differed.  ``model name`` alone would not do
-      it: virtualized builders report generic strings;
-    - the jaxlib version — AOT blob layout and XLA codegen both move with
-      it.
-
-    Only the uname fallback (no readable /proc/cpuinfo) carries the
-    original "two hosts can't collapse" guarantee; the cpuinfo path is
-    best-effort and a collision on all of the above, while now much
-    narrower, remains possible on truly identical fleet hardware — which
-    is also the one case where sharing blobs is safe.
-
-    r5: the PRIMARY key is now ``llvm_target_features()`` — the exact
-    string whose mismatch is the failure mode — because the r4
-    cpuinfo-proxy key demonstrably still collided on the driver host
-    (MULTICHIP_r04's ``cpu_aot_loader.cc`` tail).  The cpuinfo/uname
-    material stays mixed in as a tiebreak for the (observed-empty) case
-    where the probe is unavailable.
-
-    Note: strengthening this key (r4, again r5) intentionally orphans
-    caches warmed under the previous key; first runs after the change pay
-    a full recompile.
-
-    r7 ``strict_host`` (param, or env ``MX_RCNN_CACHE_STRICT_HOST=1`` so
-    spawned workers inherit it): when the AOT probe is unavailable —
-    jaxlib 0.4.x serializes nondeterministically, so
-    :func:`llvm_target_features` returns None and the key degrades to
-    exactly the cpuinfo proxy that MULTICHIP_r04/r05 showed colliding
-    across driver hosts — mix :func:`host_identity` into the key.  Each
-    host keeps a warm PER-HOST cache (strictly better than disabling
-    reuse) and a foreign host can never replay this host's blobs.  Off
-    by default: the tier-1 suite's long-lived cache on a single builder
-    would be orphaned by boot-id rotation for no safety gain there.
+    Mixes every distinct ``flags``/``Features`` and CPUID identity line of
+    ``/proc/cpuinfo`` (sorted union — heterogeneous cores report differing
+    lines in unstable order; ``platform.uname()`` when unreadable),
+    :func:`llvm_target_features` — the primary key, because the cpuinfo
+    lines alone were seen to collide across hosts whose codegen differed —
+    and the jaxlib version (blob layout and codegen move with it).
     """
     import jaxlib
 
     fields = (
         # x86 feature + identity lines.
         "flags", "vendor_id", "cpu family", "model", "stepping", "model name",
-        # ARM equivalents: Features plus the CPUID identity (implementer/
-        # part/variant/revision are what LLVM's ARM host detection keys
-        # microarch tuning on, exactly as family/model/stepping on x86).
+        # ARM equivalents.
         "Features", "CPU implementer", "CPU part", "CPU variant",
         "CPU architecture", "CPU revision",
     )
@@ -222,115 +120,35 @@ def cpu_fingerprint(strict_host: bool = False) -> str:
         key = repr(platform.uname())
     feats = llvm_target_features()
     key += "\nllvm_target_features=" + (feats if feats is not None else "?")
-    if feats is None and (strict_host or _strict_host_env()):
-        key += "\nhost=" + host_identity()
     key += "\njaxlib=" + jaxlib.version.__version__
     return hashlib.sha1(key.encode()).hexdigest()[:8]
 
 
-def backend_fingerprint(strict_host: bool = False) -> str:
-    """Cache-key fingerprint for WHATEVER backend jax initialized.
+def configure_cache() -> str:
+    """Apply the module's rule; returns the directory in use.
 
-    - cpu: :func:`cpu_fingerprint` — XLA:CPU AOT blobs are codegen'd for
-      the compiling host's LLVM target features, so the key must separate
-      hosts (stale foreign blobs SIGILL or silently change numerics).
-    - tpu / gpu: hash of (backend, device_kind, platform_version, jaxlib).
-      Accelerator executables are keyed by chip generation and compiler
-      stack, not host CPU — a v5e blob must not be replayed on a v6e
-      (or across libtpu/XLA upgrades), which is exactly what a shared
-      un-keyed ``.jax_cache`` dir (bench.py pre-r5) allowed when a
-      checkout migrates between machines.
+    Initialises the backend (``jax.default_backend()``), so call it once
+    the platform is decided and before the first compile.  A directory
+    that is already configured — by ``JAX_COMPILATION_CACHE_DIR`` or by an
+    earlier call in this process — is left exactly as it is.
     """
     import jax
 
-    backend = jax.default_backend()
-    if backend == "cpu":
-        return cpu_fingerprint(strict_host=strict_host)
-    import jaxlib
-
-    dev = jax.devices()[0]
-    try:
-        import jax.extend.backend
-
-        platform_version = jax.extend.backend.get_backend().platform_version
-    except Exception:
-        platform_version = "?"
-    key = "\n".join(
-        [
-            "backend=" + backend,
-            "device_kind=" + getattr(dev, "device_kind", "?"),
-            "platform_version=" + platform_version,
-            "jaxlib=" + jaxlib.version.__version__,
-        ]
+    on_cpu = jax.default_backend() == "cpu"
+    # CPU: only programs worth the disk (the suite compiles thousands of
+    # tiny ones, and the chip tool copies tests/.jax_cache with the tree).
+    # Accelerator: everything — a cold start there is a few hundred small
+    # programs around each large one, and each costs a compile.
+    jax.config.update(
+        "jax_persistent_cache_min_compile_time_secs", 5.0 if on_cpu else 0.0
     )
-    return backend + "-" + hashlib.sha1(key.encode()).hexdigest()[:8]
-
-
-def configure_cache(cache_root: str, min_compile_secs: float = 5.0,
-                    strict_host: bool = False) -> str:
-    """Point jax's persistent compile cache at a fingerprinted subdir.
-
-    Generalized form of :func:`configure_cpu_cache`: keys ``cache_root``
-    by :func:`backend_fingerprint` so one checkout shared across hosts /
-    chip generations never replays a foreign executable, with the same
-    keep-newest-3 sibling pruning.  ``strict_host`` (or env
-    ``MX_RCNN_CACHE_STRICT_HOST=1``) additionally separates hosts when
-    the LLVM-feature probe is unavailable — see :func:`cpu_fingerprint`.
-    Call after the backend is decided (importing jax is fine; the first
-    ``jax.devices()`` call here initializes it).  Returns the directory
-    used.
-    """
-    import jax
-
-    cache_dir = os.path.join(
-        cache_root, backend_fingerprint(strict_host=strict_host)
+    placed = jax.config.jax_compilation_cache_dir
+    if placed:
+        return placed
+    cache_dir = (
+        os.path.join(CPU_CACHE_ROOT, cpu_fingerprint())
+        if on_cpu
+        else DEVICE_CACHE_DIR
     )
-    _prune_and_point(jax, cache_root, cache_dir, min_compile_secs)
-    return cache_dir
-
-
-def configure_cpu_cache(repo_root: str, strict_host: bool = False) -> str:
-    """Point jax's persistent compile cache at the shared fingerprinted dir.
-
-    Call only after the caller has pinned the platform to CPU (the cache
-    dir is CPU-keyed).  Returns the directory used.
-    """
-    import jax
-
-    cache_root = os.path.join(repo_root, "tests", ".jax_cache")
-    cache_dir = os.path.join(cache_root, cpu_fingerprint(strict_host=strict_host))
-    _prune_and_point(jax, cache_root, cache_dir, 5.0)
-    return cache_dir
-
-
-def _prune_and_point(jax, cache_root: str, cache_dir: str,
-                     min_compile_secs: float) -> None:
-    # Key rotations (host change, jaxlib upgrade) orphan old sibling dirs.
-    # Builder hosts alternate between sessions on this shared checkout, so
-    # deleting every foreign sibling would wipe another host's warm cache
-    # each switch; instead keep the newest few by mtime and prune the rest
-    # so the root still can't grow monotonically across upgrades.
-    keep = 3
-    try:
-        # A fully-warm dir takes no new writes, so its mtime would freeze at
-        # warm-up time and age it toward eviction; touch it on every use so
-        # mtime means "last used", which is what the keep-newest rule wants.
-        if os.path.isdir(cache_dir):
-            os.utime(cache_dir)
-        sibs = [
-            os.path.join(cache_root, n)
-            for n in os.listdir(cache_root)
-            if os.path.isdir(os.path.join(cache_root, n))
-        ]
-        sibs.sort(key=os.path.getmtime, reverse=True)
-        for stale in sibs[keep:]:
-            if stale != cache_dir:
-                import shutil
-
-                shutil.rmtree(stale, ignore_errors=True)
-    except OSError:
-        pass
     jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                      min_compile_secs)
-    jax.config.update("jax_persistent_cache_enable_xla_caches", "all")
+    return cache_dir

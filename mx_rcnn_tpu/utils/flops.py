@@ -19,6 +19,26 @@ import math
 
 import jax
 
+# Published peak dense-matmul rate per chip in bf16, keyed by the
+# ``device_kind`` string jax reports (``jax.devices()[0].device_kind``).
+# The one table every MFU figure in the repo divides by; a kind that is
+# not here is an error, never a default.
+#   "TPU v5 lite": TPU v5e, 197 TFLOP/s — Google Cloud documentation,
+#   "TPU v5e"; the key is the string the chip reported in PR 21's runs.
+PEAK_BF16_FLOPS = {"TPU v5 lite": 197e12}
+
+
+def peak_bf16_flops(device_kind: str) -> float:
+    """Peak bf16 FLOP/s of one chip of ``device_kind``; raises on a kind
+    the table does not list (a CPU included — no MFU exists there)."""
+    if device_kind not in PEAK_BF16_FLOPS:
+        raise ValueError(
+            f"no published bf16 peak for device_kind {device_kind!r}; "
+            f"known: {sorted(PEAK_BF16_FLOPS)} — add it to "
+            "utils/flops.py::PEAK_BF16_FLOPS with its source"
+        )
+    return PEAK_BF16_FLOPS[device_kind]
+
 
 def _conv_flops(eqn) -> float:
     """2 * batch * out_spatial * Cout * (Cin/groups) * kernel_spatial."""

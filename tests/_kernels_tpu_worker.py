@@ -1,0 +1,391 @@
+"""On-TPU probe of every ``pallas_call`` site: Mosaic compiles it at recipe
+shapes and it matches its XLA oracle.
+
+Runs on the REAL chip (one process, jax's default platform — the parent
+test refuses anything but a TPU).  The interpret-mode CPU tests cannot see
+what Mosaic accepts, MXU bf16 truncation, or VMEM limits, so this is the
+only oracle for the on-chip claims in ``ops/pallas/``.  Shapes are the
+``r50_fpn_coco`` recipe: 800x1344 canvas, P2-P5 at 256 channels, bf16.
+
+Probes, each printed as one entry of the final ``RESULT {json}`` line:
+
+- ``roi_align_fwd[train|eval|f32]`` — ``multilevel_roi_align_pallas`` at
+  b2 x 512 rois (train), b8 x 1000 rois (eval), and b2 x 512 in f32 (the
+  tiny_synthetic / overfit-golden dtype) vs ``multilevel_roi_align``.
+- ``roi_align_bwd[train]`` — the window-RMW backward vs autodiff of the
+  XLA reference (``MX_RCNN_POOL_BWD=xla``) with a bf16 cotangent.
+- ``nms[2000]`` — ``nms_mask_pallas`` vs ``nms_mask``.
+- ``fused_middle[train|eval]`` — ``generate_fpn_proposals`` with
+  ``fused_middle=True`` vs the dense chain, under ``jax.vmap`` over the
+  batch exactly as ``detection/graph.py::_propose_one`` reaches it.
+
+The ROIAlign probes are the main path and the process exits non-zero if
+one fails.  The other two are default-off options: a failure there is
+recorded with the compiler's message (and reported by the parent test),
+because an option that cannot compile must fail loudly when selected —
+there is no fallback to hide it.
+
+Each tolerance is written next to its check with its reason.  Also prints
+two facts about the runtime the benchmark's timing method leans on
+(``runtime``): what one dispatch costs, and whether ``block_until_ready``
+is a true barrier.
+
+Run directly: python tests/_kernels_tpu_worker.py
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import sys
+import time
+import traceback
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+CANVAS = (800, 1344)
+CHANNELS = 256
+# Largest relative rounding error of one bf16 rounding (8 significand bits).
+BF16_EPS = 2.0 ** -8
+
+
+def _pyramid(rng, batch, dtype):
+    import jax.numpy as jnp
+
+    h, w = CANVAS
+    return {
+        lvl: jnp.asarray(
+            rng.standard_normal((batch, h // s, w // s, CHANNELS)), dtype
+        )
+        for lvl, s in ((2, 4), (3, 8), (4, 16), (5, 32))
+    }
+
+
+def _rois(rng, batch, n):
+    """Boxes log-uniform in size 16..600 px so all four levels get rois."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    h, w = CANVAS
+    sizes = np.exp(rng.uniform(np.log(16), np.log(600), (batch, n, 2)))
+    cx = rng.uniform(0, w, (batch, n))
+    cy = rng.uniform(0, h, (batch, n))
+    x1 = np.clip(cx - sizes[..., 0] / 2, 0, w - 2)
+    y1 = np.clip(cy - sizes[..., 1] / 2, 0, h - 2)
+    x2 = np.clip(x1 + sizes[..., 0], x1 + 1, w - 1)
+    y2 = np.clip(y1 + sizes[..., 1], y1 + 1, h - 1)
+    return jnp.asarray(np.stack([x1, y1, x2, y2], -1), jnp.float32)
+
+
+def probe_roi_align_fwd(batch, n_rois, dtype_name):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mx_rcnn_tpu.ops.pallas.roi_align import (
+        POOL_WINDOW,
+        multilevel_roi_align_pallas,
+    )
+    from mx_rcnn_tpu.ops.roi_align import multilevel_roi_align
+
+    rng = np.random.default_rng(0)
+    dtype = jnp.dtype(dtype_name)
+    pyramid = _pyramid(rng, batch, dtype)
+    rois = _rois(rng, batch, n_rois)
+
+    got = multilevel_roi_align_pallas(pyramid, rois)
+    jax.block_until_ready(got)
+
+    # Oracle one image at a time (lax.map): the XLA gather's intermediates
+    # at b8 x 1000 rois need not fit beside the pyramid all at once.
+    @jax.jit
+    def oracle(p, r):
+        return jax.lax.map(
+            lambda pr: multilevel_roi_align(
+                pr[0], pr[1], max_extent_cells=POOL_WINDOW - 10
+            ),
+            (p, r),
+        )
+
+    want = oracle(pyramid, rois)
+    a = np.asarray(jax.device_get(got), np.float32)
+    b = np.asarray(jax.device_get(want), np.float32)
+    feat_scale = max(
+        float(jnp.max(jnp.abs(f.astype(jnp.float32))))
+        for f in pyramid.values()
+    )
+    rel = float(np.abs(a - b).max()) / feat_scale
+    if dtype == jnp.bfloat16:
+        # The oracle interpolates in f32 and rounds ONCE to bf16.  The
+        # kernel's hi/lo split weights are f32-exact to ~2^-17, its
+        # intermediate rows take one bf16 rounding (<= eps * max|feature|,
+        # carried through x-weights that sum to <= 1), and its output is
+        # rounded to bf16 too — two roundings of nearby values can land on
+        # adjacent bf16 numbers (<= 2 eps * |out|).  Sum: 3 eps of the
+        # feature scale.
+        ceiling = 3 * BF16_EPS
+    else:
+        # f32 features take the HIGHEST-precision dots with exact f32
+        # weights; only summation order differs from the oracle.  1e-4 of
+        # the feature scale is the bound the CPU interpret tests hold the
+        # same path to (atol 1e-4 on unit-scale features).
+        ceiling = 1e-4
+    return {
+        "ok": bool(np.isfinite(a).all() and rel <= ceiling),
+        "max_abs_diff_over_feature_scale": rel,
+        "ceiling": ceiling,
+        "feature_scale": feat_scale,
+        "shape": list(a.shape),
+    }
+
+
+def probe_roi_align_bwd(batch, n_rois):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mx_rcnn_tpu.ops.pallas.roi_align import multilevel_roi_align_fast
+
+    rng = np.random.default_rng(0)
+    pyramid = _pyramid(rng, batch, jnp.bfloat16)
+    rois = _rois(rng, batch, n_rois)
+    # Fixed bf16 cotangent via a linear loss: grad arrives in the output
+    # dtype (bf16), exactly as in the train graph.
+    cot = jnp.asarray(
+        rng.standard_normal((batch, n_rois, 7, 7, CHANNELS)), jnp.bfloat16
+    )
+
+    # Two distinct traced functions: the env var is read at TRACE time
+    # inside _fast_bwd, and reusing one jitted function would silently
+    # replay the first trace's choice.
+    def make_loss():
+        def loss(p):
+            out = multilevel_roi_align_fast(p, rois)
+            return jnp.sum(out.astype(jnp.float32) * cot.astype(jnp.float32))
+
+        return loss
+
+    try:
+        os.environ["MX_RCNN_POOL_BWD"] = "pallas"
+        g_pallas = jax.jit(jax.grad(make_loss()))(pyramid)
+        jax.block_until_ready(g_pallas)
+        os.environ["MX_RCNN_POOL_BWD"] = "xla"
+        g_xla = jax.jit(jax.grad(make_loss()))(pyramid)
+    finally:
+        del os.environ["MX_RCNN_POOL_BWD"]
+
+    levels, worst = {}, 0.0
+    for lvl in pyramid:
+        a = np.asarray(jax.device_get(g_pallas[lvl]), np.float32)
+        b = np.asarray(jax.device_get(g_xla[lvl]), np.float32)
+        scale = float(np.abs(b).max()) or 1.0
+        diff = float(np.abs(a - b).max())
+        levels[f"P{lvl}"] = {
+            "max_abs_diff": diff, "grad_scale": scale, "rel": diff / scale,
+        }
+        worst = max(worst, diff / scale)
+    # Normalized (per-level max-abs / grad-scale) disagreement.  Both
+    # backends round in bf16: the kernel truncates weights and its
+    # intermediate once each, the XLA scatter-add ACCUMULATES in bf16
+    # (hundreds of += per P2 cell), so the band is a few bf16 roundings of
+    # the gradient scale, not one.  0.03 ~ 8 eps; the value measured when
+    # the kernel was written was 0.0092, and PR 21's run on the local
+    # libtpu is recorded in PERF.md.
+    ceiling = 0.03
+    return {
+        "ok": bool(worst <= ceiling),
+        "worst_rel": worst,
+        "ceiling": ceiling,
+        "levels": levels,
+    }
+
+
+def probe_nms(n):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mx_rcnn_tpu.ops.nms import nms_mask
+    from mx_rcnn_tpu.ops.pallas.nms import nms_mask_pallas
+
+    rng = np.random.default_rng(0)
+    # Clustered boxes so suppression chains exist (iid boxes barely overlap).
+    centers = rng.uniform(100, 700, (n // 20, 2))
+    ctr = centers[rng.integers(0, len(centers), n)] + rng.normal(0, 12, (n, 2))
+    wh = rng.uniform(40, 160, (n, 2))
+    boxes = jnp.asarray(
+        np.concatenate([ctr - wh / 2, ctr + wh / 2], 1), jnp.float32
+    )
+    scores = jnp.asarray(rng.uniform(0, 1, n), jnp.float32)
+    got = np.asarray(jax.device_get(nms_mask_pallas(boxes, scores, 0.7)))
+    want = np.asarray(jax.device_get(jax.jit(
+        lambda b, s: nms_mask(b, s, 0.7)
+    )(boxes, scores)))
+    # Exact: both sides compare the SAME 2^-16-snapped IoU to the threshold
+    # (the snap exists so that an ulp of difference between two compilers'
+    # divides cannot flip a decision), and greedy NMS is deterministic given
+    # the decisions.
+    mismatches = int((got != want).sum())
+    return {
+        "ok": mismatches == 0,
+        "mismatched_keep_bits": mismatches,
+        "kept": int(want.sum()),
+        "n": n,
+    }
+
+
+def probe_fused_middle(train):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mx_rcnn_tpu.config import get_config
+    from mx_rcnn_tpu.detection.graph import _cached_level_anchor
+    from mx_rcnn_tpu.ops.proposals import generate_fpn_proposals
+
+    cfg = get_config("r50_fpn_coco").model
+    rpn = cfg.rpn
+    pre = rpn.train_pre_nms_top_n if train else rpn.test_pre_nms_top_n
+    post = rpn.train_post_nms_top_n if train else rpn.test_post_nms_top_n
+    batch = 2 if train else 8
+    h, w = CANVAS
+    rng = np.random.default_rng(0)
+    anchors, scores, deltas = {}, {}, {}
+    for lvl in (2, 3, 4, 5, 6):
+        s = 2 ** lvl
+        fh, fw = -(-h // s), -(-w // s)
+        a = _cached_level_anchor(
+            s, tuple(cfg.anchors.ratios), tuple(cfg.anchors.scales), fh, fw
+        )
+        anchors[lvl] = jnp.asarray(a)
+        n = a.shape[0]
+        scores[lvl] = jnp.asarray(rng.uniform(0, 1, (batch, n)), jnp.float32)
+        deltas[lvl] = jnp.asarray(
+            rng.normal(0, 0.3, (batch, n, 4)), jnp.float32
+        )
+    hw = jnp.asarray([[float(h), float(w)]] * batch, jnp.float32)
+
+    def run(fused):
+        def one(sc, dl, hw_row):
+            return generate_fpn_proposals(
+                sc, dl, anchors, hw_row[0], hw_row[1],
+                pre_nms_top_n=pre, post_nms_top_n=post,
+                nms_threshold=rpn.nms_threshold, min_size=rpn.min_size,
+                fused_middle=fused,
+            )
+
+        return jax.device_get(jax.jit(jax.vmap(one))(scores, deltas, hw))
+
+    got, want = run(True), run(False)
+    # Exact: the kernel replicates decode/clip to the operation and both
+    # sides snap coordinates to 1/256 px and IoUs to 2^-16 before any
+    # discrete decision — the grids exist to absorb an ulp of difference
+    # between two compilers' exp/divide.  A row that differs is a
+    # candidate whose coordinate sat within an ulp of a grid boundary; it
+    # is counted, and any at all fails the probe.
+    rows = int(np.prod(want.valid.shape))
+    bad_rows = int(
+        (np.abs(np.asarray(got.rois) - np.asarray(want.rois)).max(-1) > 0)
+        .sum()
+    )
+    bad_valid = int((np.asarray(got.valid) != np.asarray(want.valid)).sum())
+    bad_scores = int(
+        (np.asarray(got.scores) != np.asarray(want.scores)).sum()
+    )
+    return {
+        "ok": bad_rows == 0 and bad_valid == 0 and bad_scores == 0,
+        "rows": rows,
+        "rows_with_different_rois": bad_rows,
+        "rows_with_different_valid": bad_valid,
+        "rows_with_different_scores": bad_scores,
+        "max_abs_roi_diff": float(
+            np.abs(np.asarray(got.rois) - np.asarray(want.rois)).max()
+        ),
+        "valid_rows": int(np.asarray(want.valid).sum()),
+    }
+
+
+def runtime_facts():
+    """Set-up facts about the runtime, not speeds of the detector."""
+    import jax
+    import jax.numpy as jnp
+
+    inc = jax.jit(lambda x: x + 1.0)
+    x = jnp.zeros((8, 128), jnp.float32)
+    jax.block_until_ready(inc(x))
+    n = 2000
+    t0 = time.perf_counter()
+    for _ in range(n):
+        x = inc(x)
+    jax.block_until_ready(x)
+    dispatch_us = (time.perf_counter() - t0) / n * 1e6
+
+    mm = jax.jit(lambda a: (a @ a) * (1.0 / 4096.0))
+    a = jnp.ones((4096, 4096), jnp.bfloat16)
+    jax.block_until_ready(mm(a))
+
+    def chain(sync):
+        y = a
+        t = time.perf_counter()
+        for _ in range(50):
+            y = mm(y)
+        sync(y)
+        return time.perf_counter() - t
+
+    chain(jax.block_until_ready)
+    t_block = chain(jax.block_until_ready)
+    t_fetch = chain(lambda y: jax.device_get(y[0, 0]))
+    return {
+        "dispatch_us_per_call": round(dispatch_us, 1),
+        "chain50_block_until_ready_s": round(t_block, 4),
+        "chain50_device_get_s": round(t_fetch, 4),
+    }
+
+
+PROBES = (
+    # (name, main_path, fn, args)
+    ("roi_align_fwd[train,b2x512,bf16]", True,
+     probe_roi_align_fwd, (2, 512, "bfloat16")),
+    ("roi_align_fwd[eval,b8x1000,bf16]", True,
+     probe_roi_align_fwd, (8, 1000, "bfloat16")),
+    ("roi_align_fwd[b2x512,f32]", True,
+     probe_roi_align_fwd, (2, 512, "float32")),
+    ("roi_align_bwd[train,b2x512,bf16]", True, probe_roi_align_bwd, (2, 512)),
+    ("nms[2000]", False, probe_nms, (2000,)),
+    ("fused_middle[train,b2,k2000]", False, probe_fused_middle, (True,)),
+    ("fused_middle[eval,b8,k1000]", False, probe_fused_middle, (False,)),
+)
+
+
+def main() -> int:
+    from mx_rcnn_tpu.utils.compile_cache import configure_cache
+    from mx_rcnn_tpu.utils.runtime import device_record, runtime_versions
+
+    out = {**device_record(), **runtime_versions(), "probes": {}}
+    print("DEVICE " + json.dumps(out), flush=True)
+    configure_cache()
+    main_path_ok = True
+    for name, main_path, fn, args in PROBES:
+        t0 = time.perf_counter()
+        try:
+            res = fn(*args)
+        except Exception as e:  # noqa: BLE001 - the message IS the finding
+            traceback.print_exc()
+            res = {
+                "ok": False,
+                "error": f"{type(e).__name__}: {e}"[:3000],
+            }
+        res["main_path"] = main_path
+        res["wall_s"] = round(time.perf_counter() - t0, 1)
+        out["probes"][name] = res
+        print(f"PROBE {name} " + json.dumps(res), flush=True)
+        if main_path and not res["ok"]:
+            main_path_ok = False
+    out["runtime"] = runtime_facts()
+    print("RESULT " + json.dumps(out), flush=True)
+    return 0 if main_path_ok else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -1,11 +1,11 @@
-"""Unit tests of the persistent-compile-cache key (VERDICT r4 #5).
+"""Where the compile cache lives, and the CPU key under the test mesh.
 
-The cache key's job is: two hosts whose XLA:CPU codegen differs must get
-different directories.  r3/r4 proved the /proc/cpuinfo proxy can collide
+The CPU key's job is: two hosts whose XLA:CPU codegen differs must get
+different directories.  The /proc/cpuinfo proxy was seen to collide
 (identical kernel-reported flags, different LLVM preference features —
-the ``cpu_aot_loader.cc`` mismatch tail in MULTICHIP_r04), so the r5 key
-is the LLVM target-feature string itself, extracted from a serialized
-probe executable.  These tests pin the key's inputs and sensitivity.
+the ``cpu_aot_loader.cc`` mismatch tail in MULTICHIP_r04), so the key is
+a fingerprint of a serialized probe executable.  These tests pin the
+key's inputs and sensitivity, and the placement rule around it.
 """
 
 from __future__ import annotations
@@ -16,20 +16,11 @@ from mx_rcnn_tpu.utils import compile_cache
 class TestLlvmTargetFeatures:
     def test_probe_contract_on_cpu_backend(self):
         # The suite runs with jax pinned to the fake-CPU backend
-        # (conftest), which is exactly the production condition of both
-        # callers.  The probe returns either a real ±feature run, a
-        # whole-blob hash (jaxlib 0.9.0: run not embedded), or None ONLY
-        # when the serializer itself is compile-unstable (jaxlib 0.4.x:
-        # fresh compiles of the same program serialize differently, so
-        # blob bytes can't key a cross-process cache).
+        # (conftest), which is the condition of every CPU caller.  The
+        # probe returns a real ±feature run or (jaxlib 0.9.0: run not
+        # embedded) a whole-blob hash.
         feats = compile_cache.llvm_target_features()
-        if feats is None:
-            assert compile_cache._probe_blob() != compile_cache._probe_blob(), (
-                "probe fell back with a DETERMINISTIC serializer — the "
-                "key would silently degrade to the collision-prone "
-                "cpuinfo proxy for no reason"
-            )
-        elif feats.startswith("blob:"):
+        if feats.startswith("blob:"):
             assert len(feats) == len("blob:") + 40  # sha1 hex
         else:
             toks = feats.split(",")
@@ -100,126 +91,142 @@ class TestBlobFallback:
         assert compile_cache.cpu_fingerprint() != base
 
 
-class TestStrictHostKey:
-    """r7 strict-host mode: when the LLVM probe degrades (jaxlib 0.4.x
-    serializes nondeterministically), the cpuinfo proxy is the only key
-    left — and r3/r4 proved it can collide across hosts.  Harnesses that
-    spawn subprocess workers (driver dryrun, perf_breakdown, bench) mix a
-    per-machine identity into the key so a foreign XLA:CPU AOT blob can
-    never be replayed (the cpu_aot_loader SIGILL tail in MULTICHIP_r04)."""
+class TestConfigureCache:
+    """The one rule (utils/compile_cache.py): a directory placed from
+    outside is used exactly as given; otherwise two fixed paths.  Checked
+    in fresh processes — the suite's own process was configured by
+    conftest, and the rule is about what a process does at start-up."""
 
-    def test_host_identity_sourced_and_stable(self):
-        hid = compile_cache.host_identity()
-        assert hid.split(":", 1)[0] in ("machine-id", "boot-id", "hostname")
-        assert len(hid.split(":", 1)[1]) > 0
-        assert hid == compile_cache.host_identity()
-
-    def test_strict_host_separates_keys_when_probe_degrades(self, monkeypatch):
-        monkeypatch.delenv("MX_RCNN_CACHE_STRICT_HOST", raising=False)
-        monkeypatch.setattr(
-            compile_cache, "llvm_target_features", lambda: None
-        )
-        assert (
-            compile_cache.cpu_fingerprint(strict_host=True)
-            != compile_cache.cpu_fingerprint()
-        )
-
-    def test_strict_host_noop_with_a_live_probe(self, monkeypatch):
-        # With real LLVM features in the key the proxy never engages, so
-        # strict mode must not orphan warm caches on healthy hosts.
-        monkeypatch.delenv("MX_RCNN_CACHE_STRICT_HOST", raising=False)
-        monkeypatch.setattr(
-            compile_cache, "llvm_target_features",
-            lambda: "+64bit,+avx,+avx2,+fma",
-        )
-        assert (
-            compile_cache.cpu_fingerprint(strict_host=True)
-            == compile_cache.cpu_fingerprint()
-        )
-
-    def test_env_var_engages_strict_mode(self, monkeypatch):
-        # The subprocess channel: the dryrun driver exports
-        # MX_RCNN_CACHE_STRICT_HOST=1 instead of threading a kwarg
-        # through every worker entry point.
-        monkeypatch.setattr(
-            compile_cache, "llvm_target_features", lambda: None
-        )
-        monkeypatch.delenv("MX_RCNN_CACHE_STRICT_HOST", raising=False)
-        base = compile_cache.cpu_fingerprint()
-        monkeypatch.setenv("MX_RCNN_CACHE_STRICT_HOST", "1")
-        assert compile_cache.cpu_fingerprint() != base
-        assert compile_cache.cpu_fingerprint() == compile_cache.cpu_fingerprint(
-            strict_host=True
-        )
-        monkeypatch.setenv("MX_RCNN_CACHE_STRICT_HOST", "0")
-        assert compile_cache.cpu_fingerprint() == base
-
-    def test_backend_fingerprint_threads_strict_through(self, monkeypatch):
-        monkeypatch.delenv("MX_RCNN_CACHE_STRICT_HOST", raising=False)
-        monkeypatch.setattr(
-            compile_cache, "llvm_target_features", lambda: None
-        )
-        assert (
-            compile_cache.backend_fingerprint(strict_host=True)
-            != compile_cache.backend_fingerprint()
-        )
-
-
-class TestBackendFingerprint:
-    """The generalized key bench.py/perf_breakdown.py now use: same
-    SIGILL-proofing as the CPU-only key, but correct on accelerators too
-    (keyed by chip generation + compiler stack, not host CPU)."""
-
-    def test_cpu_backend_delegates_to_cpu_fingerprint(self):
-        # The suite runs on the fake-CPU backend, so the generalized key
-        # must be exactly the battle-tested CPU key.
-        assert compile_cache.backend_fingerprint() == compile_cache.cpu_fingerprint()
-
-    def test_accelerator_key_moves_with_device_kind(self, monkeypatch):
-        import jax
-
-        class _Dev:
-            device_kind = "TPU v5e"
-
-        class _Dev2:
-            device_kind = "TPU v6e"
-
-        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        monkeypatch.setattr(jax, "devices", lambda: [_Dev()])
-        a = compile_cache.backend_fingerprint()
-        monkeypatch.setattr(jax, "devices", lambda: [_Dev2()])
-        b = compile_cache.backend_fingerprint()
-        assert a.startswith("tpu-") and b.startswith("tpu-")
-        assert a != b  # a v5e blob must never be replayed on a v6e
-
-    def test_configure_cache_creates_keyed_subdir(self, tmp_path):
-        import jax
-
-        prev = jax.config.jax_compilation_cache_dir
-        try:
-            d = compile_cache.configure_cache(str(tmp_path))
-            assert d == str(tmp_path / compile_cache.backend_fingerprint())
-            assert jax.config.jax_compilation_cache_dir == d
-        finally:
-            jax.config.update("jax_compilation_cache_dir", prev)
-
-    def test_configure_cache_prunes_stale_siblings(self, tmp_path):
+    @staticmethod
+    def _run(tmp_path, env_dir):
         import os
-        import time
+        import subprocess
+        import sys
+
+        env = dict(os.environ)
+        env["JAX_PLATFORMS"] = "cpu"
+        env["PYTHONPATH"] = compile_cache.REPO_ROOT
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)
+        if env_dir is not None:
+            env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+        code = (
+            "import jax, json;"
+            "from mx_rcnn_tpu.utils import compile_cache as c;"
+            "before = jax.config.jax_compilation_cache_dir;"
+            "d = c.configure_cache();"
+            "jax.jit(lambda x: x + 1)(1.0);"  # a compile, for good measure
+            "print(json.dumps([before, d, "
+            "jax.config.jax_compilation_cache_dir, c.cpu_fingerprint()]))"
+        )
+        out = subprocess.run(
+            [sys.executable, "-c", code], env=env, cwd=tmp_path,
+            capture_output=True, text=True, timeout=300, check=True,
+        ).stdout
+        import json
+
+        return json.loads(out.strip().splitlines()[-1])
+
+    def test_a_directory_placed_from_outside_is_left_alone(self, tmp_path):
+        import os
+
+        placed = str(tmp_path / "placed")
+        device_dir = compile_cache.DEVICE_CACHE_DIR
+        existed = os.path.isdir(device_dir)
+        stamp = os.stat(device_dir).st_mtime_ns if existed else None
+        before, returned, after, _ = self._run(tmp_path, placed)
+        # jax read the variable itself; configure_cache changed nothing:
+        # no fingerprint subdirectory, nothing created beside it.
+        assert before == returned == after == placed
+        assert not os.path.exists(placed) or os.listdir(placed) == []
+        # ...and nothing was written under <checkout>/.jax_cache.
+        assert os.path.isdir(device_dir) == existed
+        if existed:
+            assert os.stat(device_dir).st_mtime_ns == stamp
+
+    def test_unset_resolves_to_the_fixed_cpu_path(self, tmp_path):
+        import os
+
+        before, returned, after, fp = self._run(tmp_path, None)
+        assert before is None
+        assert returned == after == os.path.join(
+            compile_cache.CPU_CACHE_ROOT, fp
+        )
+        assert compile_cache.CPU_CACHE_ROOT == os.path.join(
+            compile_cache.REPO_ROOT, "tests", ".jax_cache"
+        )
+
+    def test_accelerators_take_the_fixed_checkout_path(self, monkeypatch):
+        # No device fingerprint in the path (jax's key carries backend,
+        # device kind and compiler version) and nothing from a temporary
+        # name, a pid, the clock or a boot id.
+        import os
 
         import jax
 
-        # Four stale sibling dirs + ours: keep-3 prunes the oldest.
-        for i, name in enumerate(["aaa", "bbb", "ccc", "ddd"]):
-            p = tmp_path / name
-            p.mkdir()
-            t = time.time() - 1000 + i
-            os.utime(p, (t, t))
-        prev = jax.config.jax_compilation_cache_dir
-        try:
-            d = compile_cache.configure_cache(str(tmp_path))
-        finally:
-            jax.config.update("jax_compilation_cache_dir", prev)
-        survivors = {q.name for q in tmp_path.iterdir()}
-        assert "aaa" not in survivors  # oldest pruned
-        assert os.path.basename(d) not in ("aaa",)
+        updates = {}
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(
+            jax.config, "update", lambda k, v: updates.__setitem__(k, v)
+        )
+        monkeypatch.setattr(
+            type(jax.config), "jax_compilation_cache_dir", None,
+            raising=False,
+        )
+        assert compile_cache.configure_cache() == os.path.join(
+            compile_cache.REPO_ROOT, ".jax_cache"
+        )
+        assert updates["jax_compilation_cache_dir"] == os.path.join(
+            compile_cache.REPO_ROOT, ".jax_cache"
+        )
+
+    def test_an_earlier_call_wins(self):
+        # conftest configured this process; a CLI main() called from a
+        # test must not move the cache.
+        import jax
+
+        placed = jax.config.jax_compilation_cache_dir
+        assert placed
+        assert compile_cache.configure_cache() == placed
+        assert jax.config.jax_compilation_cache_dir == placed
+
+
+class TestOneUpdateSite:
+    """No entry point points the cache anywhere itself."""
+
+    ENTRY_POINTS = (
+        "mx_rcnn_tpu/cli/train_cli.py", "mx_rcnn_tpu/cli/eval_cli.py",
+        "mx_rcnn_tpu/cli/demo_cli.py", "mx_rcnn_tpu/cli/alternate_cli.py",
+        "bench.py", "chip_smoke.py", "__graft_entry__.py",
+        "tools/perf_breakdown.py", "tools/train_soak.py", "tools/chaos.py",
+        "tools/serve_host.py", "tools/loadgen.py", "tools/soak.py",
+        "tools/deploy_watch.py",
+        "tests/conftest.py", "tests/_dist_worker.py",
+        "tests/_kernels_tpu_worker.py", "tests/_overfit_tpu_worker.py",
+    )
+
+    def test_only_compile_cache_updates_the_option(self):
+        import os
+        import re
+
+        update = re.compile(
+            r"update\(\s*[\"']jax_compilation_cache_dir[\"']"
+        )
+        hits = []
+        for root, dirs, files in os.walk(compile_cache.REPO_ROOT):
+            dirs[:] = [d for d in dirs if not d.startswith(".")]
+            for fn in files:
+                if fn.endswith(".py"):
+                    path = os.path.join(root, fn)
+                    with open(path) as f:
+                        if update.search(f.read()):
+                            hits.append(
+                                os.path.relpath(path, compile_cache.REPO_ROOT)
+                            )
+        assert hits == ["mx_rcnn_tpu/utils/compile_cache.py"], hits
+
+    def test_every_entry_point_that_compiles_calls_the_one_function(self):
+        import os
+
+        for rel in self.ENTRY_POINTS:
+            with open(os.path.join(compile_cache.REPO_ROOT, rel)) as f:
+                assert "configure_cache()" in f.read(), rel

@@ -93,6 +93,17 @@ class TestHierarchicalTopK:
         _assert_bitwise(hv, ev)
         _assert_bitwise(hi, ei)
 
+    def test_bfloat16_scores(self, rng):
+        # The dtype the bf16 presets rank (the head emits bf16 under the
+        # mixed policy).  Found by PR 21's first run at recipe width on
+        # the chip: the padded tail's floor value must come from jnp's
+        # dtype lattice — numpy calls bfloat16 kind 'V' and refused it.
+        s = jnp.asarray(rng.randn(5_000), jnp.bfloat16)
+        hv, hi = hierarchical_top_k(s, 64, block=999)
+        ev, ei = lax.top_k(s, 64)
+        _assert_bitwise(hv, ev)
+        _assert_bitwise(hi, ei)
+
     def test_k_larger_than_operand_raises(self):
         with pytest.raises(ValueError):
             hierarchical_top_k(jnp.zeros(10), 11)

@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from mx_rcnn_tpu.config import get_config
@@ -305,11 +304,15 @@ def _mesh_step(built, **plan_kw):
     jax.device_count() < 8, reason="needs the 8-device fake mesh"
 )
 class TestBucketedPmean:
-    def test_regrouping_is_exact_and_splits_the_collective(self):
-        # Four 1-MiB leaves at bucket_mb=1 -> four buckets -> four psum
-        # eqns where the single-reduce form traces one; values bitwise
-        # equal (pmean over a list reduces each leaf independently —
-        # grouping changes the schedule, never the numerics).
+    def test_regrouping_is_exact_and_invisible_in_the_trace(self):
+        # Four 1-MiB leaves, one bucket each (bucket_mb=1) or all in one
+        # (bucket_mb=0): values bitwise equal — pmean over a list reduces
+        # each leaf independently, so grouping never changes numerics.
+        # Under jax 0.9.0 it does not change the TRACE either: pmean of a
+        # list traces to one psum PER LEAF whatever the grouping, so both
+        # forms show four psum eqns, and how many all-reduce ops run is
+        # the XLA all-reduce combiner's decision (one on XLA:CPU for
+        # both; the on-chip count is in PERF.md, "Bring-up, PR 21").
         mesh = make_mesh()
         tree = {
             k: jnp.full((512, 512), float(i), jnp.float32)
@@ -317,13 +320,13 @@ class TestBucketedPmean:
         }
 
         def reduced(mb):
-            return shard_map(
+            return jax.shard_map(
                 lambda t: _bucketed_pmean(t, mb), mesh=mesh,
-                in_specs=(P(),), out_specs=P(), check_rep=False,
+                in_specs=(P(),), out_specs=P(), check_vma=False,
             )
 
         assert str(jax.make_jaxpr(reduced(1))(tree)).count("psum") == 4
-        assert str(jax.make_jaxpr(reduced(0))(tree)).count("psum") == 1
+        assert str(jax.make_jaxpr(reduced(0))(tree)).count("psum") == 4
         _assert_trees_bitwise_equal(reduced(1)(tree), reduced(0)(tree))
 
     def test_plan_gating(self):

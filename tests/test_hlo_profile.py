@@ -213,3 +213,21 @@ class TestMfuReportTool:
             assert comp in comps
         assert on_disk["default_layout"]["total_tflops_per_step"] > 0
         assert report["default_layout"]["layout"]["stem_s2d"] is True
+
+
+class TestPeakTable:
+    """utils/flops.py::PEAK_BF16_FLOPS — the one table every MFU figure
+    divides by, keyed by the device_kind jax reports."""
+
+    def test_known_kind(self):
+        from mx_rcnn_tpu.utils.flops import PEAK_BF16_FLOPS, peak_bf16_flops
+
+        for kind, peak in PEAK_BF16_FLOPS.items():
+            assert peak_bf16_flops(kind) == peak > 1e12
+
+    @pytest.mark.parametrize("kind", ["cpu", "TPU v9 imaginary", ""])
+    def test_unknown_kind_is_an_error_not_a_default(self, kind):
+        from mx_rcnn_tpu.utils.flops import peak_bf16_flops
+
+        with pytest.raises(ValueError, match="no published bf16 peak"):
+            peak_bf16_flops(kind)

@@ -122,3 +122,38 @@ class TestLoaderUsesNative:
             np.asarray(batches[0][0].gt_boxes), np.asarray(batches[1][0].gt_boxes),
             atol=1e-4,
         )
+
+
+class TestLoadedLibraryIsTheCurrentBuild:
+    """native/build.py names the library by its source's hash, so a stale
+    ``_native*.so`` lying in the package directory (git-ignored, and
+    copied along by anything that copies the tree) is rebuilt, not
+    loaded."""
+
+    def test_stale_library_is_rebuilt_not_loaded(self, tmp_path, monkeypatch):
+        import os
+        import shutil
+
+        from mx_rcnn_tpu.native import build, lib
+
+        pkg = tmp_path / "native"
+        (pkg / "src").mkdir(parents=True)
+        shutil.copy(build.SRC, pkg / "src" / "native.cc")
+        monkeypatch.setattr(build, "PKG_DIR", str(pkg))
+        monkeypatch.setattr(build, "SRC", str(pkg / "src" / "native.cc"))
+        monkeypatch.setattr(lib, "_LIB", None)
+        # Two stale files: the pre-hash name, and a build of some other
+        # source.  Neither is a library at all — loading either would
+        # raise, so a green run proves neither was opened.
+        (pkg / "_native.so").write_bytes(b"not a library")
+        (pkg / "_native.000000000000.so").write_bytes(b"not a library")
+        assert lib.available()
+        want = os.path.basename(build.so_path())
+        assert want not in ("_native.so", "_native.000000000000.so")
+        assert os.path.exists(pkg / want)
+        # The build cleared the hash-named stale sibling.
+        assert not os.path.exists(pkg / "_native.000000000000.so")
+        # A changed source is another name: the old build is not reused.
+        with open(pkg / "src" / "native.cc", "a") as f:
+            f.write("\n// changed\n")
+        assert os.path.basename(build.so_path()) != want

@@ -368,3 +368,68 @@ class TestPallasNms:
                 nms_mask_pallas(boxes, scores, 0.5, valid, interpret=True)
             )
             np.testing.assert_array_equal(out, ref)
+
+
+class TestNoHiddenFallback:
+    """detection/graph.py::_pool_rois: a Pallas request that cannot be
+    honoured RAISES when the backend is a TPU, and off-TPU the XLA gather
+    is the design (quiet).  The backend is mocked — the point is the
+    decision, which runs at trace time in Python."""
+
+    @staticmethod
+    def _pool(channels):
+        from mx_rcnn_tpu.config import get_config
+        from mx_rcnn_tpu.detection import graph
+
+        rng = np.random.RandomState(0)
+        feats = {
+            lvl: jnp.asarray(
+                rng.randn(1, 64 >> (lvl - 2), 64 >> (lvl - 2), channels),
+                jnp.float32,
+            )
+            for lvl in (2, 3, 4, 5)
+        }
+        rois = jnp.asarray([[[4.0, 4.0, 60.0, 50.0]] * 4], jnp.float32)
+        cfg = get_config("tiny_synthetic").model
+        assert cfg.rcnn.roi_align_impl == "pallas"  # the preset default
+        graph.LAST_POOL_IMPL = None
+        out = graph._pool_rois(cfg, feats, rois, 7, (2, 3, 4, 5))
+        return out, graph.LAST_POOL_IMPL
+
+    def test_unsupported_layout_raises_on_a_tpu(self, monkeypatch):
+        monkeypatch.delenv("MX_RCNN_PALLAS_INTERPRET", raising=False)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        # 64 channels: not a multiple of the 128-lane dim the kernel's
+        # window DMA slices.
+        with pytest.raises(ValueError, match="roi_align_impl='pallas'"):
+            self._pool(channels=64)
+
+    def test_off_tpu_takes_the_xla_gather_quietly(self, monkeypatch, caplog):
+        monkeypatch.delenv("MX_RCNN_PALLAS_INTERPRET", raising=False)
+        with caplog.at_level("INFO", logger="mx_rcnn_tpu"):
+            for channels in (64, 128):  # unsupported and supported layouts
+                out, impl = self._pool(channels)
+                assert impl == "xla"
+                assert out.shape == (1, 4, 7, 7, channels)
+        assert not caplog.records
+
+    def test_the_xla_gather_can_be_asked_for_by_name_on_a_tpu(
+        self, monkeypatch
+    ):
+        import dataclasses
+
+        from mx_rcnn_tpu.config import get_config
+        from mx_rcnn_tpu.detection import graph
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        cfg = get_config("tiny_synthetic").model
+        cfg = dataclasses.replace(
+            cfg, rcnn=dataclasses.replace(cfg.rcnn, roi_align_impl="xla")
+        )
+        feats = {
+            lvl: jnp.zeros((1, 64 >> (lvl - 2), 64 >> (lvl - 2), 64))
+            for lvl in (2, 3, 4, 5)
+        }
+        rois = jnp.asarray([[[4.0, 4.0, 60.0, 50.0]]], jnp.float32)
+        graph._pool_rois(cfg, feats, rois, 7, (2, 3, 4, 5))
+        assert graph.LAST_POOL_IMPL == "xla"

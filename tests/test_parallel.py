@@ -192,6 +192,36 @@ class TestShardedPallasRoiAlign:
             np.asarray(out), np.asarray(ref), atol=1e-4
         )
 
+    def test_sharded_kernel_lowers_for_the_tpu_platform(self, rng):
+        """What interpret mode cannot see: jax refuses to lower a Mosaic
+        kernel under a shard_map that leaves ANY mesh axis to GSPMD, even
+        one of size 1.  Lowering for the TPU platform needs no TPU, so the
+        real (non-interpret) kernel call is lowered here, forward and
+        backward, on the (data, model) mesh the train step uses."""
+        from mx_rcnn_tpu.ops.pallas.roi_align import sharded_multilevel_roi_align
+        from mx_rcnn_tpu.parallel.mesh import DATA_AXIS
+
+        mesh = make_mesh()
+        assert len(mesh.axis_names) == 2  # the axis that must be manual too
+        b = mesh.shape[DATA_AXIS]
+        pyr = {
+            l: jnp.asarray(
+                rng.rand(b, 64 >> (l - 2), 88 >> (l - 2), 128), jnp.bfloat16
+            )
+            for l in (2, 3, 4, 5)
+        }
+        rois = jnp.asarray([[[4.0, 4.0, 60.0, 50.0]] * 8] * b, jnp.float32)
+
+        def loss(p, rr):
+            out = sharded_multilevel_roi_align(p, rr, 7, 2, mesh, DATA_AXIS)
+            return jnp.sum(out.astype(jnp.float32))
+
+        text = (
+            jax.jit(jax.value_and_grad(loss)).trace(pyr, rois)
+            .lower(lowering_platforms=("tpu",)).as_text()
+        )
+        assert text.count("tpu_custom_call") == 2  # forward + backward
+
     def test_sharded_train_step_pallas_matches_xla(self, monkeypatch):
         """Full sharded train step, pallas-shardmap vs xla backend: same
         seed, same batch, (near-)identical metrics — and the trace must

@@ -30,7 +30,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from tools.loadgen import _hermetic_cpu  # noqa: E402
+from tools.loadgen import _fake_cpu_devices  # noqa: E402
 
 
 def _build_fleet(args):
@@ -54,7 +54,9 @@ def _build_fleet(args):
     from mx_rcnn_tpu.config import get_config
     from mx_rcnn_tpu.detection import TwoStageDetector, init_detector
     from mx_rcnn_tpu.serve import build_fleet
+    from mx_rcnn_tpu.utils.compile_cache import configure_cache
 
+    configure_cache()
     cfg = get_config(args.config)
     variables = init_detector(
         TwoStageDetector(cfg=cfg.model), jax.random.PRNGKey(0),
@@ -195,7 +197,7 @@ def main(argv=None) -> int:
         import tempfile
 
         args.obs_dir = tempfile.mkdtemp(prefix="deploy_watch_obs_")
-    _hermetic_cpu(args.replicas + 1)  # +1: the spare shadow replica
+    _fake_cpu_devices(args.replicas + 1)  # +1: the spare shadow replica
 
     rec = run_watch(args)
     print(json.dumps(rec))

@@ -62,7 +62,8 @@ traffic; the BENCH line reports ``cache_hits`` and ``coalesced``.
 Prints diagnostics to stderr and exactly one ``BENCH_serving`` JSON line
 as the LAST line on stdout:
 
-    {"bench": "serving", "replicas": 2, "hosts": ["local"],
+    {"bench": "serving", "platform": "cpu", "device_kind": "cpu",
+     "n_devices": 2, "replicas": 2, "hosts": ["local"],
      "qps": 6.0, "duration_s": 15.0,
      "submitted": 90, "completed": 88, "shed": 2, "failed": 0,
      "p50_s": 0.21, "p99_s": 0.57, "max_s": 0.61,
@@ -191,8 +192,13 @@ def tenant_table_string(specs: list[dict]) -> str:
     )
 
 
-def _hermetic_cpu(n_devices: int) -> None:
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+def _fake_cpu_devices(n_devices: int) -> None:
+    """One fake device per replica — only on a CPU the caller NAMED
+    (``JAX_PLATFORMS=cpu``: tests and CI).  With nothing said the fleet
+    runs on what jax finds, one replica per chip, and the BENCH line says
+    which (``platform`` / ``device_kind`` / ``n_devices``)."""
+    if os.environ.get("JAX_PLATFORMS", "").split(",")[0] != "cpu":
+        return
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (
@@ -332,7 +338,14 @@ def run_bench(args: argparse.Namespace) -> dict:
             f"serve.tenancy.table={tenant_table_string(tenant_specs)}",
         ])
     fleet, hosts = _build_driver(args, cfg)
+    # Remote surfaces are tools/serve_host.py processes: CPU-only.
+    args._device = {"platform": "cpu", "device_kind": "cpu", "n_devices": None}
     if fleet is None:
+        from mx_rcnn_tpu.utils.compile_cache import configure_cache
+        from mx_rcnn_tpu.utils.runtime import device_record
+
+        configure_cache()
+        args._device = device_record()
         variables = init_detector(
             TwoStageDetector(cfg=cfg.model), jax.random.PRNGKey(0),
             cfg.data.image_size,
@@ -675,6 +688,9 @@ def _finish(args, fleet, latencies, submitted, shed, failed, killed_rid,
     )
     rec = {
         "bench": "serving",
+        # Where the model ran: a record from a CPU is a count of requests,
+        # never a latency of the chip.
+        **args._device,
         "replicas": args.replicas,
         "hosts": hosts,
         "qps": args.qps,
@@ -849,7 +865,7 @@ def main(argv=None) -> int:
         if all(e["role"] != "flooder" for e in tenant_specs):
             p.error("--assert-tenant-isolation needs a role=flooder "
                     "tenant to remove in the baseline phase")
-    _hermetic_cpu(args.replicas)
+    _fake_cpu_devices(args.replicas)
 
     baseline = None
     if args.assert_tenant_isolation is not None:

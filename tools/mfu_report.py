@@ -43,7 +43,8 @@ LEGACY_LAYOUT_OVERRIDES = (
 def _variant(cfg, args, label: str) -> dict:
     import jax
 
-    from bench import V5E_PEAK_BF16_FLOPS, _synthetic_batch
+    from bench import _synthetic_batch
+    from mx_rcnn_tpu.utils.flops import peak_bf16_flops
     from mx_rcnn_tpu.train.loop import build_all
     from mx_rcnn_tpu.utils.hlo_profile import (
         component_report,
@@ -80,7 +81,12 @@ def _variant(cfg, args, label: str) -> dict:
         data,
         steps_per_call=k,
         dt_per_step=dt_per_step,
-        peak_flops=V5E_PEAK_BF16_FLOPS,
+        # MFU needs a measured time on a chip whose peak is published;
+        # an unknown device_kind (a CPU included) raises.
+        peak_flops=(
+            peak_bf16_flops(jax.devices()[0].device_kind)
+            if dt_per_step is not None else None
+        ),
     )
     report["layout"] = {
         "stem_s2d": cfg.model.backbone.stem_s2d,

@@ -67,7 +67,7 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from tools.loadgen import (
-    _hermetic_cpu,
+    _fake_cpu_devices,
     _occupancy_summary,
     _percentile,
     make_profile,
@@ -156,7 +156,9 @@ def _build_real_fleet(args, tenancy=None):
     from mx_rcnn_tpu.config import get_config
     from mx_rcnn_tpu.detection import TwoStageDetector, init_detector
     from mx_rcnn_tpu.serve import build_fleet
+    from mx_rcnn_tpu.utils.compile_cache import configure_cache
 
+    configure_cache()
     cfg = get_config(args.config)
     variables = init_detector(
         TwoStageDetector(cfg=cfg.model), jax.random.PRNGKey(0),
@@ -610,9 +612,20 @@ def run_soak(args: argparse.Namespace) -> dict:
             "p99_s": round(_percentile(vals, 0.99), 4),
             "max_s": round(vals[-1], 4),
         }
+    if args.fake_engines:
+        # No model ran: the fakes sleep a fixed service time on the host.
+        device = {"platform": "none (fake engines)", "device_kind": None,
+                  "n_devices": None}
+    else:
+        from mx_rcnn_tpu.utils.runtime import device_record
+
+        device = device_record()
     rec = {
         "bench": "soak",
         "engine_mode": mode,
+        # Where the model ran: a record from a CPU (or from fakes) is a
+        # count of requests, never a latency of the chip.
+        **device,
         "duration_s": args.duration,
         "profile": {
             "base": "sine", "burst": "spike", "qps": args.qps,
@@ -758,7 +771,7 @@ def main(argv=None) -> int:
         # --deploy needs jax either way: the candidate checkpoint is
         # saved/restored through train/checkpoint.py.  +1 device slot
         # covers the out-of-rotation shadow replica.
-        _hermetic_cpu(args.max_replicas + 1)
+        _fake_cpu_devices(args.max_replicas + 1)
 
     rec = run_soak(args)
 

@@ -209,13 +209,11 @@ def main() -> None:
 
     import jax
 
-    # Same persistent compile cache as bench.py: repeat soak invocations
-    # (smoke run, then the real run) skip the multi-minute step compile.
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    jax.config.update(
-        "jax_compilation_cache_dir", os.path.join(repo, ".jax_cache")
-    )
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 10)
+    # Persistent compile cache: repeat soak invocations (smoke run, then
+    # the real run) skip the multi-minute step compile.
+    from mx_rcnn_tpu.utils.compile_cache import configure_cache
+
+    configure_cache()
 
     from mx_rcnn_tpu.cli.common import setup_logging
     from mx_rcnn_tpu.train.loop import train
