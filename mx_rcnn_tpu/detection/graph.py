@@ -611,7 +611,8 @@ def forward_train(model: TwoStageDetector, variables, rng: jax.Array, batch: Bat
     b = images.shape[0]
     rng_assign = rng_sample = None
     if rngs is None:
-        rng_assign, rng_sample = jax.random.split(rng)
+        with jax.named_scope("rng"):
+            rng_assign, rng_sample = jax.random.split(rng)
 
     # gt_ignore=None keeps the cheaper no-IoA graph (in_axes=None maps the
     # leafless None through vmap untouched; the callees skip the overlap
@@ -704,10 +705,11 @@ def forward_train(model: TwoStageDetector, variables, rng: jax.Array, batch: Bat
         cls_logits, box_deltas, samples, cfg.rcnn.class_agnostic
     )
 
-    total = (
-        cfg.rpn.loss_weight * (rpn_cls + rpn_box)
-        + cfg.rcnn.loss_weight * (rcnn_cls + rcnn_box)
-    )
+    with jax.named_scope("total_loss"):
+        total = (
+            cfg.rpn.loss_weight * (rpn_cls + rpn_box)
+            + cfg.rcnn.loss_weight * (rcnn_cls + rcnn_box)
+        )
     metrics = {
         # Names mirror the reference's six EvalMetrics (rcnn/core/metric.py).
         "RPNAcc": rpn_acc,

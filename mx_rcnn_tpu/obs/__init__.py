@@ -11,11 +11,14 @@ across PRs 3-9 but recorded as scattered log strings:
 
 The plane is a process-wide singleton with two modes:
 
-* **Unconfigured** (the default — every existing test and tool): events
-  still derive their log lines (obs/events.py) and land in the flight
-  ring; metrics still count in-process; nothing touches the filesystem
-  and no endpoint binds.  Steady-state cost is a dict append.
-* **Configured** (``obs.configure(out_dir=...)`` — wired from the train
+* **Unconfigured / buffer-only** (the default — every existing test and
+  tool, and a benchmark run): events still derive their log lines
+  (obs/events.py) and land in the flight ring; finished spans land in the
+  tracer's own buffer (``obs.tracer().recent()``) and the flight
+  recorder's ring for spans; metrics
+  still count in-process; nothing touches the filesystem and no endpoint
+  binds.  Steady-state cost is an append.
+* **Configured / durable** (``obs.configure(out_dir=...)`` — wired from the train
   loop via ``cfg.obs``, from ``tools/loadgen.py`` via ``--obs-dir``, and
   from chaos children): events append to ``<out_dir>/journal.jsonl``,
   finished spans to ``<out_dir>/spans.jsonl``, flight dumps to
@@ -79,14 +82,15 @@ _status_providers: dict[str, Callable[[], dict]] = {}
 
 
 def _span_sink(s: Span) -> None:
-    rec = s.to_chrome()
-    _flight.record({"type": "span", **rec})
+    _flight.record(s)  # its own ring; rendered when read, not here
     fd = _spans_fd
     if fd is not None and _spans_on:
         import json
 
         try:
-            os.write(fd, (json.dumps(rec, default=str) + "\n").encode())
+            os.write(
+                fd, (json.dumps(s.to_chrome(), default=str) + "\n").encode()
+            )
         except OSError:
             pass
 
@@ -198,6 +202,7 @@ def reset() -> None:
         close()
         _registry = Registry()
         _flight = FlightRecorder()
+        _tracer.clear()
         _run_id = "-"
         _status_providers.clear()
 
@@ -311,6 +316,9 @@ def tracer() -> Tracer:
 
 
 def spans_enabled() -> bool:
+    """True when finished spans are also written to ``spans.jsonl`` (the
+    durable mode).  The serving path asks before it builds a request's
+    span tree; the training path records its few spans either way."""
     return _spans_fd is not None and _spans_on
 
 

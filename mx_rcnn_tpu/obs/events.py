@@ -92,6 +92,13 @@ def _fmt_rollback_restored(p: dict) -> str:
     ).format(**p)
 
 
+def _fmt_recompiled(p: dict) -> str:
+    return (
+        "step {step}: {fun_name} was compiled again ({seconds:.2f}s) — a "
+        "shape, dtype or static argument changed after the first interval"
+    ).format(**p)
+
+
 def _fmt_loss_spike(p: dict) -> str:
     return (
         "guardian: loss spike at step {step} — {loss:.4f} is "
@@ -365,6 +372,7 @@ EVENTS: dict[str, tuple[int, Callable[[dict], str]]] = {
     "guardian_rollback": (logging.ERROR, _fmt_guardian_rollback),
     "rollback_restored": (logging.WARNING, _fmt_rollback_restored),
     "guardian_loss_spike": (logging.WARNING, _fmt_loss_spike),
+    "recompiled": (logging.WARNING, _fmt_recompiled),
     "checkpoint_saved": (logging.INFO, _fmt_ckpt_saved),
     "checkpoint_restored": (logging.INFO, _fmt_ckpt_restored),
     "preempt_drain": (logging.WARNING, _fmt_preempt),

@@ -1,10 +1,15 @@
-"""Flight recorder: a bounded in-memory ring of recent events + spans,
+"""Flight recorder: bounded in-memory rings of recent events and spans,
 dumped to a postmortem artifact when something dies.
 
 Every event emitted through the plane (configured or not) and every
-finished span lands in the ring — a fixed-size ``collections.deque``,
-so steady-state cost is one dict append and old entries fall off the
-back.  On a trigger (engine watchdog fire, ``engine.kill``, fleet
+finished span lands here — events in one fixed-size ``collections.deque``
+and spans in another of the same size, so steady-state cost is one append,
+old entries fall off the back, and the few spans a training step records
+never push out the rare events a postmortem is read for
+(``checkpoint_saved``, ``guardian_loss_spike``, ``recompiled``).  A span
+goes in as the object it is and becomes its Chrome-trace dict only when the
+rings are read (``entries``, ``dump``: one list, in order of time): the hot
+path builds none.  On a trigger (engine watchdog fire, ``engine.kill``, fleet
 replica retirement, guardian ``TrainingDiverged``, or an unhandled
 exception via the installed crash handler) the ring is written out as
 ``flight_<trigger>_<pid>_<n>.json`` under the configured obs dir: the
@@ -29,22 +34,42 @@ __all__ = ["FlightRecorder"]
 
 
 class FlightRecorder:
-    """Bounded ring + dump-on-trigger.  Thread-safe."""
+    """Bounded rings (``size`` events, ``size`` spans) + dump-on-trigger.
+    Thread-safe."""
 
     def __init__(self, size: int = 512) -> None:
-        self._ring: collections.deque[dict] = collections.deque(maxlen=size)
+        self._ring: collections.deque = collections.deque(maxlen=size)
+        self._spans: collections.deque = collections.deque(maxlen=size)
         self._lock = threading.Lock()
         self._dumps = 0
         self.out_dir: Optional[str] = None
         self.run_id: str = "-"
 
-    def record(self, entry: dict) -> None:
+    def record(self, entry) -> None:
+        """``entry``: an event's dict, or a finished span (anything with
+        ``to_chrome()``, rendered when the rings are read; or a dict of
+        ``"type": "span"`` that ``entries`` rendered before)."""
+        is_event = isinstance(entry, dict) and entry.get("type") != "span"
         with self._lock:
-            self._ring.append(entry)
+            (self._ring if is_event else self._spans).append(entry)
 
     def entries(self) -> list[dict]:
+        """Events and spans as dicts, oldest first (an event by its
+        ``ts_mono_ns``, a span by its end; entries without a time keep
+        their place at the front)."""
         with self._lock:
-            return list(self._ring)
+            events, spans = list(self._ring), list(self._spans)
+        spans = [
+            e if isinstance(e, dict) else {"type": "span", **e.to_chrome()}
+            for e in spans
+        ]
+
+        def when_ns(e: dict) -> float:
+            if e.get("type") == "span":
+                return (e.get("ts", 0) + e.get("dur", 0)) * 1e3
+            return e.get("ts_mono_ns", 0)
+
+        return sorted(events + spans, key=when_ns)
 
     def dump(self, trigger: str, extra: Optional[dict] = None
              ) -> Optional[str]:
@@ -55,8 +80,8 @@ class FlightRecorder:
             out_dir = self.out_dir
             if not out_dir:
                 return None
+            entries = self.entries()
             with self._lock:
-                entries = list(self._ring)
                 n = self._dumps
                 self._dumps += 1
             safe = "".join(

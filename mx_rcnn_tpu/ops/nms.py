@@ -92,7 +92,10 @@ def nms_mask(
 
         def body(state):
             keep, _, it = state
-            new_keep = svalid & ~jnp.any(suppress & keep[:, None], axis=0)
+            with jax.named_scope("nms_sweep"):
+                new_keep = svalid & ~jnp.any(
+                    suppress & keep[:, None], axis=0
+                )
             return new_keep, keep, it + 1
 
         init = (svalid, jnp.zeros(n, dtype=bool), jnp.asarray(0, jnp.int32))
@@ -104,7 +107,13 @@ def nms_mask(
 
         def body(state):
             keep, _ = state
-            new_keep = svalid & ~jnp.any(suppress & keep[:, None], axis=0)
+            # One sweep = one run of this scope's ops: the trace shows a
+            # loop body's ops once per iteration, so sweeps are countable
+            # per step (perfbench/metrics/nms_sweeps.train.py).
+            with jax.named_scope("nms_sweep"):
+                new_keep = svalid & ~jnp.any(
+                    suppress & keep[:, None], axis=0
+                )
             return new_keep, keep
 
         init = (svalid, jnp.zeros(n, dtype=bool))

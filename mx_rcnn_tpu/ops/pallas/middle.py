@@ -212,6 +212,7 @@ def fused_middle_levels(
         out_specs=pl.BlockSpec((1, 8, n_pad), lambda l: (l, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((lvls, 8, n_pad), jnp.float32),
         interpret=interpret,
+        name="fused_middle",
     )(data, hw)
 
     boxes = jnp.swapaxes(out[:, 0:4, :k], 1, 2)             # (L, k, 4)

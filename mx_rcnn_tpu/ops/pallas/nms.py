@@ -111,6 +111,7 @@ def nms_mask_pallas(
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((1, n_pad), jnp.int32),
         interpret=interpret,
+        name="nms_sweep_pallas",
     )(data)[0, :n] > 0
 
     return jnp.zeros(n, dtype=bool).at[order].set(keep_sorted)

@@ -460,6 +460,9 @@ def multilevel_roi_align_pallas(
             (n + n_pad, output_size, output_size, c), feats[0].dtype
         ),
         interpret=interpret,
+        # The name the kernel runs under in a device trace: the benchmark's
+        # roofline readers find it by ``roi_align`` without ``bwd``.
+        name="roi_align_fwd",
     )(roi_params, *feats)
     out = out[:n].reshape(b, r_per, output_size, output_size, c)
     return out if batched else out[0]
@@ -675,6 +678,7 @@ def multilevel_roi_align_bwd_pallas(
         ],
         input_output_aliases={2 + i: i for i in range(len(levels))},
         interpret=interpret,
+        name="roi_align_bwd",  # read by name: ``roi_align.*bwd``
     )(roi_params, g2, *zeros)
 
     out = {}

@@ -97,11 +97,12 @@ def generate_proposals(
             scores, deltas, anchors, image_height, image_width,
             pre_nms_top_n, min_size, topk_impl, topk_recall, topk_block,
         )
-        keep_idx, keep_valid = nms_indices(
-            boxes, masked_scores, nms_threshold, post_nms_top_n,
-            sweep_cap=nms_sweep_cap, nms_impl=nms_impl,
-            interpret=pallas_interpret,
-        )
+        with jax.named_scope("nms"):
+            keep_idx, keep_valid = nms_indices(
+                boxes, masked_scores, nms_threshold, post_nms_top_n,
+                sweep_cap=nms_sweep_cap, nms_impl=nms_impl,
+                interpret=pallas_interpret,
+            )
     rois = jnp.take(boxes, keep_idx, axis=0) * keep_valid[:, None]
     out_scores = jnp.where(keep_valid, jnp.take(masked_scores, keep_idx), 0.0)
     return Proposals(rois=rois, scores=out_scores, valid=keep_valid)
@@ -121,31 +122,36 @@ def _topk_candidates(
     """
     a = scores.shape[0]
     k = min(pre_nms_top_n, a)
-    # snap(): top-k ranking and the NMS visit order are discrete in the
-    # scores; snapped scores + index-stable tie-breaks (lax.top_k and
-    # argsort both prefer the lower index) give the same candidate ordering
-    # in every compilation of this graph (see geometry.boxes.snap).
-    scores = snap(scores)
-
-    if topk_impl == "approx" and k < a:
-        top_scores, top_idx = lax.approx_max_k(
-            scores, k, recall_target=topk_recall
-        )
-    elif topk_impl == "hier":
-        # Blocked exact top-k — bit-identical to lax.top_k including the
-        # snapped-score index-stable tie-breaks (proof in ops/topk.py).
-        top_scores, top_idx = hierarchical_top_k(scores, k, block=topk_block)
-    elif topk_impl in ("exact", "approx"):
-        top_scores, top_idx = lax.top_k(scores, k)
-    else:
+    if topk_impl not in ("hier", "exact", "approx"):
         raise ValueError(
             f"topk_impl must be 'hier', 'exact' or 'approx', got {topk_impl!r}"
         )
-    return (
-        top_scores,
-        jnp.take(deltas, top_idx, axis=0),
-        jnp.take(anchors, top_idx, axis=0),
-    )
+    with jax.named_scope("topk"):
+        # snap(): top-k ranking and the NMS visit order are discrete in
+        # the scores; snapped scores + index-stable tie-breaks (lax.top_k
+        # and argsort both prefer the lower index) give the same candidate
+        # ordering in every compilation of this graph (see
+        # geometry.boxes.snap).
+        scores = snap(scores)
+
+        if topk_impl == "approx" and k < a:
+            top_scores, top_idx = lax.approx_max_k(
+                scores, k, recall_target=topk_recall
+            )
+        elif topk_impl == "hier":
+            # Blocked exact top-k — bit-identical to lax.top_k including
+            # the snapped-score index-stable tie-breaks (proof in
+            # ops/topk.py).
+            top_scores, top_idx = hierarchical_top_k(
+                scores, k, block=topk_block
+            )
+        else:
+            top_scores, top_idx = lax.top_k(scores, k)
+        return (
+            top_scores,
+            jnp.take(deltas, top_idx, axis=0),
+            jnp.take(anchors, top_idx, axis=0),
+        )
 
 
 def _pre_nms_candidates(
@@ -160,18 +166,19 @@ def _pre_nms_candidates(
         scores, deltas, anchors, pre_nms_top_n, topk_impl, topk_recall,
         topk_block,
     )
-    boxes = decode_boxes(top_deltas, top_anchors)
-    boxes = clip_boxes(boxes, image_height, image_width)
-    # snap to a 1/256-px grid: decode/clip arithmetic carries a few ulps of
-    # cross-compilation noise at coordinate scale (~1e-5 px), which is the
-    # same magnitude as the fine IoU snap grid downstream — snapping the
-    # coordinates themselves makes every IoU consumer (NMS here, roi
-    # sampling later) see bit-identical boxes.  1/256 px is far below
-    # anything detection quality can notice.
-    boxes = snap(boxes, bits=8)
+    with jax.named_scope("decode"):
+        boxes = decode_boxes(top_deltas, top_anchors)
+        boxes = clip_boxes(boxes, image_height, image_width)
+        # snap to a 1/256-px grid: decode/clip arithmetic carries a few
+        # ulps of cross-compilation noise at coordinate scale (~1e-5 px),
+        # which is the same magnitude as the fine IoU snap grid downstream
+        # — snapping the coordinates themselves makes every IoU consumer
+        # (NMS here, roi sampling later) see bit-identical boxes.  1/256 px
+        # is far below anything detection quality can notice.
+        boxes = snap(boxes, bits=8)
 
-    ok = valid_box_mask(boxes, min_size=min_size)
-    masked_scores = jnp.where(ok, top_scores, -jnp.inf)
+        ok = valid_box_mask(boxes, min_size=min_size)
+        masked_scores = jnp.where(ok, top_scores, -jnp.inf)
     return boxes, masked_scores
 
 
@@ -270,25 +277,26 @@ def generate_fpn_proposals(
             ]
         )                                                   # (L, kmax)
 
-        if nms_impl == "pallas":
-            # One sequential-sweep kernel launch per level; the sweeps
-            # would serialize under vmap regardless.
-            per_level = [
-                nms_indices(
-                    bx[l], sc[l], nms_threshold, post_nms_top_n,
-                    nms_impl="pallas", interpret=pallas_interpret,
-                )
-                for l in range(len(levels))
-            ]
-            keep_idx = jnp.stack([i for i, _ in per_level])
-            keep_valid = jnp.stack([v for _, v in per_level])
-        else:
-            keep_idx, keep_valid = jax.vmap(
-                lambda b, s: nms_indices(
-                    b, s, nms_threshold, post_nms_top_n,
-                    sweep_cap=nms_sweep_cap,
-                )
-            )(bx, sc)                                       # (L, post) x2
+        with jax.named_scope("nms"):
+            if nms_impl == "pallas":
+                # One sequential-sweep kernel launch per level; the sweeps
+                # would serialize under vmap regardless.
+                per_level = [
+                    nms_indices(
+                        bx[l], sc[l], nms_threshold, post_nms_top_n,
+                        nms_impl="pallas", interpret=pallas_interpret,
+                    )
+                    for l in range(len(levels))
+                ]
+                keep_idx = jnp.stack([i for i, _ in per_level])
+                keep_valid = jnp.stack([v for _, v in per_level])
+            else:
+                keep_idx, keep_valid = jax.vmap(
+                    lambda b, s: nms_indices(
+                        b, s, nms_threshold, post_nms_top_n,
+                        sweep_cap=nms_sweep_cap,
+                    )
+                )(bx, sc)                                   # (L, post) x2
     rois_l = jnp.take_along_axis(
         bx, keep_idx[..., None], axis=1
     ) * keep_valid[..., None]

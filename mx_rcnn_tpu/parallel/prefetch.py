@@ -19,11 +19,15 @@ Batch ORDER is unchanged by both (single producer, single consumer,
 FIFO queue), so schedule determinism — quarantine substitution, chaos
 bit-exact resume — is preserved.
 
-:class:`PrefetchStats` measures what the overlap does NOT hide: the time
-the consumer blocks waiting for a batch that is not ready (the queue ran
-dry — the loader is slower than the step).  That stall is the
-input-bound signal; it feeds the ``data_stall_ms`` entry of the
-``train_stage_ms`` breakdown (bench.py, train/loop.py).
+:class:`PrefetchStats` measures what the overlap does NOT hide, in the
+consumer's thread: ``stall_s``, the time blocked waiting for a batch that
+is not ready (the queue ran dry — the loader is slower than the step), and
+``put_s``, the time inside the ``device_put`` of the next batch, which this
+generator issues synchronously between two steps.  The same two stretches
+go on the program's timeline as ``feed.wait`` and ``feed.put`` spans
+(subsystem ``train``, ``seq`` = index of the batch = the step that consumes
+it).  ``train()`` logs them as ``data_stall_ms`` / ``data_put_ms``; the
+benchmark reads them as ``data_stall_ms.train`` / ``feed_put_ms.train``.
 """
 
 from __future__ import annotations
@@ -32,11 +36,11 @@ import collections
 import logging
 import queue
 import threading
-import time
 from typing import Iterator, Optional
 
 import jax
 
+from mx_rcnn_tpu import obs
 from mx_rcnn_tpu.parallel.mesh import shard_batch
 
 log = logging.getLogger("mx_rcnn_tpu")
@@ -49,18 +53,26 @@ class PrefetchStats:
     for the producer (an empty handoff queue); a batch that is already
     buffered costs ~0 regardless of how long the loader took to build it
     — that work was hidden behind the device step, which is the point.
-    ``take()`` returns-and-resets, so callers meter per interval (the
-    training loop) or per timed window (bench) without seeding from a
-    wall clock.
+    ``put_s`` accumulates the consumer's time inside ``device_put``.
+    ``take()`` returns-and-resets stall and batches, ``take_put()`` the
+    put seconds, so callers meter per interval (the training loop) or per
+    timed window (bench) without seeding from a wall clock.
     """
 
     def __init__(self) -> None:
         self.stall_s = 0.0
         self.batches = 0
+        self.put_s = 0.0
+        # The span the consumer is inside while it pulls (train()'s
+        # ``data``): the feed's spans become its children.  None = roots.
+        self.parent: Optional[obs.Span] = None
 
     def add(self, stall_s: float) -> None:
         self.stall_s += stall_s
         self.batches += 1
+
+    def add_put(self, put_s: float) -> None:
+        self.put_s += put_s
 
     def take(self) -> tuple[float, int]:
         """(accumulated stall seconds, batches) since the last take."""
@@ -68,6 +80,19 @@ class PrefetchStats:
         self.stall_s = 0.0
         self.batches = 0
         return out
+
+    def take_put(self) -> float:
+        """Seconds inside device_put since the last take_put."""
+        out = self.put_s
+        self.put_s = 0.0
+        return out
+
+
+def _feed_span(stats: Optional[PrefetchStats], name: str, seq: int):
+    parent = stats.parent if stats is not None else None
+    if parent is not None:
+        return parent.child(name, attrs={"seq": seq})
+    return obs.span(name, subsystem="train", attrs={"seq": seq})
 
 
 class _HostPrefetcher:
@@ -90,6 +115,7 @@ class _HostPrefetcher:
         self._q: queue.Queue = queue.Queue(maxsize=max(depth, 1))
         self._stop = threading.Event()
         self._stats = stats
+        self._seq = 0  # index of the next batch the consumer pulls
         self._thread = threading.Thread(
             target=self._run, args=(it,), name="host-prefetch", daemon=True
         )
@@ -122,19 +148,19 @@ class _HostPrefetcher:
     def __next__(self):
         if self._stop.is_set():
             raise StopIteration
-        if self._stats is None:
-            item, exc = self._q.get()
-        else:
-            # Time ONLY the blocking wait: a non-empty queue short-circuits
-            # through get_nowait with no clock reads on the hot path's
-            # happy case beyond the two perf_counter calls.
-            try:
-                item, exc = self._q.get_nowait()
-                self._stats.add(0.0)
-            except queue.Empty:
-                t0 = time.perf_counter()
+        # Time ONLY the blocking wait (``feed.wait``): a non-empty queue
+        # short-circuits through get_nowait with no clock read and no span.
+        stats = self._stats
+        try:
+            item, exc = self._q.get_nowait()
+            stall_s = 0.0
+        except queue.Empty:
+            with _feed_span(stats, "feed.wait", self._seq) as sp:
                 item, exc = self._q.get()
-                self._stats.add(time.perf_counter() - t0)
+            stall_s = sp.dur_ns / 1e9
+        self._seq += 1
+        if stats is not None:
+            stats.add(stall_s)
         if item is self._DONE:
             self._stop.set()
             if exc is not None:
@@ -190,14 +216,17 @@ class _HostPrefetcher:
 
 def _timed_pulls(it: Iterator, stats: PrefetchStats) -> Iterator:
     """host_depth=0 fallback: every pull is synchronous, so the whole
-    ``next(it)`` is consumer-blocking stall by definition."""
+    ``next(it)`` is consumer-blocking stall (``feed.wait``) by definition."""
+    seq = 0
     while True:
-        t0 = time.perf_counter()
+        sp = _feed_span(stats, "feed.wait", seq)
         try:
             item = next(it)
         except StopIteration:
             return
-        stats.add(time.perf_counter() - t0)
+        sp.end()
+        stats.add(sp.dur_ns / 1e9)
+        seq += 1
         yield item
 
 
@@ -213,22 +242,32 @@ def device_prefetch(
     ahead of the device_put stage (0 = synchronous pulls in the consumer
     thread — the pre-r6 behavior, kept for strictly single-threaded
     debugging).  ``stats``: optional :class:`PrefetchStats` accumulating
-    the consumer-side stall (data-starvation) time.  Closing the returned
-    generator (``gen.close()``) stops the thread."""
+    the consumer-side stall (data-starvation) and ``device_put`` time.
+    Closing the returned generator (``gen.close()``) stops the thread."""
     q: collections.deque = collections.deque()
     if host_depth <= 0:
         src: Iterator = it if stats is None else _timed_pulls(it, stats)
     else:
         src = _HostPrefetcher(it, host_depth, stats=stats)
 
-    def put(batch):
-        if mesh is not None:
-            return shard_batch(batch, mesh, spatial=spatial, stacked=stacked)
-        return jax.device_put(batch)
+    def put(batch, seq):
+        # Issued in the consumer's thread, between two steps: the copy
+        # itself is asynchronous, the host's part of it (layout, staging)
+        # is not, and the device may idle for it (``feed.put``).
+        with _feed_span(stats, "feed.put", seq) as sp:
+            if mesh is not None:
+                out = shard_batch(
+                    batch, mesh, spatial=spatial, stacked=stacked
+                )
+            else:
+                out = jax.device_put(batch)
+        if stats is not None:
+            stats.add_put(sp.dur_ns / 1e9)
+        return out
 
     try:
-        for batch in src:
-            q.append(put(batch))
+        for seq, batch in enumerate(src):
+            q.append(put(batch, seq))
             if len(q) > depth:
                 yield q.popleft()
         while q:

@@ -161,7 +161,8 @@ def make_train_step(
             new_state = state.apply_gradients(grads, tx)
         metrics = dict(metrics, nonfinite=nonfinite)
         if schedule is not None:
-            metrics["lr"] = schedule(state.step)
+            with jax.named_scope("schedule"):
+                metrics["lr"] = schedule(state.step)
         return new_state, metrics
 
     def _masked(params):
@@ -180,7 +181,8 @@ def make_train_step(
                     batch.images, spatial_spec
                 )
             )
-        rng = jax.random.fold_in(state.rng, state.step)
+        with jax.named_scope("rng"):
+            rng = jax.random.fold_in(state.rng, state.step)
 
         def loss_fn(params):
             variables = {"params": _masked(params), **state.model_state}

@@ -14,6 +14,7 @@ from __future__ import annotations
 import contextlib
 import logging
 import os
+import time
 from typing import Optional
 
 import jax
@@ -49,6 +50,7 @@ from mx_rcnn_tpu.train.preemption import Preempted, PreemptionGuard
 from mx_rcnn_tpu.train.optim import frozen_mask, make_optimizer
 from mx_rcnn_tpu.train.state import TrainState, create_train_state
 from mx_rcnn_tpu.utils import ProfileWindow
+from mx_rcnn_tpu.utils.compile_cache import compile_totals
 
 log = logging.getLogger("mx_rcnn_tpu")
 
@@ -78,6 +80,18 @@ def scale_schedule_steps(sched, global_batch: int):
         decay_steps=tuple(max(1, round(s * f)) for s in sched.decay_steps),
         total_steps=max(1, round(sched.total_steps * f)),
     )
+
+
+@contextlib.contextmanager
+def _setup_span(name: str):
+    """One phase of :func:`build_all` on the timeline (subsystem ``train``):
+    its seconds are the span's; ``programs`` / ``compile_s`` are what the
+    compile listener (utils/compile_cache.py) counted inside it."""
+    n0, s0 = compile_totals()
+    with obs.span(name, subsystem="train") as sp:
+        yield sp
+        n1, s1 = compile_totals()
+        sp.set(programs=n1 - n0, compile_s=round(s1 - s0, 3))
 
 
 def build_all(cfg: Config, mesh=None, freeze_backbone: bool = True,
@@ -147,7 +161,10 @@ def build_all(cfg: Config, mesh=None, freeze_backbone: bool = True,
 
     # Init params first (on host) so the freeze mask can see the tree.
     probe_tx, schedule = make_optimizer(train_cfg, None, lr_scale=lr_scale)
-    state = create_train_state(model, probe_tx, rng, cfg.data.image_size, batch=1)
+    with _setup_span("setup.init_state"):
+        state = create_train_state(
+            model, probe_tx, rng, cfg.data.image_size, batch=1
+        )
     if pretrained:
         from mx_rcnn_tpu.train.import_torch import load_pretrained_backbone
         from mx_rcnn_tpu.train.state import state_variables
@@ -158,27 +175,32 @@ def build_all(cfg: Config, mesh=None, freeze_backbone: bool = True,
             model_state={k: v for k, v in variables.items() if k != "params"},
         )
     trainable = None
-    if freeze:
-        tx, schedule = make_optimizer(
-            train_cfg, state.params, lr_scale=lr_scale, freeze_prefixes=freeze
-        )
-        state = state.replace(opt_state=tx.init(state.params))
-        # Same mask the optimizer uses: frozen leaves are stop-gradient'd
-        # inside the step so their backward is eliminated, not just zeroed.
-        trainable = frozen_mask(state.params, freeze)
-    else:
-        tx = probe_tx
+    with _setup_span("setup.optimizer"):
+        if freeze:
+            tx, schedule = make_optimizer(
+                train_cfg, state.params, lr_scale=lr_scale,
+                freeze_prefixes=freeze,
+            )
+            state = state.replace(opt_state=tx.init(state.params))
+            # Same mask the optimizer uses: frozen leaves are
+            # stop-gradient'd inside the step so their backward is
+            # eliminated, not just zeroed.
+            trainable = frozen_mask(state.params, freeze)
+        else:
+            tx = probe_tx
     # The execution plan (parallel/plan.py) owns every sharding decision
     # from here on: it validates the knob combination, resolves the
     # partition rules against the real state (unmatched leaf = hard error
     # at build time), and compiles the step.  train() rebuilds the same
     # plan (pure function of cfg+mesh) for state placement and restore.
-    plan = build_plan(cfg, mesh, model=model)
-    step_fn = make_train_step(
-        model, tx, schedule, trainable_mask=trainable,
-        pixel_stats=(cfg.data.pixel_mean, cfg.data.pixel_std),
-        plan=plan, state_template=state,
-    )
+    with _setup_span("setup.plan"):
+        plan = build_plan(cfg, mesh, model=model)
+    with _setup_span("setup.step"):
+        step_fn = make_train_step(
+            model, tx, schedule, trainable_mask=trainable,
+            pixel_stats=(cfg.data.pixel_mean, cfg.data.pixel_std),
+            plan=plan, state_template=state,
+        )
     return model, tx, state, step_fn, global_batch
 
 
@@ -282,6 +304,42 @@ def _stacked_batches(it, k: int):
         close = getattr(it, "close", None)
         if close is not None:
             close()
+
+
+def _save(ckpt_dir: str, state: TrainState, step: int,
+          wait: bool = False) -> None:
+    """One checkpoint save: a ``checkpoint`` span on the timeline (the
+    device_get and the write the loop waits for) and its journal event."""
+    with obs.span("checkpoint", subsystem="train", attrs={"step": step}):
+        save_checkpoint(ckpt_dir, jax.device_get(state), wait=wait)
+    obs.emit("train", "checkpoint_saved", {"step": step}, logger=log)
+
+
+def _report_recompiles(since_ns: int, upto_step: int) -> None:
+    """``train/recompiled`` for every program built since ``since_ns``: in a
+    steady loop nothing compiles after the first interval, so each is a
+    shape, dtype or static argument that changed under the loop.  ``step``
+    is the one whose ``train_step`` span holds the compile's end."""
+    compiles = [
+        c for c in obs.tracer().recent(since_ns, "jit")
+        if c.name == "jit.compile"
+    ]
+    if not compiles:
+        return
+    steps = [
+        s for s in obs.tracer().recent(since_ns, "train")
+        if s.name == "train_step"
+    ]
+    for c in compiles:
+        step = next(
+            (s.attrs["step"] for s in steps
+             if s.start_ns <= c.end_ns <= s.end_ns),
+            upto_step,
+        )
+        obs.emit("train", "recompiled", {
+            "step": step, "fun_name": c.attrs.get("fun_name", "?"),
+            "seconds": c.dur_ns / 1e9,
+        }, logger=log)
 
 
 def train(
@@ -471,11 +529,7 @@ def train(
     # checkpoint interval then rolls back to/resumes from the start state
     # instead of aborting the run.
     if workdir and latest_step(ckpt_dir) is None:
-        save_checkpoint(ckpt_dir, jax.device_get(state))
-        obs.emit(
-            "train", "checkpoint_saved", {"step": int(state.step)},
-            logger=log,
-        )
+        _save(ckpt_dir, state, int(state.step))
     # Quantize the profile window to the loop stride so it still opens
     # when i advances k at a time.  Round UP: the default (10, 15) window
     # exists to skip the compile step, so the start must never be pulled
@@ -501,10 +555,12 @@ def train(
     )
     pending: list[dict] = []
     # Data-starvation meter: time the consumer blocked in next(loader)
-    # past the prefetch double buffer, logged per interval as
-    # data_stall_ms (per optimizer step) alongside the device metrics.
+    # past the prefetch double buffer, and time it spent inside the
+    # device_put of a later batch, logged per interval as data_stall_ms /
+    # data_put_ms (per optimizer step) alongside the device metrics.
     prefetch_stats = PrefetchStats()
     last_drain = start
+    compiles_seen_ns: Optional[int] = None  # set at the first drain
     it = data_iter(start, 0)
     data_skip = 0      # batches the guardian skipped ahead of the schedule
     last_good = start  # newest boundary whose drained metrics were finite
@@ -519,24 +575,22 @@ def train(
                 else contextlib.nullcontext()
             )
             first_call = False
-            tspan = (
-                obs.span("train_step", subsystem="train", attrs={"step": i})
-                if obs.spans_enabled() else None
+            # The step's spans (docs/observability.md): ``data`` is the
+            # host's part of the feed — the wait past the prefetch buffer
+            # and the device_put of a later batch, its children
+            # ``feed.wait`` / ``feed.put`` — and ``step`` the asynchronous
+            # dispatch of the device program.  Always recorded: three
+            # appends to a buffer against a step of milliseconds.
+            tspan = obs.span(
+                "train_step", subsystem="train", attrs={"step": i}
             )
             with guard:
-                if tspan is None:
+                with tspan.child("data", attrs={"step": i}) as dspan:
+                    prefetch_stats.parent = dspan
                     batch = next(it)
+                with tspan.child("step", attrs={"step": i}):
                     state, metrics = step_fn(state, batch)
-                else:
-                    # Span boundaries mirror stage_bench: "data" is the
-                    # host wait past the prefetch buffer (h2d included),
-                    # "step" is the async dispatch of the device program.
-                    with tspan.child("data"):
-                        batch = next(it)
-                    with tspan.child("step"):
-                        state, metrics = step_fn(state, batch)
-            if tspan is not None:
-                tspan.end()
+            tspan.end()
             pending.append(metrics)
             done = i + k
             at_log = done % cfg.train.log_every < k or i == start
@@ -545,13 +599,20 @@ def train(
                 # Checkpoint boundaries drain too: a checkpoint is only
                 # written after its whole interval validated finite, so
                 # every on-disk step is a sound rollback target.
-                means, per_step = host_interval_metrics(pending)
+                with obs.span("drain", subsystem="train",
+                              attrs={"step": done}):
+                    means, per_step = host_interval_metrics(pending)
                 pending.clear()
-                # Host-side metric, appended AFTER the guardian sees the
+                # Host-side metrics, appended AFTER the guardian sees the
                 # interval (a slow disk must never look like divergence).
                 stall_s, _ = prefetch_stats.take()
-                stall_ms = stall_s * 1000.0 / max(done - last_drain, 1)
+                put_s = prefetch_stats.take_put()
+                per_ms = 1000.0 / max(done - last_drain, 1)
+                stall_ms, put_ms = stall_s * per_ms, put_s * per_ms
                 last_drain = done
+                if compiles_seen_ns is not None:
+                    _report_recompiles(compiles_seen_ns, done)
+                compiles_seen_ns = time.monotonic_ns()
                 rollback = guardian.observe(done, means, per_step)
                 if rollback is not None:
                     target = jax.device_get(state)
@@ -589,29 +650,20 @@ def train(
                 last_good = done
                 means.pop("nonfinite", None)
                 means["data_stall_ms"] = stall_ms
+                means["data_put_ms"] = put_ms
                 if at_log:
                     speedo(done, means)
                     if writer:
                         writer.write(done, means)
                 if at_ckpt:
-                    save_checkpoint(ckpt_dir, jax.device_get(state))
-                    obs.emit(
-                        "train", "checkpoint_saved", {"step": done},
-                        logger=log,
-                    )
+                    _save(ckpt_dir, state, done)
             if preempt.triggered:
                 # Drain complete; persist synchronously and exit resumable.
                 obs.emit(
                     "train", "preempt_drain", {"step": done}, logger=log
                 )
                 if workdir:
-                    save_checkpoint(
-                        ckpt_dir, jax.device_get(state), wait=True
-                    )
-                    obs.emit(
-                        "train", "checkpoint_saved", {"step": done},
-                        logger=log,
-                    )
+                    _save(ckpt_dir, state, done, wait=True)
                 if writer:
                     writer.close()
                 it.close()
@@ -625,9 +677,6 @@ def train(
     if writer:
         writer.close()
     if workdir:
-        save_checkpoint(ckpt_dir, jax.device_get(state), wait=True)
-        obs.emit(
-            "train", "checkpoint_saved", {"step": int(steps)}, logger=log
-        )
+        _save(ckpt_dir, state, int(steps), wait=True)
         flush_checkpoints(ckpt_dir)
     return state

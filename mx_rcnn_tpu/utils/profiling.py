@@ -2,36 +2,84 @@
 
 The reference has no profiling beyond the Speedometer samples/sec print
 (SURVEY.md §6: ``mx.profiler`` exists engine-side but the repo never uses
-it).  Here profiling is a first-class loop feature: device traces go
-through ``jax.profiler`` (viewable in XProf/Perfetto/TensorBoard), host
-step timing through :class:`StepTimer`.
+it).  Here profiling is a first-class loop feature: the device's side of
+a window goes through ``jax.profiler`` (an XPlane, viewable in
+XProf/Perfetto/TensorBoard), the host's side is the program's own spans
+(``obs/tracing.py``), written beside the XPlane as ``host_spans.json``
+with the offset that turns their clock into the Unix wall clock the XPlane
+dates its start on (``tools/obs_report.py --profile-dir`` merges the two).
+
+The profiler's own host tracer stays OFF: on the TPU runtime it records
+every block of the host's layout transposes (millions of events), which
+starved the step to 39-56 % idle and made the file 170-385 MB (PERF.md
+section 6).  A trace taken with it on measures the tracer.
 """
 
 from __future__ import annotations
 
 import contextlib
+import glob
+import json
 import logging
+import os
 import time
 from typing import Iterator, Optional
 
 import jax
 
+from mx_rcnn_tpu import obs
+from mx_rcnn_tpu.obs import tracing
+
 log = logging.getLogger("mx_rcnn_tpu")
+
+HOST_SPANS_FILE = "host_spans.json"
+
+
+def _start_trace(logdir: str) -> tuple[int, int]:
+    """Start the profiler, host tracers off.  -> (monotonic ns at start,
+    offset from the span clock to the Unix wall clock, taken here)."""
+    options = jax.profiler.ProfileOptions()
+    options.host_tracer_level = 0
+    options.python_tracer_level = 0
+    t0_ns = time.monotonic_ns()
+    offset_ns = tracing.wall_offset_ns()
+    jax.profiler.start_trace(logdir, profiler_options=options)
+    return t0_ns, offset_ns
+
+
+def _stop_trace(logdir: str, t0_ns: int, offset_ns: int) -> None:
+    """Stop the profiler and write the window's spans beside its XPlane."""
+    jax.profiler.stop_trace()
+    runs = sorted(glob.glob(os.path.join(logdir, "plugins", "profile", "*")))
+    out_dir = runs[-1] if runs else logdir
+    spans = [s.to_chrome() for s in obs.tracer().recent(since_ns=t0_ns)]
+    with open(os.path.join(out_dir, HOST_SPANS_FILE), "w") as f:
+        json.dump({
+            # ts of a span (us, time.monotonic_ns) * 1000 + this = Unix ns;
+            # less the XPlane's profile_start_time = its place on the
+            # device events' axis, to a few ms (obs/tracing.py).
+            "wall_offset_ns": offset_ns,
+            "window_start_mono_ns": t0_ns,
+            "spans": spans,
+        }, f)
+    log.info(
+        "profiler trace written to %s (%d host spans beside it)",
+        logdir, len(spans),
+    )
 
 
 @contextlib.contextmanager
 def trace(logdir: Optional[str]) -> Iterator[None]:
-    """Device+host trace of the enclosed block into ``logdir`` (no-op when
-    logdir is None).  Produces an XPlane/Perfetto dump per host."""
+    """Device trace of the enclosed block into ``logdir``, the program's
+    spans of the block beside it (no-op when logdir is None)."""
     if not logdir:
         yield
         return
-    jax.profiler.start_trace(logdir)
+    t0_ns, offset_ns = _start_trace(logdir)
     try:
         yield
     finally:
-        jax.profiler.stop_trace()
-        log.info("profiler trace written to %s", logdir)
+        _stop_trace(logdir, t0_ns, offset_ns)
 
 
 class ProfileWindow:
@@ -47,6 +95,7 @@ class ProfileWindow:
         self.start = start
         self.stop = stop
         self._active = False
+        self._t0_ns = self._offset_ns = 0
 
     def step(self, i: int, sync=None) -> None:
         """Call at the top of loop step ``i``."""
@@ -55,7 +104,7 @@ class ProfileWindow:
         if not self._active and self.start <= i < self.stop:
             if sync is not None:
                 jax.block_until_ready(sync)
-            jax.profiler.start_trace(self.logdir)
+            self._t0_ns, self._offset_ns = _start_trace(self.logdir)
             self._active = True
         elif self._active and i >= self.stop:
             self.close(sync)
@@ -64,9 +113,8 @@ class ProfileWindow:
         if self._active:
             if sync is not None:
                 jax.block_until_ready(sync)
-            jax.profiler.stop_trace()
+            _stop_trace(self.logdir, self._t0_ns, self._offset_ns)
             self._active = False
-            log.info("profiler trace written to %s", self.logdir)
 
 
 class StepTimer:

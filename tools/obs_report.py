@@ -8,6 +8,9 @@ Inputs (all optional — the report carries whatever exists):
 * ``<obs-dir>/flight_*.json``   — flight-recorder postmortem dumps
 * ``--stage-log`` file(s)       — bench/chaos stdout with ``{"metric":
   ...}`` JSON lines (train_stage_ms breakdowns, BENCH headlines)
+* ``--profile-dir``             — what ``train.py --profile DIR`` wrote: a
+  ``jax.profiler`` XPlane and, beside it, ``host_spans.json`` (the
+  program's spans of the profiled window, ``utils/profiling.py``)
 
 Outputs:
 
@@ -20,8 +23,18 @@ Outputs:
   size, from the journal alone), flight-dump summaries and any
   stage/headline lines.
 * ``<obs-dir>/trace.json`` (``--trace-out``) — the span lines wrapped in
-  a Chrome-trace ``{"traceEvents": [...]}`` array, loadable in Perfetto
-  next to the jax.profiler dumps.
+  a Chrome-trace ``{"traceEvents": [...]}`` array, loadable in Perfetto.
+  With ``--profile-dir`` the profiled window's host spans and the
+  XPlane's device events (program runs and ops, per chip) are laid on ONE
+  axis in it — microseconds since the profiler session began: a span's
+  monotonic time plus the window's wall offset less the XPlane's own
+  ``profile_start_time``.  The two clocks meet to a few milliseconds
+  (obs/tracing.py): enough to see which step a host span belongs to.
+* the report's ``spans.by_name`` — per span name (``setup.*``,
+  ``train_step``, ``data``, ``step``, ``feed.wait``, ``feed.put``,
+  ``drain``, ``checkpoint``, ``jit.trace``, ``jit.lower``,
+  ``jit.compile``, the serving spans): count,
+  total, median and longest, in milliseconds.
 
 Usage:
     python tools/obs_report.py --obs-dir /tmp/run/obs \\
@@ -47,7 +60,7 @@ INCIDENT_KINDS = frozenset({
     "worker_death", "worker_retired", "worker_wedged", "service_fallback",
     "cache_quarantine", "shm_quarantine", "cache_evict",
     "guardian_rollback", "rollback_restored", "guardian_loss_spike",
-    "training_diverged", "preempt_drain",
+    "training_diverged", "preempt_drain", "recompiled",
     "checkpoint_saved", "checkpoint_restored",
     "engine_dead", "engine_killed",
     "fleet_quarantine", "fleet_reinstate", "fleet_retire", "weight_swap",
@@ -221,6 +234,69 @@ def _order_key(rec: dict):
     return (rec.get("ts", 0.0), rec.get("ts_mono_ns", 0))
 
 
+def span_table(spans: list[dict]) -> dict:
+    """{span name: {count, total_ms, p50_ms, max_ms}} over Chrome-trace
+    span events (``dur`` in microseconds)."""
+    durs: dict[str, list[float]] = {}
+    for s in spans:
+        durs.setdefault(s.get("name", "?"), []).append(s.get("dur", 0.0) / 1e3)
+    table = {}
+    for name, d in sorted(durs.items()):
+        d.sort()
+        table[name] = {
+            "count": len(d),
+            "total_ms": round(sum(d), 3),
+            "p50_ms": round(d[(len(d) - 1) // 2], 3),
+            "max_ms": round(d[-1], 3),
+        }
+    return table
+
+
+def merge_profile(profile_dir: str) -> list[dict]:
+    """Chrome-trace events of the newest profiled window under
+    ``profile_dir``: its host spans and the XPlane's device events on one
+    axis (microseconds since the profiler session began).  [] when the
+    directory holds no ``host_spans.json``."""
+    found = sorted(glob.glob(os.path.join(
+        profile_dir, "plugins", "profile", "*", "host_spans.json"
+    ))) or sorted(glob.glob(os.path.join(profile_dir, "host_spans.json")))
+    if not found:
+        return []
+    with open(found[-1]) as f:
+        doc = json.load(f)
+    planes = sorted(glob.glob(
+        os.path.join(os.path.dirname(found[-1]), "*.xplane.pb")
+    ))
+    events, start_ns = [], None
+    if planes:
+        from jax.profiler import ProfileData
+
+        data = ProfileData.from_file(planes[-1])
+        for plane in data.planes:
+            if plane.name == "Task Environment":
+                start_ns = dict(plane.stats).get("profile_start_time")
+            if not plane.name.startswith("/device:"):
+                continue
+            for line in plane.lines:
+                if line.name not in ("XLA Modules", "XLA Ops"):
+                    continue
+                for e in line.events:
+                    events.append({
+                        "ph": "X", "name": e.name.split(" ", 1)[0].lstrip("%"),
+                        "cat": line.name, "pid": plane.name, "tid": line.name,
+                        "ts": e.start_ns / 1e3, "dur": e.duration_ns / 1e3,
+                    })
+    spans = doc.get("spans", [])
+    if start_ns is None:
+        # No XPlane to date the axis: the window's own start stands in.
+        shift_us = -doc.get("window_start_mono_ns", 0) / 1e3
+    else:
+        shift_us = (doc.get("wall_offset_ns", 0) - int(start_ns)) / 1e3
+    for s in spans:
+        events.append(dict(s, ts=s["ts"] + shift_us, pid="host"))
+    return events
+
+
 def build_report(
     obs_dir: str, stage_logs: tuple[str, ...] = ()
 ) -> tuple[dict, list[dict]]:
@@ -294,6 +370,7 @@ def build_report(
             "count": len(spans),
             "traces": len(traces),
             "max_spans_per_trace": max(traces.values(), default=0),
+            "by_name": span_table(spans),
         },
         "flight_dumps": flights,
         "stage_lines": stage_lines,
@@ -308,6 +385,10 @@ def main(argv=None) -> int:
     p.add_argument("--stage-log", action="append", default=[],
                    help="bench/chaos log with JSON metric lines "
                         "(repeatable)")
+    p.add_argument("--profile-dir", default=None,
+                   help="the directory train.py --profile DIR wrote: its "
+                        "host spans and device events are merged into "
+                        "the trace file on one axis")
     p.add_argument("--out", default="artifacts/obs_report.json")
     p.add_argument("--trace-out", default=None,
                    help="Chrome-trace wrap of spans.jsonl (default: "
@@ -319,6 +400,18 @@ def main(argv=None) -> int:
     trace_out = args.trace_out
     if trace_out is None:
         trace_out = os.path.join(args.obs_dir, "trace.json")
+    if args.profile_dir:
+        merged = merge_profile(args.profile_dir)
+        report["profile"] = {
+            "dir": os.path.abspath(args.profile_dir),
+            "events": len(merged),
+            "host_spans": span_table(
+                [e for e in merged if e.get("pid") == "host"]
+            ),
+        }
+        # The merged window replaces the raw span lines in the trace file:
+        # they sit on another axis (the process's monotonic clock).
+        spans = merged or spans
     if trace_out != "none" and spans:
         with open(trace_out, "w") as f:
             json.dump({"traceEvents": spans}, f)
