@@ -192,11 +192,13 @@ class RCNNConfig:
     # Class-agnostic box regression (False = per-class, reference default).
     class_agnostic: bool = False
     loss_weight: float = 1.0
-    # ROIAlign backend: "pallas" (default — one batch-folded windowed-DMA
-    # kernel launch per step; measured 83.1 -> 77.6 ms/step on the full
-    # R50-FPN train step once the whole batch rides one grid) or "xla"
-    # (flattened-pyramid gather — the oracle, the backward, and the
-    # automatic fallback off-TPU or on unsupported layouts).
+    # ROIAlign backend on a MULTI-level pyramid: "pallas" (default — one
+    # batch-folded windowed-DMA kernel launch per step; measured 83.1 ->
+    # 77.6 ms/step on the full R50-FPN train step once the whole batch
+    # rides one grid) or "xla" (flattened-pyramid gather — the oracle, the
+    # backward, and the automatic fallback off-TPU or on unsupported
+    # layouts).  Not read on a one-level (C4 / VGG) pyramid: there the one
+    # path is the interpolation matmul, ops/roi_align.py::roi_align_matmul.
     roi_align_impl: str = "pallas"
     # Backward for the pallas forward: "pallas" (default — the windowed-DMA
     # scatter-accumulate kernel ops/pallas/roi_align.py::_bwd_kernel, the

@@ -33,7 +33,7 @@ from mx_rcnn_tpu.geometry import (
     shifted_anchors_np,
     weighted_smooth_l1,
 )
-from mx_rcnn_tpu.ops import assign_anchors, generate_proposals, roi_align, sample_rois
+from mx_rcnn_tpu.ops import assign_anchors, generate_proposals, sample_rois
 from mx_rcnn_tpu.ops.nms import batched_nms, nms_indices
 from mx_rcnn_tpu.ops.pallas.roi_align import (
     POOL_WINDOW,
@@ -42,7 +42,7 @@ from mx_rcnn_tpu.ops.pallas.roi_align import (
     sharded_multilevel_roi_align,
 )
 from mx_rcnn_tpu.ops.proposals import Proposals, generate_fpn_proposals
-from mx_rcnn_tpu.ops.roi_align import multilevel_roi_align
+from mx_rcnn_tpu.ops.roi_align import multilevel_roi_align, roi_align_matmul
 
 # Batch moved to data/batch.py (jax-free) so input-service workers can
 # unpickle batches without importing the model stack; re-exported here so
@@ -340,8 +340,9 @@ def _slice_levels(levels, anchors, score_row, delta_row):
 
 
 # Trace-time record of the backend _pool_rois last selected ("pallas",
-# "pallas-shardmap", or "xla") — set while jit traces, so tests and the
-# driver dryrun can assert which path a compiled program actually took.
+# "pallas-shardmap", "xla", or "matmul" on a one-level pyramid) — set while
+# jit traces, so tests and the driver dryrun can assert which path a
+# compiled program actually took.
 LAST_POOL_IMPL: Optional[str] = None
 
 # Same record for the detection middle (_propose_one): "fused" (the Pallas
@@ -376,13 +377,19 @@ def _pool_rois_impl(cfg: ModelConfig, feats, rois, pooled_size: int,
                     roi_level_set, mesh=None):
     """ROIAlign over the batch. rois: (B, R, 4) -> (B, R, S, S, C).
 
-    ``cfg.rcnn.roi_align_impl`` picks the backend: "pallas" (default — ONE
-    batch-folded kernel launch per step) or "xla" (flattened-pyramid
-    gather — the oracle).  "pallas" still takes the XLA gather in two
-    cases, both decided from what the code can see and neither a failure:
-    off-TPU without MX_RCNN_PALLAS_INTERPRET=1 (the design for CPU tests),
-    and on a single-level C4 pyramid (the kernel's window bounds roi
-    extent through FPN level reassignment, which one level cannot do).
+    A single-level (C4 / VGG) pyramid has ONE path on every platform:
+    ``ops/roi_align.py::roi_align_matmul``, the dense interpolation matmul
+    over the whole map ("matmul").  ``cfg.rcnn.roi_align_impl`` is not
+    read there: the Pallas kernel's window bounds a roi's extent through
+    FPN level reassignment, which one level cannot do, and the XLA gather
+    is only the oracle.
+
+    On a multi-level pyramid ``cfg.rcnn.roi_align_impl`` picks the
+    backend: "pallas" (default — ONE batch-folded kernel launch per step)
+    or "xla" (flattened-pyramid gather — the oracle).  "pallas" still
+    takes the XLA gather off-TPU without MX_RCNN_PALLAS_INTERPRET=1 (the
+    design for CPU tests; decided from what the code can see, not a
+    failure).
     On a TPU, a multi-level pyramid whose layout the kernel cannot slice
     (:func:`pallas_supported`) RAISES: a TPU path was asked for, and
     nothing quietly stands in for it.  The pallas path's backward is a
@@ -408,12 +415,11 @@ def _pool_rois_impl(cfg: ModelConfig, feats, rois, pooled_size: int,
     levels = sorted(feats)
     if len(levels) == 1:
         lvl = levels[0]
-        LAST_POOL_IMPL = "xla"
-        return jax.vmap(
-            lambda f, r: roi_align(
-                f, r, pooled_size, 1.0 / (2**lvl), cfg.rcnn.sampling_ratio
-            )
-        )(feats[lvl], rois)
+        LAST_POOL_IMPL = "matmul"
+        return roi_align_matmul(
+            feats[lvl], rois, pooled_size, 1.0 / (2**lvl),
+            cfg.rcnn.sampling_ratio,
+        )
 
     roi_levels = {l: f for l, f in feats.items() if l in roi_level_set}
     on_tpu = jax.default_backend() == "tpu"

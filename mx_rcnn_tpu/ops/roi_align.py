@@ -1,17 +1,26 @@
-"""ROIAlign, XLA-native (gather + bilinear), with multilevel FPN dispatch.
+"""ROIAlign in plain XLA: the gather form (the oracle, and the multilevel
+FPN dispatch off the TPU) and the one-level matmul form (the path every
+one-level pyramid takes).
 
 Replaces the engine-side ``mx.symbol.ROIPooling`` CUDA op the reference's
 R-CNN head depends on (SURVEY.md section 3.5), upgraded to ROIAlign per the
 BASELINE north star.  Design notes for TPU:
 
 - All shapes static: (R rois) x (S x S bins) x (sr x sr samples/bin).
-- The bilinear gather is expressed as 4 corner gathers from the flattened
-  (H*W, C) feature map with computed flat indices — XLA lowers this to
-  dynamic-gather, which is the memory-bound but correct baseline; the
-  Pallas kernel (ops/pallas/roi_align.py) is the performance path.
-- Sample points are accumulated one at a time (sr*sr iterations, unrolled
-  at trace time) so the peak intermediate is (R, S, S, C), not
-  (R, S*sr, S*sr, C).
+- ``roi_align`` / ``multilevel_roi_align`` express the bilinear sample as 4
+  corner gathers from the flattened (H*W, C) feature map with computed flat
+  indices.  XLA lowers this to dynamic-gather and, in the backward, to a
+  scatter-add per image: correct and slow on the chip (PERF.md section 6,
+  PR 26).  They are the reference semantics every other form is tested
+  against, and the multi-level path wherever the Pallas kernel
+  (ops/pallas/roi_align.py) does not run.
+- ``roi_align_matmul`` is the same arithmetic for ONE level as a dense
+  matmul over the whole map: ROIAlign is linear in the features, so each
+  output bin is a row of interpolation weights against the (H*W, C) map.
+  No gather, no scatter; the backward is the transposed matmul.
+- In the gather form sample points are accumulated one at a time (sr*sr
+  iterations, unrolled at trace time) so the peak intermediate is
+  (R, S, S, C), not (R, S*sr, S*sr, C).
 """
 
 from __future__ import annotations
@@ -67,6 +76,102 @@ def roi_align(
     # (keeps the Pallas kernel and this reference bit-for-bit interchangeable
     # inside a bf16 train graph, including cotangent dtypes in custom_vjp).
     return (out / (sampling_ratio * sampling_ratio)).astype(features.dtype)
+
+
+def _axis_weights(start, bin_size, output_size, sampling_ratio, extent, cell):
+    """Interpolation weights of one axis, the bin's subsample mean folded in.
+
+    ``start``, ``bin_size``: (B, R) in cells.  ``cell``: (K,) int32, the
+    index along THIS axis of each of the K columns asked for (``arange`` for
+    the axis itself; ``k // W`` or ``k % W`` for the flattened map).
+    Returns (B, S, R, K) float32: row (b, p, r) holds, for each of the ``sr``
+    samples of bin p, the two bilinear taps of ``_bilinear_gather_flat`` —
+    a sample counts iff -1 < s < extent, is clipped to [0, extent - 1], and
+    its upper tap is clamped to extent - 1 so both merge on the edge cell —
+    averaged over the samples.
+    """
+    bins = jnp.arange(output_size, dtype=jnp.float32)[:, None, None]
+    sub = (jnp.arange(sampling_ratio, dtype=jnp.float32)[:, None] + 0.5) / sampling_ratio
+    s = start[:, None, None, :] + (bins + sub) * bin_size[:, None, None, :]  # (B,S,sr,R)
+    inside = (s > -1.0) & (s < extent)
+    c = jnp.clip(s, 0.0, extent - 1.0)
+    c0 = jnp.floor(c)
+    frac = c - c0
+    i0 = c0.astype(jnp.int32)
+    i1 = jnp.minimum(i0 + 1, extent - 1)
+    taps = (
+        jnp.where(cell == i0[..., None], (1.0 - frac)[..., None], 0.0)
+        + jnp.where(cell == i1[..., None], frac[..., None], 0.0)
+    )  # (B, S, sr, R, K)
+    taps = jnp.where(inside[..., None], taps, 0.0)
+    return taps.sum(axis=2) / sampling_ratio
+
+
+@partial(jax.jit, static_argnums=(2, 3, 4))
+def roi_align_matmul(
+    features: jnp.ndarray,
+    rois: jnp.ndarray,
+    output_size: int = 7,
+    spatial_scale: float = 1.0 / 16.0,
+    sampling_ratio: int = 2,
+) -> jnp.ndarray:
+    """ROIAlign over one level for a whole batch, as one matmul per image.
+
+    ``pooled[r, p, q, c] = sum_yx Wy[r, p, y] * Wx[r, q, x] * F[y, x, c]``:
+    the two factors are multiplied out into ONE (S*S*R, H*W) weight matrix
+    per image and contracted with the (H*W, C) map on the MXU.  The weights
+    are ``roi_align``'s to the letter (``_axis_weights``); most are zero,
+    and the chip multiplies by them anyway — that is cheaper there than a
+    gather (measured: PERF.md section 6, PR 26).  The cost grows with
+    H*W*C per roi, not with the roi's extent.
+
+    Precision is the features': float32 features dot float32 weights at
+    ``HIGHEST``; bfloat16 features dot the weights split ``w = hi + lo``
+    into two bfloat16 halves (about 2**-17 relative: a single bfloat16
+    weight would shift WHERE a feature is sampled by 2**-8, the Pallas
+    kernel's rule) with float32 accumulation, and the sum is rounded once,
+    to the features' dtype.  The backward is autodiff's: the same two
+    matmuls transposed, each accumulated in float32 inside the MXU.  The
+    rois carry no gradient (proposals are decoded from stop-gradient
+    scores and deltas).
+
+    Args:
+      features: (B, H, W, C).
+      rois: (B, R, 4) boxes in input-image coordinates (x1, y1, x2, y2).
+      output_size, spatial_scale, sampling_ratio: as ``roi_align``.
+
+    Returns:
+      (B, R, S, S, C) pooled features in the features' dtype.
+    """
+    b, h, w, c = features.shape
+    r = rois.shape[1]
+    s = output_size
+    # Rows of the weight matrix are ordered (p, q, r) with R padded to a
+    # multiple of 8: R then fills whole sublanes of the chip's (8, 128)
+    # tiles and the reshape below is a relabelling, not a copy (300 eval
+    # rois unpadded: 12.4 ms where padded takes 9.0, PERF.md, PR 26).
+    rois = jnp.pad(rois, ((0, 0), (0, -r % 8), (0, 0)))
+    rp = rois.shape[1]
+    scaled = jax.lax.stop_gradient(rois) * spatial_scale
+    x1, y1 = scaled[..., 0], scaled[..., 1]
+    rw = jnp.maximum(scaled[..., 2] - x1, 1.0)
+    rh = jnp.maximum(scaled[..., 3] - y1, 1.0)
+    k = jnp.arange(h * w, dtype=jnp.int32)
+    wy = _axis_weights(y1, rh / s, s, sampling_ratio, h, k // w)  # (B, S, R, HW)
+    wx = _axis_weights(x1, rw / s, s, sampling_ratio, w, k % w)
+    weights = (wy[:, :, None] * wx[:, None, :]).reshape(b, s * s * rp, h * w)
+    flat = features.reshape(b, h * w, c)
+    dot = partial(jnp.einsum, "bmk,bkc->bmc", preferred_element_type=jnp.float32)
+    if features.dtype == jnp.bfloat16:
+        hi = weights.astype(jnp.bfloat16)
+        lo = (weights - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+        out = dot(hi, flat) + dot(lo, flat)
+    else:
+        out = dot(
+            weights, flat.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST
+        )
+    out = out.astype(features.dtype).reshape(b, s, s, rp, c)[:, :, :, :r]
+    return out.transpose(0, 3, 1, 2, 4)
 
 
 def _bilinear_gather(flat, h, w, sy, sx):

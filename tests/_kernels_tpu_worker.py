@@ -1,5 +1,6 @@
-"""On-TPU probe of every ``pallas_call`` site: Mosaic compiles it at recipe
-shapes and it matches its XLA oracle.
+"""On-TPU probe of every ``pallas_call`` site (Mosaic compiles it at recipe
+shapes and it matches its XLA oracle) and of the one-level matmul ROIAlign
+against the gather form.
 
 Runs on the REAL chip (one process, jax's default platform — the parent
 test refuses anything but a TPU).  The interpret-mode CPU tests cannot see
@@ -14,6 +15,11 @@ Probes, each printed as one entry of the final ``RESULT {json}`` line:
   tiny_synthetic / overfit-golden dtype) vs ``multilevel_roi_align``.
 - ``roi_align_bwd[train]`` — the window-RMW backward vs autodiff of the
   XLA reference (``MX_RCNN_POOL_BWD=xla``) with a bf16 cotangent.
+- ``roi_align_matmul[vgg16_voc07.train_b16]`` — the one-level path
+  (``ops/roi_align.py::roi_align_matmul``, plain XLA) at the benchmark
+  cell's shape, 16 x 38 x 64 x 512 bf16 and 16 x 128 rois, vs the vmapped
+  gather form, forward and feature gradient, with the measured time of
+  each (the readings PERF.md quotes).
 - ``nms[2000]`` — ``nms_mask_pallas`` vs ``nms_mask``.
 - ``fused_middle[train|eval]`` — ``generate_fpn_proposals`` with
   ``fused_middle=True`` vs the dense chain, under ``jax.vmap`` over the
@@ -201,6 +207,86 @@ def probe_roi_align_bwd(batch, n_rois):
     }
 
 
+def probe_roi_align_matmul(batch, h, w, channels, n_rois):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mx_rcnn_tpu.ops.roi_align import roi_align, roi_align_matmul
+
+    rng = np.random.default_rng(0)
+    feat = jnp.asarray(
+        rng.standard_normal((batch, h, w, channels)), jnp.bfloat16
+    )
+    # Boxes log-uniform from one cell to past the whole map, centres
+    # anywhere on it: some reach past every border.
+    size = np.exp(rng.uniform(np.log(16), np.log(w * 16 * 1.1), (batch, n_rois, 2)))
+    cx = rng.uniform(0, w * 16, (batch, n_rois))
+    cy = rng.uniform(0, h * 16, (batch, n_rois))
+    rois = jnp.asarray(
+        np.stack(
+            [cx - size[..., 0] / 2, cy - size[..., 1] / 2,
+             cx + size[..., 0] / 2, cy + size[..., 1] / 2], -1,
+        ),
+        jnp.float32,
+    )
+    cot = jnp.asarray(
+        rng.standard_normal((batch, n_rois, 7, 7, channels)), jnp.bfloat16
+    )
+
+    def both_ways(pool):
+        def run(f, r, g):
+            out, vjp = jax.vjp(lambda x: pool(x, r), f)
+            return out, vjp(g)[0]
+
+        return jax.jit(run)
+
+    forms = {
+        "matmul": both_ways(lambda f, r: roi_align_matmul(f, r, 7, 1 / 16.0, 2)),
+        "gather": both_ways(
+            jax.vmap(lambda f, r: roi_align(f, r, 7, 1 / 16.0, 2))
+        ),
+    }
+    out, ms = {}, {}
+    for name, fn in forms.items():
+        out[name] = jax.block_until_ready(fn(feat, rois, cot))
+        best = float("inf")
+        for _ in range(3):
+            t0 = time.perf_counter()
+            for _ in range(5):
+                res = fn(feat, rois, cot)
+            jax.block_until_ready(res)
+            best = min(best, (time.perf_counter() - t0) / 5)
+        ms[name] = round(best * 1e3, 3)
+
+    def f32(x):
+        return np.asarray(jax.device_get(x), np.float32)
+
+    feat_scale = float(jnp.max(jnp.abs(feat.astype(jnp.float32))))
+    fwd_rel = float(np.abs(f32(out["matmul"][0]) - f32(out["gather"][0])).max()) / feat_scale
+    g_m, g_g = f32(out["matmul"][1]), f32(out["gather"][1])
+    bwd_rel = float(np.abs(g_m - g_g).max()) / float(np.abs(g_g).max())
+    # Forward: both forms interpolate to f32 accuracy (the matmul's split
+    # weights are exact to ~2^-17) and round once to bf16, so they differ
+    # by adjacent bf16 numbers at most — inside the 3 eps of the feature
+    # scale the Pallas forward is held to.  Backward: the gather's
+    # scatter-add accumulates in bf16 (the band of probe_roi_align_bwd,
+    # 0.03 of the gradient scale); the matmul accumulates in f32.
+    fwd_ceiling, bwd_ceiling = 3 * BF16_EPS, 0.03
+    return {
+        "ok": bool(
+            np.isfinite(g_m).all()
+            and fwd_rel <= fwd_ceiling and bwd_rel <= bwd_ceiling
+        ),
+        "fwd_max_abs_diff_over_feature_scale": fwd_rel,
+        "fwd_ceiling": fwd_ceiling,
+        "bwd_max_abs_diff_over_grad_scale": bwd_rel,
+        "bwd_ceiling": bwd_ceiling,
+        "fwd_plus_bwd_ms": ms,
+        "shape": list(out["matmul"][0].shape),
+    }
+
+
 def probe_nms(n):
     import jax
     import jax.numpy as jnp
@@ -352,6 +438,8 @@ PROBES = (
     ("roi_align_fwd[b2x512,f32]", True,
      probe_roi_align_fwd, (2, 512, "float32")),
     ("roi_align_bwd[train,b2x512,bf16]", True, probe_roi_align_bwd, (2, 512)),
+    ("roi_align_matmul[vgg16_voc07.train_b16]", True,
+     probe_roi_align_matmul, (16, 38, 64, 512, 128)),
     ("nms[2000]", False, probe_nms, (2000,)),
     ("fused_middle[train,b2,k2000]", False, probe_fused_middle, (True,)),
     ("fused_middle[eval,b8,k1000]", False, probe_fused_middle, (False,)),

@@ -11,6 +11,7 @@ from mx_rcnn_tpu.ops import (
     multilevel_roi_align,
     nms_mask,
     roi_align,
+    roi_align_matmul,
     sample_rois,
 )
 from mx_rcnn_tpu.ops.nms import nms_indices
@@ -131,6 +132,161 @@ def test_roi_align_gradient_flows(rng):
     g = jax.grad(f)(feat)
     assert bool(jnp.isfinite(g).all())
     assert float(jnp.abs(g).sum()) > 0
+
+
+# The one-level matmul form (ops/roi_align.py::roi_align_matmul) against the
+# gather form and the numpy oracle.  A non-square, odd-sized map at stride
+# 16 (144 x 208 px), so a transposed axis or an off-by-one extent shows.
+_MAP_H, _MAP_W = 9, 13
+_BF16_EPS = 2.0 ** -8  # one bf16 rounding (tests/_kernels_tpu_worker.py)
+
+def _random_rois(batch=3, n=12):
+    r = np.random.RandomState(7)
+    x1 = r.uniform(-60, _MAP_W * 16, (batch, n))
+    y1 = r.uniform(-60, _MAP_H * 16, (batch, n))
+    bw = np.exp(r.uniform(np.log(4), np.log(_MAP_W * 16 * 1.3), (batch, n)))
+    bh = np.exp(r.uniform(np.log(4), np.log(_MAP_H * 16 * 1.3), (batch, n)))
+    return np.stack([x1, y1, x1 + bw, y1 + bh], -1)
+
+
+_ROI_CASES = {
+    # (B, R, 4) boxes in image pixels.
+    "past_left": [[[-70.0, 20.5, 60.0, 100.0], [-300.0, 10.0, -2.0, 90.0]]],
+    "past_top": [[[30.0, -55.5, 120.0, 40.0], [10.0, -400.0, 190.0, -20.0]]],
+    "past_right": [[[150.0, 30.0, 290.5, 110.0], [230.0, 5.0, 400.0, 80.0]]],
+    "past_bottom": [[[20.0, 100.0, 90.0, 201.5], [5.0, 170.0, 200.0, 300.0]]],
+    "under_one_cell": [[[40.3, 50.7, 44.1, 53.2], [100.0, 100.0, 100.0, 100.0]]],
+    "whole_map": [[[0.0, 0.0, 208.0, 144.0], [-40.0, -30.0, 260.5, 190.25]]],
+    "batch_of_three": _random_rois(),
+}
+_CASE_NAMES = list(_ROI_CASES)
+
+
+def _gather_form(feat, rois, s, sr):
+    return jax.vmap(lambda f, r: roi_align(f, r, s, 1 / 16.0, sr))(feat, rois)
+
+
+def _one_level_inputs(case, channels=6):
+    rois = np.asarray(_ROI_CASES[case], np.float32)
+    feat = np.random.RandomState(3).standard_normal(
+        (rois.shape[0], _MAP_H, _MAP_W, channels)
+    ).astype(np.float32)
+    return feat, rois
+
+
+@pytest.mark.parametrize("sr", [1, 2])
+@pytest.mark.parametrize("s", [7, 14])
+@pytest.mark.parametrize("case", _CASE_NAMES)
+def test_roi_align_matmul_matches_gather_and_oracle_f32(case, s, sr):
+    feat, rois = _one_level_inputs(case)
+    got = np.asarray(roi_align_matmul(jnp.asarray(feat), jnp.asarray(rois), s, 1 / 16.0, sr))
+    assert got.shape == (rois.shape[0], rois.shape[1], s, s, feat.shape[-1])
+    assert got.dtype == np.float32
+    gather = np.asarray(_gather_form(jnp.asarray(feat), jnp.asarray(rois), s, sr))
+    np.testing.assert_allclose(got, gather, rtol=0, atol=1e-5)
+    oracle = np.stack(
+        [roi_align_np(f, r, s, 1 / 16.0, sr) for f, r in zip(feat, rois)]
+    )
+    np.testing.assert_allclose(got, oracle, rtol=0, atol=1e-5)
+
+
+@pytest.mark.parametrize("case", _CASE_NAMES)
+def test_roi_align_matmul_bf16_within_the_pallas_ceiling(case):
+    # The ceiling the chip worker holds the Pallas forward to: 3 bf16
+    # roundings of the feature scale (tests/_kernels_tpu_worker.py).
+    feat, rois = _one_level_inputs(case)
+    got = roi_align_matmul(
+        jnp.asarray(feat, jnp.bfloat16), jnp.asarray(rois), 7, 1 / 16.0, 2
+    )
+    assert got.dtype == jnp.bfloat16
+    truth = np.asarray(
+        _gather_form(
+            jnp.asarray(feat, jnp.bfloat16).astype(jnp.float32),
+            jnp.asarray(rois), 7, 2,
+        )
+    )
+    err = np.abs(np.asarray(got, np.float32) - truth).max()
+    assert err <= 3 * _BF16_EPS * np.abs(feat).max()
+
+
+@pytest.mark.parametrize("sr", [1, 2])
+@pytest.mark.parametrize("case", _CASE_NAMES)
+def test_roi_align_matmul_feature_gradient_f32(case, sr):
+    feat, rois = _one_level_inputs(case)
+    cot = np.random.RandomState(5).standard_normal(
+        (*rois.shape[:2], 7, 7, feat.shape[-1])
+    ).astype(np.float32)
+
+    def grad_of(fn):
+        return np.asarray(
+            jax.grad(lambda f: jnp.sum(fn(f) * cot))(jnp.asarray(feat))
+        )
+
+    got = grad_of(lambda f: roi_align_matmul(f, jnp.asarray(rois), 7, 1 / 16.0, sr))
+    want = grad_of(lambda f: _gather_form(f, jnp.asarray(rois), 7, sr))
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-6)
+
+
+@pytest.mark.parametrize("what", ["forward", "gradient"])
+@pytest.mark.parametrize("case", _CASE_NAMES)
+def test_roi_align_matmul_bf16_no_further_from_f32_than_the_gather(case, what):
+    """The precision rule: split (hi + lo) bf16 weights and float32
+    accumulation.  A single-pass bf16 weight, or a bf16 accumulator, reads
+    several times the gather form's own bf16 error here."""
+    feat, rois = _one_level_inputs(case, channels=64)
+    rois = jnp.asarray(rois)
+    feat16 = jnp.asarray(feat, jnp.bfloat16)
+    cot = jnp.asarray(
+        np.random.RandomState(5).standard_normal(
+            (*rois.shape[:2], 7, 7, feat.shape[-1])
+        ),
+        jnp.bfloat16,
+    )
+    forms = {
+        "matmul": lambda f: roi_align_matmul(f, rois, 7, 1 / 16.0, 2),
+        "gather": lambda f: _gather_form(f, rois, 7, 2),
+    }
+
+    def value(form, f):
+        if what == "forward":
+            return np.asarray(forms[form](f), np.float32)
+        loss = lambda x: jnp.sum(  # noqa: E731
+            forms[form](x).astype(jnp.float32) * cot.astype(jnp.float32)
+        )
+        return np.asarray(jax.grad(loss)(f), np.float32)
+
+    truth = value("gather", feat16.astype(jnp.float32))
+    err = {
+        form: np.linalg.norm(value(form, feat16) - truth) / np.linalg.norm(truth)
+        for form in forms
+    }
+    # 1 %: both round a float32 sum once, in another summation order.
+    assert err["matmul"] <= 1.01 * err["gather"], err
+
+
+def test_one_level_pyramid_pools_by_matmul_without_gather_or_scatter():
+    from mx_rcnn_tpu.config import get_config
+    from mx_rcnn_tpu.detection import graph
+
+    cfg = get_config("vgg16_voc07").model
+    feat, rois = map(jnp.asarray, _one_level_inputs("batch_of_three", channels=8))
+
+    def pool(f):
+        return graph._pool_rois(cfg, {4: f}, rois, 7, (4,))
+
+    graph.LAST_POOL_IMPL = None
+    got = pool(feat)
+    assert graph.LAST_POOL_IMPL == "matmul"
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(_gather_form(feat, rois, 7, 2)),
+        rtol=0, atol=1e-5,
+    )
+    forward = str(jax.make_jaxpr(pool)(feat))
+    backward = str(jax.make_jaxpr(jax.grad(lambda f: jnp.sum(pool(f) ** 2)))(feat))
+    for text in (forward, backward):
+        assert "dot_general" in text
+        assert "gather" not in text
+        assert "scatter" not in text
 
 
 def test_fpn_level_assignment():
