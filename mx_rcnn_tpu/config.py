@@ -37,8 +37,53 @@ class AnchorConfig:
 
 
 @dataclass(frozen=True)
+class DecoderConfig:
+    """Decoder-only language-model blocks used as a plain stride-16 backbone
+    (models/decoder.py; read only when ``BackboneConfig.name`` names one).
+    The defaults are Ling-3.0-flash-VL's published sizes
+    (huggingface.co/inclusionAI/Ling-3.0-flash-VL config.json) at one chip's
+    share: the published layers ``layers`` are held (a layer's kind follows
+    from its published index), and of each layer's ``num_experts`` routed
+    experts the ``experts_count`` from ``experts_first`` on; the router keeps
+    its published width."""
+
+    hidden_size: int = 2560
+    num_heads: int = 32
+    head_dim: int = 128
+    # Published indices of the layers held: the leading dense layer once, and
+    # one whole period of ``layer_group_size``.
+    layers: tuple[int, ...] = (0, 6, 7, 8, 9, 10, 11)
+    first_k_dense: int = 2        # layers below it: dense SwiGLU, no experts
+    layer_group_size: int = 6     # the last layer of each group is MLA, the rest KDA
+    intermediate_size: int = 6144
+    moe_intermediate_size: int = 768
+    num_experts: int = 512
+    experts_first: int = 0
+    experts_count: int = 8
+    num_experts_per_tok: int = 8
+    n_group: int = 8
+    topk_group: int = 4
+    routed_scaling_factor: float = 2.5
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    rope_theta: float = 6.0e6
+    rms_norm_eps: float = 1.0e-6
+    short_conv_kernel: int = 4
+    kda_lower_bound: float = -5.0
+    patch: int = 16               # stride-16 patchify convolution
+    neck_channels: int = 256
+
+
+DECODER_BACKBONES = ("ling3_flash_vl",)
+
+
+@dataclass(frozen=True)
 class BackboneConfig:
-    name: str = "resnet50"  # resnet50 | resnet101 | vgg16
+    # resnet50 | resnet101 | vgg16 | ling3_flash_vl (decoder blocks as a
+    # plain backbone, sized by ``decoder``)
+    name: str = "resnet50"
     # Stages to freeze, counted like the reference's fixed_param_prefix
     # (conv1 + res2 frozen for ResNet; conv1_/conv2_ for VGG).
     freeze_stages: int = 2
@@ -69,6 +114,7 @@ class BackboneConfig:
     # across an R101 trunk — FrozenBN does NOT all fuse into the convs).
     # ResNet + frozen_bn only; no-op otherwise.  Param tree unchanged.
     fold_frozen_bn: bool = False
+    decoder: DecoderConfig = field(default_factory=DecoderConfig)
 
 
 @dataclass(frozen=True)
@@ -677,6 +723,11 @@ def _backbone(name: str) -> BackboneConfig:
         return BackboneConfig(
             name=name, stem_s2d=True, stem_pool_fold=True, c2_pad=True
         )
+    if name in DECODER_BACKBONES:
+        # Nothing frozen (no pretrained stem to protect); every block is
+        # recomputed on the backward pass (12 bytes a parameter leave no
+        # room for stored activations).
+        return BackboneConfig(name=name, freeze_stages=0, norm="none", remat=True)
     return BackboneConfig(name=name)
 
 
@@ -784,6 +835,27 @@ _register(
     lambda: Config(
         name="mask_r50_fpn_coco",
         model=_fpn_model(81, "resnet50", mask=True),
+        data=DataConfig(dataset="coco"),
+        train=TrainConfig(per_device_batch=2),
+    ),
+)
+def _ling3_flash_vl_det_model() -> ModelConfig:
+    m = _c4_model(81, "ling3_flash_vl")
+    # Two images a call at test time too: 4,200 tokens an image through
+    # 802 M parameters leave no room for the default eight.
+    return _replace(
+        m, rpn=_replace(m.rpn, channels=256), test=_replace(m.test, per_device_batch=2)
+    )
+
+
+# Ling-3.0-flash-VL's decoder blocks (KDA linear attention, MLA, routed
+# experts at one chip's share) as a plain stride-16 backbone (Li et al.,
+# arXiv:2203.16527, without the pyramid) under the one-level middle.
+_register(
+    "ling3_flash_vl_det",
+    lambda: Config(
+        name="ling3_flash_vl_det",
+        model=_ling3_flash_vl_det_model(),
         data=DataConfig(dataset="coco"),
         train=TrainConfig(per_device_batch=2),
     ),

@@ -612,7 +612,9 @@ def forward_train(model: TwoStageDetector, variables, rng: jax.Array, batch: Bat
     """
     cfg = model.cfg
     images = prep_images(batch.images, pixel_stats)
-    feats = model.apply(variables, images, method="features")
+    # ``counters``: what a backbone sows of its own routing (models/decoder.py);
+    # empty for the convolutional ones, whose trace this leaves as it was.
+    feats, sown = model.apply(variables, images, method="features", mutable=["counters"])
 
     b = images.shape[0]
     rng_assign = rng_sample = None
@@ -726,6 +728,8 @@ def forward_train(model: TwoStageDetector, variables, rng: jax.Array, batch: Bat
         "RCNNL1Loss": rcnn_box,
         "loss": total,
     }
+    for name, (value,) in sorted(sown.get("counters", {}).get("backbone", {}).items()):
+        metrics[name] = value
 
     if cfg.mask.enabled and batch.gt_masks is not None:
         # sample_rois compacts fg into a leading block, so the static fg

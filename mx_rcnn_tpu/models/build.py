@@ -5,7 +5,8 @@ from __future__ import annotations
 import jax.numpy as jnp
 from flax import linen as nn
 
-from mx_rcnn_tpu.config import BackboneConfig
+from mx_rcnn_tpu.config import DECODER_BACKBONES, BackboneConfig
+from mx_rcnn_tpu.models.decoder import DecoderBackbone
 from mx_rcnn_tpu.models.resnet import ResNet, STAGE_BLOCKS
 from mx_rcnn_tpu.models.vgg import VGG16
 
@@ -34,4 +35,9 @@ def build_backbone(
                 "conv stack with no strided RGB conv to rewrite)"
             )
         return VGG16(dtype=dtype, remat=cfg.remat, name="backbone")
-    raise ValueError(f"unknown backbone {cfg.name!r}")
+    if cfg.name in DECODER_BACKBONES:
+        if cfg.stem_s2d:
+            raise ValueError("backbone.stem_s2d is ResNet-only (a decoder backbone patchifies)")
+        return DecoderBackbone(cfg=cfg.decoder, dtype=dtype, remat=cfg.remat, name="backbone")
+    known = sorted(STAGE_BLOCKS) + ["vgg16"] + list(DECODER_BACKBONES)
+    raise ValueError(f"unknown backbone {cfg.name!r}; known: {known}")

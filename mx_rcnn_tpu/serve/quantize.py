@@ -155,6 +155,17 @@ def quantize_network(variables) -> dict:
 
     def one(path, leaf):
         keys = _path_keys(path)
+        if "router" in keys or "kda" in keys:
+            # A decoder backbone (models/decoder.py): int8 weights move the
+            # router's top-k picks and the KDA decay gate, and the repo has
+            # no calibration or parity check for either.
+            raise NotImplementedError(
+                "full-network int8 (the full_q8n level) is not defined for a "
+                f"decoder backbone: leaf {'/'.join(map(str, keys))!r} belongs to an "
+                "expert router or a KDA mixer, whose selection and decay gate "
+                "need a calibrated quantization this repo does not have; "
+                "serve it at the float levels"
+            )
         leaf = jnp.asarray(leaf)
         if keys and keys[0] == "params" and keys[-1] == "kernel" \
                 and leaf.ndim >= 2:

@@ -45,6 +45,14 @@ COMPONENT_PATTERNS: tuple[tuple[str, tuple[str, ...]], ...] = (
     ("C3", ("backbone/layer2_",)),
     ("C4", ("backbone/layer3_",)),
     ("C5", ("backbone/layer4_",)),
+    # The decoder backbone's scopes (models/decoder.py): each block's mixer
+    # and feed-forward under ``backbone/l<k>/``.
+    ("patchify", ("backbone/patchify",)),
+    ("KDA", ("/kda/",)),
+    ("MLA", ("/mla/",)),
+    ("MoE", ("/moe/",)),
+    ("dense-FFN", ("/ffn/",)),
+    ("neck", ("backbone/neck",)),
     ("FPN", ("/fpn/", "fpn/lateral", "fpn/output", "fpn_topdown")),
     ("RPN-head", ("rpn.packed", "rpn._heads", "/rpn/", ".rpn)")),
     ("ROI", ("roi_align",)),
@@ -81,7 +89,8 @@ def component_of(name_stack: str) -> str:
     """Model component for a jaxpr/HLO name stack; ``other`` if unmatched
     (everything FLOP-bearing is scoped — ``other`` should stay ~empty;
     tools/tpulint.py enforces >=99% attribution on the train step)."""
-    s = _DECORATIONS.sub("", str(name_stack)).replace(")", "")
+    # A closing "/" so that a pattern "/x/" also finds the stack's last scope.
+    s = _DECORATIONS.sub("", str(name_stack)).replace(")", "") + "/"
     for comp, pats in COMPONENT_PATTERNS:
         if any(p in s for p in pats):
             return comp
@@ -98,7 +107,10 @@ def _bucket(acc: dict, comp: str) -> dict:
 
 def _walk(jaxpr, scale: float, acc: dict, outer_stack: str) -> None:
     for eqn in jaxpr.eqns:
-        stack = str(eqn.source_info.name_stack) or outer_stack
+        # A sub-jaxpr's name stacks are relative to the equation that holds
+        # it (a checkpoint inside a checkpoint, a scan's body): keep both.
+        inner = str(eqn.source_info.name_stack)
+        stack = f"{outer_stack}/{inner}" if outer_stack and inner else inner or outer_stack
         prim = eqn.primitive.name
         if prim in ("conv_general_dilated", "dot_general"):
             f = (_conv_flops if prim == "conv_general_dilated" else _dot_flops)(eqn)
