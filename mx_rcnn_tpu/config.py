@@ -184,13 +184,13 @@ class RPNConfig:
     # masked-out terms are exact zeros), so metrics match to f32
     # round-off, not bitwise — opt-in for A/B.
     loss_impl: str = "dense"
-    # Sweep bound for the proposal NMS fixed point (ops/nms.py).  0 =
-    # iterate to convergence (exact greedy NMS, the default).  > 0 caps
-    # the batched per-level lane at that many sweeps: any cap >= N is
-    # still exact and score-sorted RPN boxes converge in a handful of
-    # sweeps, so a cap like 16 bounds the worst lane's data-dependent
-    # latency while matching exact NMS on everything but adversarial
-    # box soups.
+    # Sweep bound for the proposal NMS (ops/nms.py), which resolves the
+    # candidates tile by tile in score order with a fixed point inside
+    # each tile.  0 = every tile iterates to convergence (exact greedy
+    # NMS, the default).  > 0 bounds EACH TILE's loop to that many
+    # sweeps: any cap >= N is still exact and the best box survives any
+    # cap; a tile of real RPN candidates is 9-17 sweeps deep (PERF.md
+    # section 6, PR 29), so a smaller cap changes which boxes are kept.
     nms_sweep_cap: int = 0
     # Run the weight-shared head over all FPN levels as ONE packed
     # computation (models/heads.py::RPNHead.packed) instead of five

@@ -6,24 +6,60 @@ bitmask kernel ``rcnn/cython/nms_kernel.cu``).  The reference runs NMS on
 the host (or a CUDA kernel) with a device round-trip inside the Proposal
 custom op; here NMS stays inside the jitted step.
 
-Algorithm: score-sort, build the O(N^2) IoU "suppression" matrix (strictly
-upper-triangular: an earlier box can suppress a later one), then iterate
+Algorithm: exact greedy NMS, tile by tile in score order, with no N x N
+array anywhere.  Sort by score (stable), cut the candidates into
+``ceil(N / TILE)`` tiles and take them in order.  For a tile:
 
-    keep[i] <- not OR_{j<i} (keep[j] AND iou[j, i] > thresh)
+1. *Across tiles.*  Every suppressor outside the tile has a better score,
+   sits in an earlier tile and is final already:
 
-to a fixed point with ``lax.while_loop``.  Any fixed point of this map is
-exactly the greedy-NMS solution (induction over i), and the iteration
-finalizes at least one undecided box per sweep, so it terminates in at most
-N sweeps — in practice a handful, each an O(N^2) VPU-friendly masked
-reduction, with no host sync and no dynamic shapes.
+       alive[i] = valid[i] and not OR_j (keep[j] and iou[j, i] > thresh)
 
-Measured alternative (v5e, honest chained timing): a Detectron-style
-64-box blocked-greedy lax.scan has a FIXED O(N^2/B) cost, but its ~2N/B
-sequential tiny steps serialize poorly on TPU — 116 ms vs this
-implementation's 91 ms even on the adversarial case (2000 iid random
-boxes, where the sweep count is worst-case), and it loses ~0.8 img/s on
-the full train-step bench (where RPN's score-sorted boxes converge in a
-few sweeps).  The data-dependent sweep count is the better trade here.
+   over the boxes ``j`` of the tiles done, a TILE x TILE block of pairs at
+   a time — a compare-and-reduce whose IoU block is made inside the
+   reduction's fusion and never written out.  Only blocks above the
+   diagonal exist: half the pair arithmetic of a full matrix.
+2. *Inside the tile.*  On the TILE x TILE block (strictly upper triangular:
+   an earlier box can suppress a later one) iterate
+
+       keep[i] <- alive[i] and not OR_{j<i} (keep[j] and iou[j, i] > thresh)
+
+   to a fixed point with ``lax.while_loop``.  Any fixed point of this map is
+   exactly the greedy-NMS solution of the tile given ``alive`` (induction
+   over i), the iteration finalizes at least one undecided box per sweep,
+   and by (1) everything outside the tile that could suppress box i was
+   final before the tile started — so the tiles' results, in order, are the
+   greedy solution of the whole set, bit for bit what the dense form gives.
+
+N <= TILE is one tile: the plain fixed point on the whole matrix, which is
+what per-class ``batched_nms`` over a few hundred detections and every
+small shape run.
+
+Why tiles (TPU v5e, PERF.md sections 5-6, PR 29).  The form this replaced
+built the whole 6000 x 6000 IoU matrix in float32 and a boolean mask per
+image and swept the mask whole: on the benchmark's VGG-16 step (16 images,
+6000 -> 2000) that was 39.6 ms of a 174 ms step (ledger, PR 27) — 7 ms
+writing the matrix, 7 the mask, and 28-30 sweeps of 0.77 ms each, every one
+a read of 576 MB at 91 % of the chip's memory bandwidth, paid by all sixteen
+images until the slowest converged.  What NMS needs from memory is the boxes
+(0.5 MB).  Here a tile's block (4 MB for sixteen images) stays in VMEM
+through its sweeps, ~140 of 7 us a step; the same 16 x 6000 candidates take
+6.2 ms standalone against 35.3 (tests/_kernels_tpu_worker.py).
+
+Why the tile loop is a ``fori_loop`` over row blocks and not unrolled.  Both
+take the same time (5.7 ms a call).  Unrolled, with the tile's block stored,
+the cross-tile reduction came out WRONG on the chip under two nested
+``vmap``s — images x pyramid levels, how every FPN preset reaches this —
+while one ``vmap`` and the CPU agreed with the oracle bit for bit: 10,509
+of 40,000 keep bits at 8 x 5 x 1000, whichever way the block was stored
+(``optimization_barrier``, a concatenate, bit-packed words).  The loop form
+is right on every shape tried, flat and nested, and the worker's probe holds
+it there.  Timed beside them on the same candidates: tiles of 256 and 1024
+(6.5 ms each against 6.1 at 512), one global fixed point over a bit-packed
+mask (9.5), the tile's sweep as an MXU product (5.8).  The sequential
+alternatives lost long before: a 64-box blocked-greedy ``lax.scan`` needs
+~2N/64 tiny steps, the Pallas sweep (``ops/pallas/nms.py``) N of them.  The
+dense form lives on as the oracle in ``tests/oracles.py::nms_mask_dense``.
 """
 
 from __future__ import annotations
@@ -34,7 +70,59 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from mx_rcnn_tpu.geometry import iou_matrix, snap
+from mx_rcnn_tpu.geometry import area, snap
+
+
+# Candidates per tile.  Read against the static N: N <= TILE is one tile, the
+# plain fixed point on the whole (small) matrix.  Picked on the chip at
+# 16 x 6000 and 40 x 2000 (PERF.md section 6, PR 29); a program constant
+# like ``ops/kda.py::CHUNK``, not a setting.
+TILE = 512
+
+
+def _overlaps(rows: jnp.ndarray, cols: jnp.ndarray, iou_threshold: float):
+    """(R, C) bool: does box ``rows[j]`` overlap box ``cols[i]`` past the
+    threshold.  Made to be consumed inside a reduction's fusion, never stored
+    at R x C for a large R.
+
+    ``geometry.iou_matrix``'s arithmetic to the letter, one coordinate at a
+    time: its ``(R, C, 2)`` corner arrays are what XLA wrote out to HBM
+    (a trailing axis of 2 fuses into nothing on this chip).
+    """
+    rx1, ry1, rx2, ry2 = (rows[:, c, None] for c in range(4))
+    cx1, cy1, cx2, cy2 = (cols[None, :, c] for c in range(4))
+    w = jnp.maximum(jnp.minimum(rx2, cx2) - jnp.maximum(rx1, cx1), 0.0)
+    h = jnp.maximum(jnp.minimum(ry2, cy2) - jnp.maximum(ry1, cy1), 0.0)
+    inter = w * h
+    union = area(rows)[:, None] + area(cols)[None, :] - inter
+    iou = jnp.where(union > 0.0, inter / jnp.where(union > 0.0, union, 1.0), 0.0)
+    # snap(): the > threshold suppression decision must not flip on
+    # cross-compilation ulp noise (see geometry.boxes.snap); one flipped
+    # suppression cascades through the whole greedy chain.
+    return snap(iou) > iou_threshold
+
+
+def _tile_fixed_point(alive, suppress, sweep_cap: int):
+    """``keep[i] <- alive[i] & ~any_j(keep[j] & suppress[j, i])`` iterated
+    from ``keep = alive`` until it stops changing (or ``sweep_cap`` sweeps)."""
+
+    def cond(state):
+        keep, prev, it = state
+        changed = jnp.any(keep != prev)
+        return changed & (it < sweep_cap) if sweep_cap > 0 else changed
+
+    def body(state):
+        keep, _, it = state
+        # One sweep = one run of this scope's ops: the trace shows a loop
+        # body's ops once per iteration, so sweeps are countable per step
+        # (perfbench/metrics/nms_sweeps.train.py).
+        with jax.named_scope("nms_sweep"):
+            new_keep = alive & ~jnp.any(suppress & keep[:, None], axis=0)
+        return new_keep, keep, it + 1
+
+    init = (alive, jnp.zeros_like(alive), jnp.asarray(0, jnp.int32))
+    keep, _, _ = lax.while_loop(cond, body, init)
+    return keep
 
 
 def nms_mask(
@@ -53,14 +141,13 @@ def nms_mask(
       iou_threshold: suppression threshold (reference default 0.7 for RPN
         proposals, 0.3 at test time).
       valid: optional (N,) bool; invalid entries never keep nor suppress.
-      sweep_cap: 0 (default) iterates the fixed point to convergence —
-        exact greedy NMS.  > 0 bounds the while_loop to that many sweeps:
-        each sweep finalizes at least one undecided box, so any cap >= N
-        is still exact, and score-sorted RPN boxes converge in a handful
-        of sweeps regardless; a small cap trades exactness on adversarial
-        inputs for a hard latency bound (the batched per-level lane then
-        pays a bounded worst case instead of the slowest lane's
-        data-dependent sweep count).  Opt-in via ``RPNConfig.nms_sweep_cap``.
+      sweep_cap: 0 (default) iterates every tile's fixed point to
+        convergence — exact greedy NMS.  > 0 bounds EACH TILE's
+        while_loop to that many sweeps: each sweep finalizes at least one
+        undecided box, so any cap >= N is still exact, and the best box
+        survives any cap; a small cap trades exactness on adversarial
+        inputs for a hard latency bound.  Opt-in via
+        ``RPNConfig.nms_sweep_cap``.
 
     Returns:
       (N,) bool keep mask.
@@ -75,51 +162,45 @@ def nms_mask(
     sboxes = jnp.take(boxes, order, axis=0)
     svalid = jnp.take(valid, order)
 
-    # snap(): the > threshold suppression decision must not flip on
-    # cross-compilation ulp noise (see geometry.boxes.snap); one flipped
-    # suppression cascades through the whole greedy chain.
-    iou = snap(iou_matrix(sboxes, sboxes))
-    upper = jnp.triu(jnp.ones((n, n), dtype=bool), k=1)
-    suppress = (iou > iou_threshold) & upper & svalid[:, None] & svalid[None, :]
+    tile = min(n, TILE)
+    upper = jnp.triu(jnp.ones((tile, tile), dtype=bool), k=1)
+    if n <= TILE:  # one tile: the plain fixed point on the whole matrix
+        keep_sorted = _tile_fixed_point(
+            svalid, _overlaps(sboxes, sboxes, iou_threshold) & upper, sweep_cap
+        )
+        return jnp.zeros(n, dtype=bool).at[order].set(keep_sorted)
 
-    if sweep_cap and sweep_cap > 0:
-        # Bounded variant: identical iteration, with a sweep counter in
-        # the carry.  Convergence before the cap gives the exact greedy
-        # fixed point; hitting the cap returns the current iterate.
-        def cond(state):
-            keep, prev, it = state
-            return jnp.any(keep != prev) & (it < sweep_cap)
+    tiles = -(-n // tile)
+    pad = tiles * tile - n  # the tail's padding is invalid: never keeps nor suppresses
+    tboxes = jnp.pad(sboxes, ((0, pad), (0, 0))).reshape(tiles, tile, 4)
+    tvalid = jnp.pad(svalid, (0, pad)).reshape(tiles, tile)
 
-        def body(state):
-            keep, _, it = state
-            with jax.named_scope("nms_sweep"):
-                new_keep = svalid & ~jnp.any(
-                    suppress & keep[:, None], axis=0
-                )
-            return new_keep, keep, it + 1
+    def one_tile(k, kept):
+        """``kept`` (tiles, tile): final for the tiles before ``k``, False after."""
+        cols = tboxes[k]
 
-        init = (svalid, jnp.zeros(n, dtype=bool), jnp.asarray(0, jnp.int32))
-        keep_sorted, _, _ = lax.while_loop(cond, body, init)
-    else:
-        def cond(state):
-            keep, prev = state
-            return jnp.any(keep != prev)
+        def rows_of(j, dead):
+            # Tile j < k is final: its survivors against this tile's boxes,
+            # the block made inside the reduction and never stored.
+            with jax.named_scope("nms_cross"):
+                hit = _overlaps(tboxes[j], cols, iou_threshold) & kept[j][:, None]
+                return dead | jnp.any(hit, axis=0)
 
-        def body(state):
-            keep, _ = state
-            # One sweep = one run of this scope's ops: the trace shows a
-            # loop body's ops once per iteration, so sweeps are countable
-            # per step (perfbench/metrics/nms_sweeps.train.py).
-            with jax.named_scope("nms_sweep"):
-                new_keep = svalid & ~jnp.any(
-                    suppress & keep[:, None], axis=0
-                )
-            return new_keep, keep
+        dead = lax.fori_loop(0, k, rows_of, jnp.zeros(tile, dtype=bool))
+        # The barrier makes the block a stored operand of the loop (4 MB for
+        # 16 images, which XLA then keeps in VMEM); without it XLA sinks the
+        # IoU arithmetic into the loop body and pays it every sweep (8.8 ms
+        # against 5.7 at 16 x 6000, PERF.md section 6, PR 29).
+        suppress = lax.optimization_barrier(
+            _overlaps(cols, cols, iou_threshold) & upper
+        )
+        keep_k = _tile_fixed_point(tvalid[k] & ~dead, suppress, sweep_cap)
+        return kept.at[k].set(keep_k)
 
-        init = (svalid, jnp.zeros(n, dtype=bool))
-        keep_sorted, _ = lax.while_loop(cond, body, init)
-
-    return jnp.zeros(n, dtype=bool).at[order].set(keep_sorted)
+    kept = lax.fori_loop(
+        0, tiles, one_tile, jnp.zeros((tiles, tile), dtype=bool)
+    )
+    return jnp.zeros(n, dtype=bool).at[order].set(kept.reshape(-1)[:n])
 
 
 def rank_keep(keep: jnp.ndarray, scores: jnp.ndarray, max_outputs: int):
@@ -167,12 +248,12 @@ def nms_indices(
     (``rcnn/symbol/proposal.py`` pads rois to RPN_POST_NMS_TOP_N).
 
     ``nms_impl`` selects the keep-mask backend: ``"xla"`` (default) is the
-    batched while-loop fixed point above; ``"pallas"`` routes through the
+    tiled fixed point above; ``"pallas"`` routes through the
     VMEM-resident greedy sweep (``ops/pallas/nms.py::nms_mask_pallas``,
     bit-identical keep bits — it snaps IoU on the same 2**-16 grid before
     the threshold compare).  The pallas sweep is always-exact greedy, so
     ``sweep_cap`` does not apply to it (the cap exists to bound the XLA
-    fixed point's data-dependent sweep count).  ``interpret`` runs the
+    fixed points' data-dependent sweep count).  ``interpret`` runs the
     pallas kernel in interpret mode (CPU CI).
     """
     if nms_impl == "pallas":

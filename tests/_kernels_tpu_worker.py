@@ -20,6 +20,13 @@ Probes, each printed as one entry of the final ``RESULT {json}`` line:
   cell's shape, 16 x 38 x 64 x 512 bf16 and 16 x 128 rois, vs the vmapped
   gather form, forward and feature gradient, with the measured time of
   each (the readings PERF.md quotes).
+- ``nms_tiled[vgg16_voc07.train_b16,seed941]`` — the tiled
+  ``nms_indices`` (``ops/nms.py``, plain XLA, the main path) vs the dense
+  fixed point it replaced (``tests/oracles.py::nms_mask_dense``) at 16 x
+  6000 -> 2000 on the pre-NMS candidates of a real step of the benchmark
+  cell (seed 941's weights and first batch), with the measured time of
+  each (the readings PERF.md quotes), and under two nested ``vmap``s
+  (8 images x 5 levels cut from the same candidates).
 - ``nms[2000]`` — ``nms_mask_pallas`` vs ``nms_mask``.
 - ``fused_middle[train|eval]`` — ``generate_fpn_proposals`` with
   ``fused_middle=True`` vs the dense chain, under ``jax.vmap`` over the
@@ -54,6 +61,21 @@ CANVAS = (800, 1344)
 CHANNELS = 256
 # Largest relative rounding error of one bf16 rounding (8 significand bits).
 BF16_EPS = 2.0 ** -8
+
+
+def _least_ms(fn, *args, reps=3, calls=10):
+    """Least mean time of ``calls`` chained calls over ``reps`` tries, ms."""
+    import jax
+
+    jax.block_until_ready(fn(*args))
+    best = float("inf")
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        for _ in range(calls):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        best = min(best, (time.perf_counter() - t0) / calls)
+    return best * 1e3
 
 
 def _pyramid(rng, batch, dtype):
@@ -250,14 +272,7 @@ def probe_roi_align_matmul(batch, h, w, channels, n_rois):
     out, ms = {}, {}
     for name, fn in forms.items():
         out[name] = jax.block_until_ready(fn(feat, rois, cot))
-        best = float("inf")
-        for _ in range(3):
-            t0 = time.perf_counter()
-            for _ in range(5):
-                res = fn(feat, rois, cot)
-            jax.block_until_ready(res)
-            best = min(best, (time.perf_counter() - t0) / 5)
-        ms[name] = round(best * 1e3, 3)
+        ms[name] = round(_least_ms(fn, feat, rois, cot, calls=5), 3)
 
     def f32(x):
         return np.asarray(jax.device_get(x), np.float32)
@@ -319,6 +334,136 @@ def probe_nms(n):
         "kept": int(want.sum()),
         "n": n,
     }
+
+
+def probe_nms_tiled(seed):
+    """The tiled ``nms_indices`` (ops/nms.py, PR 29) against the dense fixed
+    point it replaced (tests/oracles.py) on a real step's candidates, bit
+    for bit: at the benchmark cell's shape, 16 images x 6000 candidates ->
+    2000 at 0.7, with the measured time of each; and under two nested
+    ``vmap``s (images x levels, as every pyramid preset reaches it), where
+    an unrolled tile loop came out WRONG on this chip while the CPU agreed
+    (PERF.md section 6, PR 29) - several tiles (8 x 5 x <= 2000) and one
+    (8 x 5 x 300)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from oracles import nms_mask_dense  # tests/, this script's own directory
+
+    from mx_rcnn_tpu.ops.nms import TILE, nms_indices, rank_keep
+
+    def tiled(k):
+        return lambda b, s: nms_indices(b, s, 0.7, k)
+
+    def dense(k):
+        return jax.jit(jax.vmap(
+            lambda b, s: rank_keep(nms_mask_dense(b, s, 0.7), s, k)
+        ))
+
+    def slots_that_differ(got, want):
+        # Exact: the same snapped IoUs meet the same threshold in both, and
+        # the greedy result is a function of those decisions alone.
+        return sum(
+            int((np.asarray(g).reshape(w.shape) != np.asarray(w)).sum())
+            for g, w in zip(jax.device_get(got), jax.device_get(want))
+        )
+
+    boxes, scores = real_step_candidates(seed)
+    flat, flat_dense = jax.jit(jax.vmap(tiled(2000))), dense(2000)
+    want = flat_dense(boxes, scores)
+    bad = slots_that_differ(flat(boxes, scores), want)
+
+    # Levels: every third candidate of an image at three offsets and two
+    # shorter ones, padded with -inf as generate_fpn_proposals pads.
+    n, lens = boxes.shape[1], ((0, 2000), (1, 2000), (2, 2000), (0, 1200), (1, 300))
+    lb = np.zeros((8, len(lens), 2000, 4), np.float32)
+    ls = np.full((8, len(lens), 2000), -np.inf, np.float32)
+    for i in range(8):
+        for l, (off, ln) in enumerate(lens):
+            idx = np.arange(off, n, 3)[:ln]
+            lb[i, l, :len(idx)] = np.asarray(boxes[i])[idx]
+            ls[i, l, :len(idx)] = np.asarray(scores[i])[idx]
+    nested_bad = {}
+    for k in (2000, 300):
+        b, s = jnp.asarray(lb[:, :, :k]), jnp.asarray(ls[:, :, :k])
+        nested_bad[f"8x5x{k}"] = slots_that_differ(
+            jax.jit(jax.vmap(jax.vmap(tiled(k))))(b, s),
+            dense(k)(b.reshape(-1, k, 4), s.reshape(-1, k)),
+        )
+    return {
+        "ok": bad == 0 and not any(nested_bad.values()),
+        "mismatched_slots": bad,
+        "mismatched_slots_nested": nested_bad,
+        "kept": int(np.asarray(jax.device_get(want[1])).sum()),
+        "shape": list(boxes.shape),
+        "tile": TILE,
+        "tiled_ms": _least_ms(flat, boxes, scores),
+        "dense_ms": _least_ms(flat_dense, boxes, scores),
+    }
+
+
+def real_step_candidates(seed, workload="vgg16_voc07.train_b16"):
+    """The pre-NMS candidates of a real step: the benchmark cell's model with
+    the weights and the first batch of ``--seed``, forward to the RPN, top-k,
+    decode and clip as ``generate_proposals`` does it.  -> boxes (B, N, 4),
+    scores (B, N) with ``-inf`` on what the min-size mask dropped: what
+    ``nms_indices`` is handed inside the step, so the chain depth is the
+    cell's and not a generator's."""
+    import jax
+    import jax.numpy as jnp
+
+    from mx_rcnn_tpu.detection.graph import level_anchors, prep_images
+    from mx_rcnn_tpu.ops.proposals import _pre_nms_candidates
+    from mx_rcnn_tpu.train.loop import build_all, build_plan
+    from mx_rcnn_tpu.train.state import state_variables
+    from perfbench import program, traffic
+    from perfbench import weights as W
+    from perfbench.reference import detector as D
+    from perfbench.spec import Spec
+
+    spec = Spec(REPO)
+    cell = spec.cell(workload)
+    conf = spec.config(cell["config"])
+    cfg = program.load_config(conf, cell)
+    model, _, state, _, global_batch = build_all(cfg, None)
+    w0 = W.make_weights(seed, D.all_specs(conf["reference"]))
+    variables = state_variables(state)
+    variables = {
+        k: program._place_weights(program._unfreeze(v), w0, k)
+        for k, v in variables.items()
+    }
+    images, boxes, classes = traffic.make_images(
+        spec.traffic(cell["traffic"]), conf["reference"]["num_classes"], seed
+    )
+    feed = program.train_feed(
+        cfg, build_plan(cfg, None, model=model), None,
+        program.records(images, boxes, classes), global_batch,
+        seed % (2**31), program.prefetch_stats(),
+    )
+    try:
+        batch = next(feed)
+    finally:
+        feed.close()
+    rpn = cfg.model.rpn
+
+    @jax.jit
+    def candidates(variables, batch):
+        x = prep_images(batch.images, (cfg.data.pixel_mean, cfg.data.pixel_std))
+        feats, _ = model.apply(variables, x, method="features", mutable=["counters"])
+        out = model.apply(variables, feats, method="rpn")
+        anchors = level_anchors(cfg.model, feats)
+        (lvl,) = sorted(out)
+        scores = jax.nn.sigmoid(out[lvl][0])
+        return jax.vmap(
+            lambda s, d, hw: _pre_nms_candidates(
+                s, d, anchors[lvl], hw[0], hw[1], rpn.train_pre_nms_top_n,
+                rpn.min_size, rpn.topk_impl, rpn.topk_recall, rpn.topk_block,
+            )
+        )(scores, out[lvl][1], batch.image_hw)
+
+    b, s = jax.device_get(candidates(variables, batch))
+    return jnp.asarray(b, jnp.float32), jnp.asarray(s, jnp.float32)
 
 
 def probe_fused_middle(train):
@@ -440,6 +585,7 @@ PROBES = (
     ("roi_align_bwd[train,b2x512,bf16]", True, probe_roi_align_bwd, (2, 512)),
     ("roi_align_matmul[vgg16_voc07.train_b16]", True,
      probe_roi_align_matmul, (16, 38, 64, 512, 128)),
+    ("nms_tiled[vgg16_voc07.train_b16,seed941]", True, probe_nms_tiled, (941,)),
     ("nms[2000]", False, probe_nms, (2000,)),
     ("fused_middle[train,b2,k2000]", False, probe_fused_middle, (True,)),
     ("fused_middle[eval,b8,k1000]", False, probe_fused_middle, (False,)),

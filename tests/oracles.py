@@ -35,6 +35,19 @@ def iou_matrix_np(boxes: np.ndarray, query: np.ndarray, plus_one: bool = False):
     return out
 
 
+def _iou_row_np(box: np.ndarray, boxes: np.ndarray):
+    """``iou_matrix_np(box[None], boxes)[0]``, a row at a time: the same
+    arithmetic in the same dtype, so the greedy oracle below can reach
+    thousands of boxes."""
+    iw = np.maximum(np.minimum(box[2], boxes[:, 2]) - np.maximum(box[0], boxes[:, 0]), 0.0)
+    ih = np.maximum(np.minimum(box[3], boxes[:, 3]) - np.maximum(box[1], boxes[:, 1]), 0.0)
+    inter = iw * ih
+    a1 = max(box[2] - box[0], 0) * max(box[3] - box[1], 0)
+    a2 = np.maximum(boxes[:, 2] - boxes[:, 0], 0) * np.maximum(boxes[:, 3] - boxes[:, 1], 0)
+    union = a1 + a2 - inter
+    return np.where(union > 0, inter / np.where(union > 0, union, 1.0), 0.0)
+
+
 def greedy_nms_np(boxes: np.ndarray, scores: np.ndarray, iou_thresh: float):
     """Classic greedy NMS (rcnn/processing/nms.py::py_nms semantics, modern
     +0 box convention). Returns kept indices in descending-score order."""
@@ -45,13 +58,76 @@ def greedy_nms_np(boxes: np.ndarray, scores: np.ndarray, iou_thresh: float):
         if suppressed[idx]:
             continue
         keep.append(idx)
-        for jdx in order:
-            if suppressed[jdx] or jdx == idx:
-                continue
-            iou = iou_matrix_np(boxes[idx : idx + 1], boxes[jdx : jdx + 1])[0, 0]
-            if iou > iou_thresh:
-                suppressed[jdx] = True
+        hit = _iou_row_np(boxes[idx], boxes) > iou_thresh
+        hit[idx] = False
+        suppressed |= hit
     return np.array(keep, dtype=np.int64)
+
+
+def nms_mask_dense(boxes, scores, iou_threshold, valid=None, sweep_cap=0):
+    """The dense fixed-point NMS that ``mx_rcnn_tpu/ops/nms.py::nms_mask`` was
+    until PR 29, moved here verbatim as the second oracle: the whole N x N
+    IoU matrix, the whole suppression mask, one global fixed point.  The
+    package keeps the tiled form alone; this one is what it must equal bit
+    for bit."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from mx_rcnn_tpu.geometry import iou_matrix, snap
+
+    n = boxes.shape[0]
+    if valid is None:
+        valid = jnp.isfinite(scores)
+    else:
+        valid = valid & jnp.isfinite(scores)
+
+    order = jnp.argsort(-scores)  # descending; stable for ties
+    sboxes = jnp.take(boxes, order, axis=0)
+    svalid = jnp.take(valid, order)
+
+    # snap(): the > threshold suppression decision must not flip on
+    # cross-compilation ulp noise (see geometry.boxes.snap); one flipped
+    # suppression cascades through the whole greedy chain.
+    iou = snap(iou_matrix(sboxes, sboxes))
+    upper = jnp.triu(jnp.ones((n, n), dtype=bool), k=1)
+    suppress = (iou > iou_threshold) & upper & svalid[:, None] & svalid[None, :]
+
+    if sweep_cap and sweep_cap > 0:
+        # Bounded variant: identical iteration, with a sweep counter in
+        # the carry.  Convergence before the cap gives the exact greedy
+        # fixed point; hitting the cap returns the current iterate.
+        def cond(state):
+            keep, prev, it = state
+            return jnp.any(keep != prev) & (it < sweep_cap)
+
+        def body(state):
+            keep, _, it = state
+            with jax.named_scope("nms_sweep"):
+                new_keep = svalid & ~jnp.any(
+                    suppress & keep[:, None], axis=0
+                )
+            return new_keep, keep, it + 1
+
+        init = (svalid, jnp.zeros(n, dtype=bool), jnp.asarray(0, jnp.int32))
+        keep_sorted, _, _ = lax.while_loop(cond, body, init)
+    else:
+        def cond(state):
+            keep, prev = state
+            return jnp.any(keep != prev)
+
+        def body(state):
+            keep, _ = state
+            with jax.named_scope("nms_sweep"):
+                new_keep = svalid & ~jnp.any(
+                    suppress & keep[:, None], axis=0
+                )
+            return new_keep, keep
+
+        init = (svalid, jnp.zeros(n, dtype=bool))
+        keep_sorted, _ = lax.while_loop(cond, body, init)
+
+    return jnp.zeros(n, dtype=bool).at[order].set(keep_sorted)
 
 
 def encode_np(boxes: np.ndarray, anchors: np.ndarray):

@@ -1,3 +1,5 @@
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -14,10 +16,10 @@ from mx_rcnn_tpu.ops import (
     roi_align_matmul,
     sample_rois,
 )
-from mx_rcnn_tpu.ops.nms import nms_indices
+from mx_rcnn_tpu.ops.nms import TILE, nms_indices, rank_keep
 from mx_rcnn_tpu.ops.roi_align import fpn_level_assignment
 
-from oracles import greedy_nms_np, roi_align_np
+from oracles import greedy_nms_np, nms_mask_dense, roi_align_np
 
 
 def random_boxes(rng, n, size=100.0):
@@ -97,6 +99,161 @@ def test_nms_jit_no_retrace(rng):
     n0 = f._cache_size()
     f(boxes, scores + 0.01).block_until_ready()
     assert f._cache_size() == n0
+
+
+# The tiled NMS (ops/nms.py, PR 29) against both oracles, bit for bit.  Every
+# older NMS test stays under one tile (N <= TILE) and runs the plain fixed
+# point; these cross tile edges.  Boxes sit on whole pixels and the threshold
+# is 0.5, so no IoU lies within 2**-17 of it and the greedy oracle, which
+# compares the unsnapped IoU, decides every pair as the snapped ones do.
+
+NMS_THRESH = 0.5
+
+
+def _grid_boxes(rng, n, canvas=240):
+    xy = rng.randint(0, canvas, (n, 2))
+    wh = rng.randint(4, 31, (n, 2))
+    return np.concatenate([xy, xy + wh], axis=1).astype(np.float32)
+
+
+def _descending(n):
+    return np.linspace(1.0, 0.0, n, dtype=np.float32)
+
+
+def _nms_case(case, rng, n):
+    """-> boxes (n, 4), scores (n,), valid (n,) bool or None."""
+    if case == "random":
+        return _grid_boxes(rng, n), rng.permutation(n).astype(np.float32), None
+    if case == "chain":
+        # Box i overlaps only its neighbours (IoU 2/3 with i +- 1, 3/7 with
+        # i +- 2): whether i survives hangs on i - 1, a chain N deep that
+        # crosses every tile edge.
+        x = 2.0 * np.arange(n, dtype=np.float32)
+        boxes = np.stack([x, np.zeros(n, np.float32), x + 10.0, np.full(n, 10.0, np.float32)], 1)
+        return boxes, _descending(n), None
+    if case == "identical":
+        return np.tile(np.asarray([[3, 5, 40, 33]], np.float32), (n, 1)), _descending(n), None
+    if case == "tile_invalid":
+        # Score order is input order, so the second tile (or what there is
+        # of a second half) is invalid as a whole.
+        valid = np.ones(n, bool)
+        lo = TILE if n >= 2 * TILE else n // 2
+        valid[lo:lo + TILE] = False
+        return _grid_boxes(rng, n, canvas=120), _descending(n), valid
+    if case == "ties_and_inf":
+        scores = rng.randint(0, 7, n).astype(np.float32)
+        scores[rng.rand(n) < 0.1] = -np.inf
+        return _grid_boxes(rng, n, canvas=120), scores, None
+    raise AssertionError(case)
+
+
+def _greedy_mask(boxes, scores, valid=None, thresh=NMS_THRESH):
+    ok = np.isfinite(scores) if valid is None else valid & np.isfinite(scores)
+    idx = np.flatnonzero(ok)
+    want = np.zeros(len(boxes), bool)
+    want[idx[greedy_nms_np(boxes[idx], scores[idx], thresh)]] = True
+    return want
+
+
+def _dense_mask(boxes, scores, valid=None, thresh=NMS_THRESH):
+    v = None if valid is None else jnp.asarray(valid)
+    return np.asarray(nms_mask_dense(jnp.asarray(boxes), jnp.asarray(scores), thresh, v))
+
+
+def _tiled_case(rng, case, n):
+    boxes, scores, valid = _nms_case(case, rng, n)
+    v = None if valid is None else jnp.asarray(valid)
+    got = np.asarray(nms_mask(jnp.asarray(boxes), jnp.asarray(scores), NMS_THRESH, v))
+    np.testing.assert_array_equal(got, _greedy_mask(boxes, scores, valid))
+    # The dense fixed point takes one sweep per link of the chain over the
+    # whole matrix: at 6000 that is the minutes this PR exists to remove.
+    if not (case == "chain" and n > 4 * TILE):
+        np.testing.assert_array_equal(got, _dense_mask(boxes, scores, valid))
+
+
+def _vmap_case(rng, _, n):
+    # Images whose chains differ in depth share every tile's loop under
+    # vmap: the shallow ones must sit still while the deep one converges.
+    cases = [_nms_case(c, rng, n) for c in ("chain", "random", "identical", "ties_and_inf")]
+    boxes = np.stack([b for b, _, _ in cases])
+    scores = np.stack([s for _, s, _ in cases])
+    got = np.asarray(jax.vmap(lambda b, s: nms_mask(b, s, NMS_THRESH))(
+        jnp.asarray(boxes), jnp.asarray(scores)))
+    for i in range(len(cases)):
+        np.testing.assert_array_equal(got[i], _greedy_mask(boxes[i], scores[i]))
+        np.testing.assert_array_equal(got[i], _dense_mask(boxes[i], scores[i]))
+
+
+def _indices_case(rng, max_outputs, n):
+    boxes, scores, _ = _nms_case("ties_and_inf", rng, n)
+    idx, ok = nms_indices(jnp.asarray(boxes), jnp.asarray(scores), NMS_THRESH, max_outputs)
+    want_idx, want_ok = rank_keep(
+        jnp.asarray(_dense_mask(boxes, scores)), jnp.asarray(scores), max_outputs)
+    np.testing.assert_array_equal(np.asarray(idx), np.asarray(want_idx))
+    np.testing.assert_array_equal(np.asarray(ok), np.asarray(want_ok))
+    kept = np.flatnonzero(_greedy_mask(boxes, scores))
+    assert int(np.asarray(ok).sum()) == min(len(kept), max_outputs)
+    assert set(np.asarray(idx)[np.asarray(ok)]) <= set(kept)
+
+
+def _batched_case(rng, _, n):
+    boxes, scores = _grid_boxes(rng, n, canvas=60), rng.permutation(n).astype(np.float32)
+    classes = rng.randint(1, 5, n)
+    got = np.asarray(batched_nms(
+        jnp.asarray(boxes), jnp.asarray(scores), jnp.asarray(classes), NMS_THRESH))
+    want = np.zeros(n, bool)
+    for c in np.unique(classes):
+        idx = np.flatnonzero(classes == c)
+        want[idx[greedy_nms_np(boxes[idx], scores[idx], NMS_THRESH)]] = True
+    np.testing.assert_array_equal(got, want)
+    span = boxes.max() - boxes.min() + 1.0
+    np.testing.assert_array_equal(
+        got, _dense_mask(boxes + classes[:, None].astype(np.float32) * span, scores))
+
+
+_TILED_SIZES = (TILE - 1, TILE, TILE + 1, 3 * TILE + 5, 6000)
+_TILED_CASES = [
+    pytest.param(_tiled_case, case, n, id=f"{case}-{n}")
+    for case in ("random", "chain", "identical", "tile_invalid", "ties_and_inf")
+    for n in _TILED_SIZES
+] + [
+    pytest.param(_vmap_case, "", 3 * TILE + 5, id="vmap-depths-differ"),
+    pytest.param(_indices_case, 2000, 6000, id="nms_indices-6000-2000"),
+    pytest.param(_indices_case, 300, 6000, id="nms_indices-6000-300"),
+    pytest.param(_batched_case, "", 3 * TILE + 5, id="batched_nms"),
+]
+
+
+@pytest.mark.parametrize("check,case,n", _TILED_CASES)
+def test_tiled_nms_equals_both_oracles(rng, check, case, n):
+    check(rng, case, n)
+
+
+def _array_sizes(hlo_text):
+    for dims in re.findall(r"\b(?:pred|[fsu]\d+|bf16)\[([0-9,]+)\]", hlo_text):
+        yield int(np.prod([int(d) for d in dims.split(",")]))
+
+
+def test_no_n_by_n_buffer_under_nms_indices():
+    """The mechanism, held: at N = 6000 the compiled ``nms_indices`` holds no
+    array of N x N elements, in the program or in its temporaries (the dense
+    form's mask alone is N x N bytes): three loops over TILE x TILE blocks;
+    and N <= TILE is one loop, the plain fixed point."""
+    n = 6000
+    args = (jax.ShapeDtypeStruct((n, 4), jnp.float32), jax.ShapeDtypeStruct((n,), jnp.float32))
+    lowered = nms_indices.lower(*args, 0.7, 2000)
+    compiled = lowered.compile()
+    assert max(_array_sizes(compiled.as_text())) < n * n // 4
+    assert compiled.memory_analysis().temp_size_in_bytes < n * n // 4
+    # the tile loop, the row-block loop inside it, and a tile's sweeps
+    assert lowered.as_text().count("stablehlo.while") == 3
+
+    dense = jax.jit(lambda b, s: nms_mask_dense(b, s, 0.7)).lower(*args).compile()
+    assert max(_array_sizes(dense.as_text())) >= n * n  # the test can see one
+
+    small = (jax.ShapeDtypeStruct((TILE, 4), jnp.float32),
+             jax.ShapeDtypeStruct((TILE,), jnp.float32))
+    assert nms_indices.lower(*small, 0.7, 300).as_text().count("stablehlo.while") == 1
 
 
 # ---------------- ROIAlign ----------------
