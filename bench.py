@@ -80,8 +80,6 @@ def resolved_knobs(cfg) -> dict:
         "packed_head": m.rpn.packed_head,
         "roi_align_impl": m.rcnn.roi_align_impl,
         "roi_align_bwd_impl": m.rcnn.roi_align_bwd_impl,
-        "nms_impl": m.rpn.nms_impl,
-        "fused_middle": m.rpn.fused_middle,
         "roi_block": m.rcnn.roi_block,
         "steps_per_call": cfg.train.steps_per_call,
         "accum_steps": cfg.train.accum_steps,

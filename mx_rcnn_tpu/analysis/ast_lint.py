@@ -53,7 +53,7 @@ TPU008 no-interpret     a ``pallas_call(...)`` without an explicit
                         ``_pallas_interpret()`` gate): an implicit
                         default means the kernel silently fails to lower
                         off-TPU, and the CI interpret-mode parity suites
-                        (test_roi_align, test_fused_middle) can't reach
+                        (test_roi_align, test_pallas) can't reach
                         it.
 """
 

@@ -326,10 +326,6 @@ UPCAST_ALLOWLIST = (
     "guardian",
     "optimizer",
     "proposals",
-    # The fused Pallas middle runs decode/clip/NMS in f32 in-kernel (box
-    # coordinates are f32 by contract) — its named scope covers the f32
-    # staging of bf16 scores/deltas into the kernel operand block.
-    "fused_middle",
     "sample_rois",
     "assign_anchors",
     "roi_align",

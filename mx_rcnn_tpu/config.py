@@ -184,14 +184,6 @@ class RPNConfig:
     # masked-out terms are exact zeros), so metrics match to f32
     # round-off, not bitwise — opt-in for A/B.
     loss_impl: str = "dense"
-    # Sweep bound for the proposal NMS (ops/nms.py), which resolves the
-    # candidates tile by tile in score order with a fixed point inside
-    # each tile.  0 = every tile iterates to convergence (exact greedy
-    # NMS, the default).  > 0 bounds EACH TILE's loop to that many
-    # sweeps: any cap >= N is still exact and the best box survives any
-    # cap; a tile of real RPN candidates is 9-17 sweeps deep (PERF.md
-    # section 6, PR 29), so a smaller cap changes which boxes are kept.
-    nms_sweep_cap: int = 0
     # Run the weight-shared head over all FPN levels as ONE packed
     # computation (models/heads.py::RPNHead.packed) instead of five
     # sequential small-spatial convs (the P2 apply alone measured
@@ -201,24 +193,6 @@ class RPNConfig:
     # (parallel/step.py::mesh_safe_model_cfg — the packed canvas would
     # concatenate across height shards).
     packed_head: bool = True
-    # Proposal-NMS backend.  "xla" (default) runs the batched while-loop
-    # fixed point (ops/nms.py::nms_mask — the oracle).  "pallas" routes
-    # the keep-mask through ops/pallas/nms.py::nms_mask_pallas, the
-    # VMEM-resident greedy sweep — bit-identical keep bits (parity suite
-    # tests/test_pallas.py / test_fused_middle.py); falls back to "xla"
-    # off-TPU unless MX_RCNN_PALLAS_INTERPRET=1 forces interpret mode.
-    nms_impl: str = "xla"
-    # Fuse the proposal middle — decode -> clip -> snap -> min-size ->
-    # greedy NMS — into ONE Pallas kernel per proposal call
-    # (ops/pallas/middle.py): the per-level score/box tiles stay in VMEM
-    # across the whole chain instead of round-tripping HBM between
-    # ops/proposals.py, ops/topk.py and ops/nms.py as a string of small
-    # XLA programs.  Bit-identical to the dense path (the kernel
-    # replicates decode_boxes/clip_boxes/snap/iou_matrix to the bit and
-    # greedy NMS in top-k positional order provably equals the
-    # argsort-order oracle — docs/performance.md).  Default-off; same
-    # fallback discipline as nms_impl.
-    fused_middle: bool = False
 
 
 @dataclass(frozen=True)
@@ -305,9 +279,6 @@ class TestConfig:
     # 82.1 -> 94.9 img/s/chip.
     nms_mode: str = "fused"
     fused_top_k: int = 1000
-    # Sweep bound for the postprocess NMS fixed points (same semantics
-    # as RPNConfig.nms_sweep_cap; 0 = exact convergence, the default).
-    nms_sweep_cap: int = 0
 
 
 @dataclass(frozen=True)
@@ -479,7 +450,7 @@ class TrainConfig:
     # under the remaining backward compute instead of serializing after
     # it.  Exact: each leaf rides exactly one pmean either way, so the
     # reduction is bitwise identical to the single fused pmean
-    # (tests/test_fused_middle.py asserts it).  0 (default) keeps the
+    # (tests/test_block_parity.py asserts it).  0 (default) keeps the
     # plain GSPMD step — PR 3's bit-exact resume proofs carry over
     # literally.  On jax 0.9.0 only zero vs non-zero matters: the bucket
     # size itself never reaches the compiler (parallel/step.py).
@@ -652,14 +623,6 @@ class ServeConfig:
     # packable immediately — lowest latency, occupancy rides on queue
     # depth.
     pack_window_s: float = 0.0
-    # Serving-side fused-middle override (detection/graph.py): "inherit"
-    # keeps model.rpn.fused_middle / model.rpn.nms_impl as-is; "on"
-    # forces fused_middle=True + nms_impl="pallas" for every serving
-    # program (full/small/reduced/proposals and the q8 levels); "off"
-    # forces the dense XLA chain.  Same off-TPU fallback and
-    # MX_RCNN_PALLAS_INTERPRET contract as training — off-TPU without
-    # interpret mode the override silently serves the dense chain.
-    fused_middle: str = "inherit"
     # Content-addressed result cache (serve/result_cache.py): max cached
     # responses per router (LRU).  0 (default) disables the cache AND
     # in-flight coalescing — duplicate-heavy serving surfaces opt in

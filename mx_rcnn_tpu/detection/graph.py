@@ -257,42 +257,10 @@ def _rcnn_losses_impl(cls_logits, box_deltas, samples, class_agnostic: bool):
 
 
 def _propose_one(cfg: ModelConfig, train: bool):
-    """Builds the per-image proposal fn over concatenated level outputs.
-
-    ``rpn.fused_middle``/``rpn.nms_impl`` select the detection-middle
-    backend: the fused Pallas kernel (ops/pallas/middle.py — decode ->
-    clip -> snap -> NMS VMEM-resident, bit-identical to the dense chain),
-    the pallas keep-mask sweep under the dense decode, or the all-XLA
-    oracle.  On a TPU a Pallas backend that was asked for is what runs —
-    a kernel Mosaic refuses fails the compile, nothing stands in for it.
-    Off-TPU the Pallas backends need MX_RCNN_PALLAS_INTERPRET=1; without
-    it the XLA chain runs (the design for CPU tests; debug-logged).
-    """
-    global LAST_MIDDLE_IMPL
+    """Builds the per-image proposal fn over concatenated level outputs."""
     rpn_cfg = cfg.rpn
     pre = rpn_cfg.train_pre_nms_top_n if train else rpn_cfg.test_pre_nms_top_n
     post = rpn_cfg.train_post_nms_top_n if train else rpn_cfg.test_post_nms_top_n
-
-    if rpn_cfg.nms_impl not in ("xla", "pallas"):
-        raise ValueError(
-            f"rpn.nms_impl must be 'xla' or 'pallas', got {rpn_cfg.nms_impl!r}"
-        )
-    interpret = _pallas_interpret()
-    can_pallas = jax.default_backend() == "tpu" or interpret
-    if not can_pallas and (
-        rpn_cfg.fused_middle or rpn_cfg.nms_impl == "pallas"
-    ):
-        import logging
-
-        logging.getLogger("mx_rcnn_tpu").debug(
-            "rpn fused_middle/nms_impl='pallas' off-TPU without "
-            "MX_RCNN_PALLAS_INTERPRET=1 — using the XLA detection middle"
-        )
-    fused = rpn_cfg.fused_middle and can_pallas
-    nms_impl = rpn_cfg.nms_impl if can_pallas else "xla"
-    LAST_MIDDLE_IMPL = (
-        "fused" if fused else ("pallas-nms" if nms_impl == "pallas" else "xla")
-    )
 
     def single(level_scores, level_deltas, level_anchor, hw) -> Proposals:
         if len(level_scores) == 1:
@@ -307,9 +275,6 @@ def _propose_one(cfg: ModelConfig, train: bool):
                 nms_threshold=rpn_cfg.nms_threshold, min_size=rpn_cfg.min_size,
                 topk_impl=rpn_cfg.topk_impl, topk_recall=rpn_cfg.topk_recall,
                 topk_block=rpn_cfg.topk_block,
-                nms_sweep_cap=rpn_cfg.nms_sweep_cap,
-                nms_impl=nms_impl, fused_middle=fused,
-                pallas_interpret=interpret,
             )
         return generate_fpn_proposals(
             level_scores, level_deltas, level_anchor, hw[0], hw[1],
@@ -317,9 +282,6 @@ def _propose_one(cfg: ModelConfig, train: bool):
             nms_threshold=rpn_cfg.nms_threshold, min_size=rpn_cfg.min_size,
             topk_impl=rpn_cfg.topk_impl, topk_recall=rpn_cfg.topk_recall,
             topk_block=rpn_cfg.topk_block,
-            nms_sweep_cap=rpn_cfg.nms_sweep_cap,
-            nms_impl=nms_impl, fused_middle=fused,
-            pallas_interpret=interpret,
         )
 
     return single
@@ -344,11 +306,6 @@ def _slice_levels(levels, anchors, score_row, delta_row):
 # jit traces, so tests and the driver dryrun can assert which path a
 # compiled program actually took.
 LAST_POOL_IMPL: Optional[str] = None
-
-# Same record for the detection middle (_propose_one): "fused" (the Pallas
-# fused middle), "pallas-nms" (dense decode + pallas keep-mask sweep), or
-# "xla" (the all-XLA oracle / fallback).
-LAST_MIDDLE_IMPL: Optional[str] = None
 
 
 def _pallas_interpret() -> bool:
@@ -916,8 +873,7 @@ def _postprocess_one(cfg: ModelConfig, rois, roi_valid, probs, deltas, hw):
         top_s, top_i = lax.top_k(sc, per_class_k)
         top_b = jnp.take(boxes, top_i, axis=0)
         keep_i, keep_v = nms_indices(
-            top_b, top_s, cfg.test.nms_threshold, per_class_k,
-            sweep_cap=cfg.test.nms_sweep_cap,
+            top_b, top_s, cfg.test.nms_threshold, per_class_k
         )
         out_b = jnp.take(top_b, keep_i, axis=0)
         out_s = jnp.where(keep_v, jnp.take(top_s, keep_i), -jnp.inf)
@@ -979,8 +935,7 @@ def _postprocess_one_fused(cfg: ModelConfig, rois, roi_valid, probs, deltas, hw)
 
     cand_valid = jnp.isfinite(top_s)
     keep = batched_nms(
-        boxes, top_s, cls, cfg.test.nms_threshold, valid=cand_valid,
-        sweep_cap=cfg.test.nms_sweep_cap,
+        boxes, top_s, cls, cfg.test.nms_threshold, valid=cand_valid
     )
     kept_s = jnp.where(keep, top_s, -jnp.inf)
     out_s, out_i = lax.top_k(kept_s, min(d_out, k))

@@ -58,8 +58,9 @@ it there.  Timed beside them on the same candidates: tiles of 256 and 1024
 (6.5 ms each against 6.1 at 512), one global fixed point over a bit-packed
 mask (9.5), the tile's sweep as an MXU product (5.8).  The sequential
 alternatives lost long before: a 64-box blocked-greedy ``lax.scan`` needs
-~2N/64 tiny steps, the Pallas sweep (``ops/pallas/nms.py``) N of them.  The
-dense form lives on as the oracle in ``tests/oracles.py::nms_mask_dense``.
+~2N/64 tiny steps, a one-box-at-a-time Pallas sweep N of them (9.7 ms
+against 2.3 an image at N = 2000; deleted in PR 30).  The dense form lives
+on as the oracle in ``tests/oracles.py::nms_mask_dense``.
 """
 
 from __future__ import annotations
@@ -102,26 +103,24 @@ def _overlaps(rows: jnp.ndarray, cols: jnp.ndarray, iou_threshold: float):
     return snap(iou) > iou_threshold
 
 
-def _tile_fixed_point(alive, suppress, sweep_cap: int):
+def _tile_fixed_point(alive, suppress):
     """``keep[i] <- alive[i] & ~any_j(keep[j] & suppress[j, i])`` iterated
-    from ``keep = alive`` until it stops changing (or ``sweep_cap`` sweeps)."""
+    from ``keep = alive`` until it stops changing."""
 
     def cond(state):
-        keep, prev, it = state
-        changed = jnp.any(keep != prev)
-        return changed & (it < sweep_cap) if sweep_cap > 0 else changed
+        keep, prev = state
+        return jnp.any(keep != prev)
 
     def body(state):
-        keep, _, it = state
+        keep, _ = state
         # One sweep = one run of this scope's ops: the trace shows a loop
         # body's ops once per iteration, so sweeps are countable per step
         # (perfbench/metrics/nms_sweeps.train.py).
         with jax.named_scope("nms_sweep"):
             new_keep = alive & ~jnp.any(suppress & keep[:, None], axis=0)
-        return new_keep, keep, it + 1
+        return new_keep, keep
 
-    init = (alive, jnp.zeros_like(alive), jnp.asarray(0, jnp.int32))
-    keep, _, _ = lax.while_loop(cond, body, init)
+    keep, _ = lax.while_loop(cond, body, (alive, jnp.zeros_like(alive)))
     return keep
 
 
@@ -130,7 +129,6 @@ def nms_mask(
     scores: jnp.ndarray,
     iou_threshold: float,
     valid: jnp.ndarray | None = None,
-    sweep_cap: int = 0,
 ) -> jnp.ndarray:
     """Greedy NMS as a boolean keep-mask in *input* order.
 
@@ -141,13 +139,6 @@ def nms_mask(
       iou_threshold: suppression threshold (reference default 0.7 for RPN
         proposals, 0.3 at test time).
       valid: optional (N,) bool; invalid entries never keep nor suppress.
-      sweep_cap: 0 (default) iterates every tile's fixed point to
-        convergence — exact greedy NMS.  > 0 bounds EACH TILE's
-        while_loop to that many sweeps: each sweep finalizes at least one
-        undecided box, so any cap >= N is still exact, and the best box
-        survives any cap; a small cap trades exactness on adversarial
-        inputs for a hard latency bound.  Opt-in via
-        ``RPNConfig.nms_sweep_cap``.
 
     Returns:
       (N,) bool keep mask.
@@ -166,7 +157,7 @@ def nms_mask(
     upper = jnp.triu(jnp.ones((tile, tile), dtype=bool), k=1)
     if n <= TILE:  # one tile: the plain fixed point on the whole matrix
         keep_sorted = _tile_fixed_point(
-            svalid, _overlaps(sboxes, sboxes, iou_threshold) & upper, sweep_cap
+            svalid, _overlaps(sboxes, sboxes, iou_threshold) & upper
         )
         return jnp.zeros(n, dtype=bool).at[order].set(keep_sorted)
 
@@ -194,7 +185,7 @@ def nms_mask(
         suppress = lax.optimization_barrier(
             _overlaps(cols, cols, iou_threshold) & upper
         )
-        keep_k = _tile_fixed_point(tvalid[k] & ~dead, suppress, sweep_cap)
+        keep_k = _tile_fixed_point(tvalid[k] & ~dead, suppress)
         return kept.at[k].set(keep_k)
 
     kept = lax.fori_loop(
@@ -206,10 +197,8 @@ def nms_mask(
 def rank_keep(keep: jnp.ndarray, scores: jnp.ndarray, max_outputs: int):
     """Rank a keep mask by score into up to ``max_outputs`` indices.
 
-    The back half of :func:`nms_indices`, shared with the fused middle
-    (``ops/pallas/middle.py`` computes the keep mask in-kernel and hands
-    it here): kept entries first, best score first, padded slots index 0
-    with ``out_valid`` False.
+    The back half of :func:`nms_indices`: kept entries first, best score
+    first, padded slots index 0 with ``out_valid`` False.
     """
     n = keep.shape[0]
     neg = jnp.where(keep, -scores, jnp.inf)
@@ -225,20 +214,13 @@ def rank_keep(keep: jnp.ndarray, scores: jnp.ndarray, max_outputs: int):
     return jnp.where(out_valid, idx, 0), out_valid
 
 
-@partial(
-    jax.jit,
-    static_argnums=(2, 3),
-    static_argnames=("sweep_cap", "nms_impl", "interpret"),
-)
+@partial(jax.jit, static_argnums=(2, 3))
 def nms_indices(
     boxes: jnp.ndarray,
     scores: jnp.ndarray,
     iou_threshold: float,
     max_outputs: int,
     valid: jnp.ndarray | None = None,
-    sweep_cap: int = 0,
-    nms_impl: str = "xla",
-    interpret: bool = False,
 ):
     """NMS returning up to ``max_outputs`` kept indices, score-descending.
 
@@ -246,29 +228,10 @@ def nms_indices(
     Padded slots hold index 0 with ``out_valid`` False — the static-shape
     replacement for the reference Proposal op's pad-with-repeats
     (``rcnn/symbol/proposal.py`` pads rois to RPN_POST_NMS_TOP_N).
-
-    ``nms_impl`` selects the keep-mask backend: ``"xla"`` (default) is the
-    tiled fixed point above; ``"pallas"`` routes through the
-    VMEM-resident greedy sweep (``ops/pallas/nms.py::nms_mask_pallas``,
-    bit-identical keep bits — it snaps IoU on the same 2**-16 grid before
-    the threshold compare).  The pallas sweep is always-exact greedy, so
-    ``sweep_cap`` does not apply to it (the cap exists to bound the XLA
-    fixed points' data-dependent sweep count).  ``interpret`` runs the
-    pallas kernel in interpret mode (CPU CI).
     """
-    if nms_impl == "pallas":
-        from mx_rcnn_tpu.ops.pallas.nms import nms_mask_pallas
-
-        keep = nms_mask_pallas(
-            boxes, scores, iou_threshold, valid, interpret=interpret
-        )
-    elif nms_impl == "xla":
-        keep = nms_mask(
-            boxes, scores, iou_threshold, valid, sweep_cap=sweep_cap
-        )
-    else:
-        raise ValueError(f"nms_impl must be 'xla' or 'pallas', got {nms_impl!r}")
-    return rank_keep(keep, scores, max_outputs)
+    return rank_keep(
+        nms_mask(boxes, scores, iou_threshold, valid), scores, max_outputs
+    )
 
 
 def batched_nms(
@@ -277,7 +240,6 @@ def batched_nms(
     classes: jnp.ndarray,
     iou_threshold: float,
     valid: jnp.ndarray | None = None,
-    sweep_cap: int = 0,
 ) -> jnp.ndarray:
     """Per-class NMS in one shot via the coordinate-offset trick.
 
@@ -288,5 +250,4 @@ def batched_nms(
     """
     span = jnp.max(boxes) - jnp.min(boxes) + 1.0
     offset = classes.astype(boxes.dtype)[:, None] * span
-    return nms_mask(boxes + offset, scores, iou_threshold, valid,
-                    sweep_cap=sweep_cap)
+    return nms_mask(boxes + offset, scores, iou_threshold, valid)

@@ -6,7 +6,6 @@ reference impls in tests).  Kernels run in interpret mode on CPU, so the
 same tests cover both backends.
 """
 
-from mx_rcnn_tpu.ops.pallas.nms import nms_mask_pallas
 from mx_rcnn_tpu.ops.pallas.roi_align import (
     multilevel_roi_align_fast,
     multilevel_roi_align_pallas,
@@ -15,5 +14,4 @@ from mx_rcnn_tpu.ops.pallas.roi_align import (
 __all__ = [
     "multilevel_roi_align_fast",
     "multilevel_roi_align_pallas",
-    "nms_mask_pallas",
 ]

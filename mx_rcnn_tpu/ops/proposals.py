@@ -17,7 +17,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from mx_rcnn_tpu.geometry import clip_boxes, decode_boxes, snap, valid_box_mask
-from mx_rcnn_tpu.ops.nms import nms_indices, rank_keep
+from mx_rcnn_tpu.ops.nms import nms_indices
 from mx_rcnn_tpu.ops.topk import hierarchical_top_k
 
 
@@ -40,10 +40,6 @@ def generate_proposals(
     topk_impl: str = "hier",
     topk_recall: float = 0.95,
     topk_block: int = 32768,
-    nms_sweep_cap: int = 0,
-    nms_impl: str = "xla",
-    fused_middle: bool = False,
-    pallas_interpret: bool = False,
 ) -> Proposals:
     """Single-level proposal generation.
 
@@ -61,65 +57,32 @@ def generate_proposals(
         (bit-identical to ``"exact"``, see ``ops/topk.py``); only the
         strict-subset case (k < A) can go approx; k == A is a plain sort
         either way.
-      nms_sweep_cap: 0 (default) runs the NMS fixed point to convergence
-        (exact); > 0 bounds the sweep count (see ``ops/nms.py``).
-      nms_impl: keep-mask backend for the non-fused path — ``"xla"``
-        (default, the oracle) or ``"pallas"`` (see ``ops/nms.py``).
-      fused_middle: run decode->clip->snap->NMS as ONE Pallas kernel
-        (``ops/pallas/middle.py``), bit-identical to the dense chain.
-        When set, ``nms_impl``/``nms_sweep_cap`` don't apply (the kernel
-        IS the exact greedy NMS).
-      pallas_interpret: run any Pallas kernel in interpret mode (CPU CI).
 
     Returns:
       Fixed-size Proposals; invalid slots carry zeros.
     """
-    if fused_middle:
-        from mx_rcnn_tpu.ops.pallas.middle import fused_middle_levels
-
-        with jax.named_scope("fused_middle"):
-            top_scores, top_deltas, top_anchors = _topk_candidates(
-                scores, deltas, anchors,
-                pre_nms_top_n, topk_impl, topk_recall, topk_block,
-            )
-            bx, msc, keep = fused_middle_levels(
-                top_anchors[None], top_deltas[None], top_scores[None],
-                image_height, image_width,
-                min_size=min_size, iou_threshold=nms_threshold,
-                interpret=pallas_interpret,
-            )
-            boxes, masked_scores = bx[0], msc[0]
-            keep_idx, keep_valid = rank_keep(
-                keep[0], masked_scores, post_nms_top_n
-            )
-    else:
-        boxes, masked_scores = _pre_nms_candidates(
-            scores, deltas, anchors, image_height, image_width,
-            pre_nms_top_n, min_size, topk_impl, topk_recall, topk_block,
+    boxes, masked_scores = _pre_nms_candidates(
+        scores, deltas, anchors, image_height, image_width,
+        pre_nms_top_n, min_size, topk_impl, topk_recall, topk_block,
+    )
+    with jax.named_scope("nms"):
+        keep_idx, keep_valid = nms_indices(
+            boxes, masked_scores, nms_threshold, post_nms_top_n
         )
-        with jax.named_scope("nms"):
-            keep_idx, keep_valid = nms_indices(
-                boxes, masked_scores, nms_threshold, post_nms_top_n,
-                sweep_cap=nms_sweep_cap, nms_impl=nms_impl,
-                interpret=pallas_interpret,
-            )
     rois = jnp.take(boxes, keep_idx, axis=0) * keep_valid[:, None]
     out_scores = jnp.where(keep_valid, jnp.take(masked_scores, keep_idx), 0.0)
     return Proposals(rois=rois, scores=out_scores, valid=keep_valid)
 
 
-def _topk_candidates(
-    scores, deltas, anchors,
-    pre_nms_top_n: int, topk_impl: str, topk_recall: float,
+def _pre_nms_candidates(
+    scores, deltas, anchors, image_height, image_width,
+    pre_nms_top_n: int, min_size: float, topk_impl: str, topk_recall: float,
     topk_block: int = 32768,
 ):
-    """Score snap + pre-NMS top-k + candidate gather.
-
-    The front half shared by the dense chain (:func:`_pre_nms_candidates`)
-    and the fused middle (``ops/pallas/middle.py`` takes over from here).
-    Returns ``(top_scores (k,), deltas (k, 4), anchors (k, 4))`` in
-    score-descending, index-ascending-tie order.
-    """
+    """Shared pre-NMS front half: score snap, top-k by objectness, decode,
+    clip, and min-size masking.  Returns (boxes (k, 4), masked_scores (k,))
+    in score-descending, index-ascending-tie order, with suppressed/invalid
+    candidates at ``-inf`` score."""
     a = scores.shape[0]
     k = min(pre_nms_top_n, a)
     if topk_impl not in ("hier", "exact", "approx"):
@@ -147,25 +110,8 @@ def _topk_candidates(
             )
         else:
             top_scores, top_idx = lax.top_k(scores, k)
-        return (
-            top_scores,
-            jnp.take(deltas, top_idx, axis=0),
-            jnp.take(anchors, top_idx, axis=0),
-        )
-
-
-def _pre_nms_candidates(
-    scores, deltas, anchors, image_height, image_width,
-    pre_nms_top_n: int, min_size: float, topk_impl: str, topk_recall: float,
-    topk_block: int = 32768,
-):
-    """Shared pre-NMS front half: top-k by objectness, decode, clip, and
-    min-size masking.  Returns (boxes (k, 4), masked_scores (k,)) with
-    suppressed/invalid candidates at ``-inf`` score."""
-    top_scores, top_deltas, top_anchors = _topk_candidates(
-        scores, deltas, anchors, pre_nms_top_n, topk_impl, topk_recall,
-        topk_block,
-    )
+        top_deltas = jnp.take(deltas, top_idx, axis=0)
+        top_anchors = jnp.take(anchors, top_idx, axis=0)
     with jax.named_scope("decode"):
         boxes = decode_boxes(top_deltas, top_anchors)
         boxes = clip_boxes(boxes, image_height, image_width)
@@ -195,10 +141,6 @@ def generate_fpn_proposals(
     topk_impl: str = "hier",
     topk_recall: float = 0.95,
     topk_block: int = 32768,
-    nms_sweep_cap: int = 0,
-    nms_impl: str = "xla",
-    fused_middle: bool = False,
-    pallas_interpret: bool = False,
 ) -> Proposals:
     """FPN-style proposals: per-level top-k + NMS, then global top-k by score.
 
@@ -211,92 +153,33 @@ def generate_fpn_proposals(
     bit-for-bit, tested).  L sequential while-loops would pay L
     convergence latencies back-to-back; one batched loop pays the
     worst lane's.  r4 A/B on the train step: see BASELINE.md.
-
-    ``fused_middle`` replaces the decode->clip->snap->NMS chain with one
-    Pallas launch gridded over the level axis (``ops/pallas/middle.py``)
-    — bit-identical outputs, no HBM round-trips between the stages.
-    ``nms_impl`` selects the keep-mask backend on the non-fused path
-    ("pallas" runs one kernel launch per level — vmapping the sequential
-    sweep would serialize anyway).
     """
     # Detectron recipe: each level may keep up to post_nms_top_n proposals;
     # the global top-k over the union then trims to post_nms_top_n total.
     levels = sorted(level_scores.keys())
-    if fused_middle:
-        from mx_rcnn_tpu.ops.pallas.middle import fused_middle_levels
-
-        with jax.named_scope("fused_middle"):
-            cand = [
-                _topk_candidates(
-                    level_scores[lvl], level_deltas[lvl], level_anchors[lvl],
-                    pre_nms_top_n, topk_impl, topk_recall, topk_block,
-                )
-                for lvl in levels
-            ]
-            kmax = max(s.shape[0] for s, _, _ in cand)
-            sc_k = jnp.stack(
-                [
-                    jnp.pad(s, (0, kmax - s.shape[0]),
-                            constant_values=-jnp.inf)
-                    for s, _, _ in cand
-                ]
-            )                                               # (L, kmax)
-            dl_k = jnp.stack(
-                [jnp.pad(d, ((0, kmax - d.shape[0]), (0, 0)))
-                 for _, d, _ in cand]
-            )                                               # (L, kmax, 4)
-            an_k = jnp.stack(
-                [jnp.pad(a, ((0, kmax - a.shape[0]), (0, 0)))
-                 for _, _, a in cand]
-            )                                               # (L, kmax, 4)
-            bx, sc, keep = fused_middle_levels(
-                an_k, dl_k, sc_k, image_height, image_width,
-                min_size=min_size, iou_threshold=nms_threshold,
-                interpret=pallas_interpret,
-            )
-            keep_idx, keep_valid = jax.vmap(
-                lambda k_, s_: rank_keep(k_, s_, post_nms_top_n)
-            )(keep, sc)                                     # (L, post) x2
-    else:
-        cand = [
-            _pre_nms_candidates(
-                level_scores[lvl], level_deltas[lvl], level_anchors[lvl],
-                image_height, image_width,
-                pre_nms_top_n, min_size, topk_impl, topk_recall, topk_block,
-            )
-            for lvl in levels
+    cand = [
+        _pre_nms_candidates(
+            level_scores[lvl], level_deltas[lvl], level_anchors[lvl],
+            image_height, image_width,
+            pre_nms_top_n, min_size, topk_impl, topk_recall, topk_block,
+        )
+        for lvl in levels
+    ]
+    kmax = max(b.shape[0] for b, _ in cand)
+    bx = jnp.stack(
+        [jnp.pad(b, ((0, kmax - b.shape[0]), (0, 0))) for b, _ in cand]
+    )                                                       # (L, kmax, 4)
+    sc = jnp.stack(
+        [
+            jnp.pad(s, (0, kmax - s.shape[0]), constant_values=-jnp.inf)
+            for _, s in cand
         ]
-        kmax = max(b.shape[0] for b, _ in cand)
-        bx = jnp.stack(
-            [jnp.pad(b, ((0, kmax - b.shape[0]), (0, 0))) for b, _ in cand]
-        )                                                   # (L, kmax, 4)
-        sc = jnp.stack(
-            [
-                jnp.pad(s, (0, kmax - s.shape[0]), constant_values=-jnp.inf)
-                for _, s in cand
-            ]
-        )                                                   # (L, kmax)
+    )                                                       # (L, kmax)
 
-        with jax.named_scope("nms"):
-            if nms_impl == "pallas":
-                # One sequential-sweep kernel launch per level; the sweeps
-                # would serialize under vmap regardless.
-                per_level = [
-                    nms_indices(
-                        bx[l], sc[l], nms_threshold, post_nms_top_n,
-                        nms_impl="pallas", interpret=pallas_interpret,
-                    )
-                    for l in range(len(levels))
-                ]
-                keep_idx = jnp.stack([i for i, _ in per_level])
-                keep_valid = jnp.stack([v for _, v in per_level])
-            else:
-                keep_idx, keep_valid = jax.vmap(
-                    lambda b, s: nms_indices(
-                        b, s, nms_threshold, post_nms_top_n,
-                        sweep_cap=nms_sweep_cap,
-                    )
-                )(bx, sc)                                   # (L, post) x2
+    with jax.named_scope("nms"):
+        keep_idx, keep_valid = jax.vmap(
+            lambda b, s: nms_indices(b, s, nms_threshold, post_nms_top_n)
+        )(bx, sc)                                           # (L, post) x2
     rois_l = jnp.take_along_axis(
         bx, keep_idx[..., None], axis=1
     ) * keep_valid[..., None]

@@ -198,15 +198,6 @@ class DetectorRunner:
         output slots (cheaper postprocess/NMS).
       * ``("proposals", smallest bucket)`` — RPN-only, class-agnostic.
 
-    ``cfg.serve.fused_middle`` overrides the detection middle for every
-    serving program: ``"on"`` forces the fused Pallas proposal chain
-    (``rpn.fused_middle=True, nms_impl="pallas"``), ``"off"`` forces the
-    dense XLA chain, ``"inherit"`` keeps ``cfg.model.rpn`` as-is.  The
-    override rides the model config the programs are traced from, so it
-    inherits training's off-TPU fallback and
-    ``MX_RCNN_PALLAS_INTERPRET`` contract unchanged
-    (detection/graph.py::_propose_one).
-
     ``run`` letterboxes each request image into the plan's bucket, pads
     the micro-batch to the static ``batch_size``, executes, and maps
     boxes back to original image coordinates.  Any (mode, bucket) pair
@@ -257,28 +248,7 @@ class DetectorRunner:
         self.reduced_max_detections = int(reduced_max_detections)
         stats = (cfg.data.pixel_mean, cfg.data.pixel_std)
 
-        # Serving-side fused-middle override: trace every program from a
-        # model config whose rpn section reflects cfg.serve.fused_middle.
-        # graph._propose_one reads these at trace time, so the existing
-        # off-TPU fallback / MX_RCNN_PALLAS_INTERPRET contract applies.
         model_cfg = cfg.model
-        fused = getattr(getattr(cfg, "serve", None), "fused_middle",
-                        "inherit")
-        if fused not in ("inherit", "on", "off"):
-            raise ValueError(
-                f"serve.fused_middle must be inherit/on/off, got {fused!r}"
-            )
-        if fused != "inherit":
-            model_cfg = dataclasses.replace(
-                model_cfg,
-                rpn=dataclasses.replace(
-                    model_cfg.rpn,
-                    fused_middle=(fused == "on"),
-                    nms_impl="pallas" if fused == "on" else "xla",
-                ),
-            )
-        self.model_cfg = model_cfg
-
         model = TwoStageDetector(cfg=model_cfg)
         reduced_cfg = dataclasses.replace(
             model_cfg,

@@ -68,11 +68,6 @@ COMPONENT_PATTERNS: tuple[tuple[str, tuple[str, ...]], ...] = (
     # both (proposal pre-NMS candidates, assign_anchors' _select_random),
     # and first-match-wins gives it its own bucket for A/B attribution.
     ("topk-hier", ("topk_hier",)),
-    # Before proposals: the fused Pallas middle (ops/pallas/middle.py,
-    # rpn.fused_middle) is scoped inside the proposal call — first match
-    # wins gives the kernel launch its own bucket so the r06 A/B
-    # (fused vs string-of-XLA-programs) attributes cleanly.
-    ("fused_middle", ("fused_middle",)),
     ("proposals", ("proposals",)),
     ("sampling", ("sample_rois", "assign_anchors")),
     ("preprocess", ("prep_images",)),

@@ -1,6 +1,6 @@
 """On-TPU probe of every ``pallas_call`` site (Mosaic compiles it at recipe
-shapes and it matches its XLA oracle) and of the one-level matmul ROIAlign
-against the gather form.
+shapes and it matches its XLA oracle), of the one-level matmul ROIAlign
+against the gather form, and of the tiled NMS against the dense oracle.
 
 Runs on the REAL chip (one process, jax's default platform — the parent
 test refuses anything but a TPU).  The interpret-mode CPU tests cannot see
@@ -27,16 +27,9 @@ Probes, each printed as one entry of the final ``RESULT {json}`` line:
   cell (seed 941's weights and first batch), with the measured time of
   each (the readings PERF.md quotes), and under two nested ``vmap``s
   (8 images x 5 levels cut from the same candidates).
-- ``nms[2000]`` — ``nms_mask_pallas`` vs ``nms_mask``.
-- ``fused_middle[train|eval]`` — ``generate_fpn_proposals`` with
-  ``fused_middle=True`` vs the dense chain, under ``jax.vmap`` over the
-  batch exactly as ``detection/graph.py::_propose_one`` reaches it.
 
-The ROIAlign probes are the main path and the process exits non-zero if
-one fails.  The other two are default-off options: a failure there is
-recorded with the compiler's message (and reported by the parent test),
-because an option that cannot compile must fail loudly when selected —
-there is no fallback to hide it.
+Every probe is the main path of some preset: a failure is recorded with the
+compiler's message and the process exits non-zero.
 
 Each tolerance is written next to its check with its reason.  Also prints
 two facts about the runtime the benchmark's timing method leans on
@@ -302,40 +295,6 @@ def probe_roi_align_matmul(batch, h, w, channels, n_rois):
     }
 
 
-def probe_nms(n):
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from mx_rcnn_tpu.ops.nms import nms_mask
-    from mx_rcnn_tpu.ops.pallas.nms import nms_mask_pallas
-
-    rng = np.random.default_rng(0)
-    # Clustered boxes so suppression chains exist (iid boxes barely overlap).
-    centers = rng.uniform(100, 700, (n // 20, 2))
-    ctr = centers[rng.integers(0, len(centers), n)] + rng.normal(0, 12, (n, 2))
-    wh = rng.uniform(40, 160, (n, 2))
-    boxes = jnp.asarray(
-        np.concatenate([ctr - wh / 2, ctr + wh / 2], 1), jnp.float32
-    )
-    scores = jnp.asarray(rng.uniform(0, 1, n), jnp.float32)
-    got = np.asarray(jax.device_get(nms_mask_pallas(boxes, scores, 0.7)))
-    want = np.asarray(jax.device_get(jax.jit(
-        lambda b, s: nms_mask(b, s, 0.7)
-    )(boxes, scores)))
-    # Exact: both sides compare the SAME 2^-16-snapped IoU to the threshold
-    # (the snap exists so that an ulp of difference between two compilers'
-    # divides cannot flip a decision), and greedy NMS is deterministic given
-    # the decisions.
-    mismatches = int((got != want).sum())
-    return {
-        "ok": mismatches == 0,
-        "mismatched_keep_bits": mismatches,
-        "kept": int(want.sum()),
-        "n": n,
-    }
-
-
 def probe_nms_tiled(seed):
     """The tiled ``nms_indices`` (ops/nms.py, PR 29) against the dense fixed
     point it replaced (tests/oracles.py) on a real step's candidates, bit
@@ -466,77 +425,6 @@ def real_step_candidates(seed, workload="vgg16_voc07.train_b16"):
     return jnp.asarray(b, jnp.float32), jnp.asarray(s, jnp.float32)
 
 
-def probe_fused_middle(train):
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from mx_rcnn_tpu.config import get_config
-    from mx_rcnn_tpu.detection.graph import _cached_level_anchor
-    from mx_rcnn_tpu.ops.proposals import generate_fpn_proposals
-
-    cfg = get_config("r50_fpn_coco").model
-    rpn = cfg.rpn
-    pre = rpn.train_pre_nms_top_n if train else rpn.test_pre_nms_top_n
-    post = rpn.train_post_nms_top_n if train else rpn.test_post_nms_top_n
-    batch = 2 if train else 8
-    h, w = CANVAS
-    rng = np.random.default_rng(0)
-    anchors, scores, deltas = {}, {}, {}
-    for lvl in (2, 3, 4, 5, 6):
-        s = 2 ** lvl
-        fh, fw = -(-h // s), -(-w // s)
-        a = _cached_level_anchor(
-            s, tuple(cfg.anchors.ratios), tuple(cfg.anchors.scales), fh, fw
-        )
-        anchors[lvl] = jnp.asarray(a)
-        n = a.shape[0]
-        scores[lvl] = jnp.asarray(rng.uniform(0, 1, (batch, n)), jnp.float32)
-        deltas[lvl] = jnp.asarray(
-            rng.normal(0, 0.3, (batch, n, 4)), jnp.float32
-        )
-    hw = jnp.asarray([[float(h), float(w)]] * batch, jnp.float32)
-
-    def run(fused):
-        def one(sc, dl, hw_row):
-            return generate_fpn_proposals(
-                sc, dl, anchors, hw_row[0], hw_row[1],
-                pre_nms_top_n=pre, post_nms_top_n=post,
-                nms_threshold=rpn.nms_threshold, min_size=rpn.min_size,
-                fused_middle=fused,
-            )
-
-        return jax.device_get(jax.jit(jax.vmap(one))(scores, deltas, hw))
-
-    got, want = run(True), run(False)
-    # Exact: the kernel replicates decode/clip to the operation and both
-    # sides snap coordinates to 1/256 px and IoUs to 2^-16 before any
-    # discrete decision — the grids exist to absorb an ulp of difference
-    # between two compilers' exp/divide.  A row that differs is a
-    # candidate whose coordinate sat within an ulp of a grid boundary; it
-    # is counted, and any at all fails the probe.
-    rows = int(np.prod(want.valid.shape))
-    bad_rows = int(
-        (np.abs(np.asarray(got.rois) - np.asarray(want.rois)).max(-1) > 0)
-        .sum()
-    )
-    bad_valid = int((np.asarray(got.valid) != np.asarray(want.valid)).sum())
-    bad_scores = int(
-        (np.asarray(got.scores) != np.asarray(want.scores)).sum()
-    )
-    return {
-        "ok": bad_rows == 0 and bad_valid == 0 and bad_scores == 0,
-        "rows": rows,
-        "rows_with_different_rois": bad_rows,
-        "rows_with_different_valid": bad_valid,
-        "rows_with_different_scores": bad_scores,
-        "max_abs_roi_diff": float(
-            np.abs(np.asarray(got.rois) - np.asarray(want.rois)).max()
-        ),
-        "valid_rows": int(np.asarray(want.valid).sum()),
-    }
-
-
 def runtime_facts():
     """Set-up facts about the runtime, not speeds of the detector."""
     import jax
@@ -575,20 +463,17 @@ def runtime_facts():
 
 
 PROBES = (
-    # (name, main_path, fn, args)
-    ("roi_align_fwd[train,b2x512,bf16]", True,
+    # (name, fn, args)
+    ("roi_align_fwd[train,b2x512,bf16]",
      probe_roi_align_fwd, (2, 512, "bfloat16")),
-    ("roi_align_fwd[eval,b8x1000,bf16]", True,
+    ("roi_align_fwd[eval,b8x1000,bf16]",
      probe_roi_align_fwd, (8, 1000, "bfloat16")),
-    ("roi_align_fwd[b2x512,f32]", True,
+    ("roi_align_fwd[b2x512,f32]",
      probe_roi_align_fwd, (2, 512, "float32")),
-    ("roi_align_bwd[train,b2x512,bf16]", True, probe_roi_align_bwd, (2, 512)),
-    ("roi_align_matmul[vgg16_voc07.train_b16]", True,
+    ("roi_align_bwd[train,b2x512,bf16]", probe_roi_align_bwd, (2, 512)),
+    ("roi_align_matmul[vgg16_voc07.train_b16]",
      probe_roi_align_matmul, (16, 38, 64, 512, 128)),
-    ("nms_tiled[vgg16_voc07.train_b16,seed941]", True, probe_nms_tiled, (941,)),
-    ("nms[2000]", False, probe_nms, (2000,)),
-    ("fused_middle[train,b2,k2000]", False, probe_fused_middle, (True,)),
-    ("fused_middle[eval,b8,k1000]", False, probe_fused_middle, (False,)),
+    ("nms_tiled[vgg16_voc07.train_b16,seed941]", probe_nms_tiled, (941,)),
 )
 
 
@@ -599,8 +484,8 @@ def main() -> int:
     out = {**device_record(), **runtime_versions(), "probes": {}}
     print("DEVICE " + json.dumps(out), flush=True)
     configure_cache()
-    main_path_ok = True
-    for name, main_path, fn, args in PROBES:
+    all_ok = True
+    for name, fn, args in PROBES:
         t0 = time.perf_counter()
         try:
             res = fn(*args)
@@ -610,15 +495,13 @@ def main() -> int:
                 "ok": False,
                 "error": f"{type(e).__name__}: {e}"[:3000],
             }
-        res["main_path"] = main_path
         res["wall_s"] = round(time.perf_counter() - t0, 1)
         out["probes"][name] = res
         print(f"PROBE {name} " + json.dumps(res), flush=True)
-        if main_path and not res["ok"]:
-            main_path_ok = False
+        all_ok = all_ok and res["ok"]
     out["runtime"] = runtime_facts()
     print("RESULT " + json.dumps(out), flush=True)
-    return 0 if main_path_ok else 1
+    return 0 if all_ok else 1
 
 
 if __name__ == "__main__":

@@ -64,7 +64,7 @@ def greedy_nms_np(boxes: np.ndarray, scores: np.ndarray, iou_thresh: float):
     return np.array(keep, dtype=np.int64)
 
 
-def nms_mask_dense(boxes, scores, iou_threshold, valid=None, sweep_cap=0):
+def nms_mask_dense(boxes, scores, iou_threshold, valid=None):
     """The dense fixed-point NMS that ``mx_rcnn_tpu/ops/nms.py::nms_mask`` was
     until PR 29, moved here verbatim as the second oracle: the whole N x N
     IoU matrix, the whole suppression mask, one global fixed point.  The
@@ -93,39 +93,18 @@ def nms_mask_dense(boxes, scores, iou_threshold, valid=None, sweep_cap=0):
     upper = jnp.triu(jnp.ones((n, n), dtype=bool), k=1)
     suppress = (iou > iou_threshold) & upper & svalid[:, None] & svalid[None, :]
 
-    if sweep_cap and sweep_cap > 0:
-        # Bounded variant: identical iteration, with a sweep counter in
-        # the carry.  Convergence before the cap gives the exact greedy
-        # fixed point; hitting the cap returns the current iterate.
-        def cond(state):
-            keep, prev, it = state
-            return jnp.any(keep != prev) & (it < sweep_cap)
+    def cond(state):
+        keep, prev = state
+        return jnp.any(keep != prev)
 
-        def body(state):
-            keep, _, it = state
-            with jax.named_scope("nms_sweep"):
-                new_keep = svalid & ~jnp.any(
-                    suppress & keep[:, None], axis=0
-                )
-            return new_keep, keep, it + 1
+    def body(state):
+        keep, _ = state
+        with jax.named_scope("nms_sweep"):
+            new_keep = svalid & ~jnp.any(suppress & keep[:, None], axis=0)
+        return new_keep, keep
 
-        init = (svalid, jnp.zeros(n, dtype=bool), jnp.asarray(0, jnp.int32))
-        keep_sorted, _, _ = lax.while_loop(cond, body, init)
-    else:
-        def cond(state):
-            keep, prev = state
-            return jnp.any(keep != prev)
-
-        def body(state):
-            keep, _ = state
-            with jax.named_scope("nms_sweep"):
-                new_keep = svalid & ~jnp.any(
-                    suppress & keep[:, None], axis=0
-                )
-            return new_keep, keep
-
-        init = (svalid, jnp.zeros(n, dtype=bool))
-        keep_sorted, _ = lax.while_loop(cond, body, init)
+    init = (svalid, jnp.zeros(n, dtype=bool))
+    keep_sorted, _ = lax.while_loop(cond, body, init)
 
     return jnp.zeros(n, dtype=bool).at[order].set(keep_sorted)
 

@@ -1,13 +1,10 @@
-"""Exactness proofs for the PR-13 perf paths: the fused Pallas proposal
-middle, the pallas NMS knob, the blocked ROI sampling stats, and the
-bucketed/overlapped gradient all-reduce.
+"""Exactness proofs for two PR-13 perf paths: the blocked ROI sampling
+stats and the bucketed/overlapped gradient all-reduce.
 
-Same discipline as test_detection_middle.py: every new fast path is a
+Same discipline as test_detection_middle.py: every fast path is a
 layout/schedule rewrite of exact math and must be BIT-identical to the
-dense oracle it replaces, on adversarial inputs — snapped-score ties,
--inf masked lanes, zero-valid images, and sweep-capped NMS.  The kernel
-tests run in Pallas interpret mode (CPU CI); the collective tests run on
-the 8-device fake mesh the suite always has (conftest.py).
+dense form it replaces.  The collective tests run on the 8-device fake
+mesh the suite always has (conftest.py).
 
 The one tolerance in this file is deliberate: the overlapped step's
 ``loss`` METRIC is a pmean of per-shard means where GSPMD sums globally
@@ -28,9 +25,6 @@ from jax.sharding import PartitionSpec as P
 
 from mx_rcnn_tpu.config import get_config
 from mx_rcnn_tpu.detection import Batch, TwoStageDetector
-from mx_rcnn_tpu.geometry import snap
-from mx_rcnn_tpu.ops.nms import nms_indices
-from mx_rcnn_tpu.ops.proposals import generate_fpn_proposals, generate_proposals
 from mx_rcnn_tpu.ops.sampling import RoiSamples, sample_rois
 from mx_rcnn_tpu.parallel import (
     ExecutionPlan,
@@ -67,115 +61,6 @@ def _random_anchors(rng, n, canvas=800):
     lo = np.minimum(a[:, :2], a[:, 2:])
     hi = np.maximum(a[:, :2], a[:, 2:]) + 1.0
     return jnp.asarray(np.concatenate([lo, hi], axis=1))
-
-
-def _tied_scores(rng, n):
-    # Heavy snapped ties + -inf masked lanes: the adversarial score
-    # texture the positional-order == argsort-order proof must survive
-    # (ops/pallas/middle.py docstring).
-    s = snap(jnp.asarray(rng.rand(n), jnp.float32))
-    s = jnp.round(s * 16) / 16
-    return s.at[::5].set(-jnp.inf)
-
-
-def _fpn_inputs(rng):
-    level_scores, level_deltas, level_anchors = {}, {}, {}
-    for lvl, n in ((2, 3000), (3, 800), (4, 200), (5, 60)):
-        level_scores[lvl] = _tied_scores(rng, n)
-        level_deltas[lvl] = jnp.asarray(rng.randn(n, 4) * 0.1, jnp.float32)
-        level_anchors[lvl] = _random_anchors(rng, n, canvas=700)
-    return level_scores, level_deltas, level_anchors
-
-
-# ---------------------------------------------------------------------------
-# Fused Pallas middle == dense decode/clip/NMS chain, bit for bit
-
-
-class TestFusedMiddleParity:
-    # pre_nms 256 keeps the interpret-mode NMS loop inside the tier-1
-    # time budget (the kernel's fori_loop emulates N x N-lane steps on
-    # CPU); the adversarial texture (ties, -inf lanes) is k-independent.
-    KW = dict(image_height=800.0, image_width=800.0, pre_nms_top_n=256,
-              post_nms_top_n=128, nms_threshold=0.7)
-
-    @pytest.mark.slow  # CI perf_smoke runs the full file in interpret mode
-    def test_single_level_fused_equals_dense(self, rng):
-        a = 4_000
-        scores = _tied_scores(rng, a)
-        deltas = jnp.asarray(rng.randn(a, 4) * 0.1, jnp.float32)
-        anchors = _random_anchors(rng, a, canvas=700)
-        r_f = generate_proposals(scores, deltas, anchors, **self.KW,
-                                 fused_middle=True, pallas_interpret=True)
-        r_d = generate_proposals(scores, deltas, anchors, **self.KW)
-        for x, y in zip(r_f, r_d):
-            _assert_bitwise(x, y)
-
-    def test_fpn_fused_equals_dense(self, rng):
-        scores, deltas, anchors = _fpn_inputs(rng)
-        r_f = generate_fpn_proposals(scores, deltas, anchors, **self.KW,
-                                     fused_middle=True, pallas_interpret=True)
-        r_d = generate_fpn_proposals(scores, deltas, anchors, **self.KW)
-        for x, y in zip(r_f, r_d):
-            _assert_bitwise(x, y)
-
-    def test_fpn_fused_with_min_size(self, rng):
-        scores, deltas, anchors = _fpn_inputs(rng)
-        kw = dict(self.KW, min_size=16.0)
-        r_f = generate_fpn_proposals(scores, deltas, anchors, **kw,
-                                     fused_middle=True, pallas_interpret=True)
-        r_d = generate_fpn_proposals(scores, deltas, anchors, **kw)
-        for x, y in zip(r_f, r_d):
-            _assert_bitwise(x, y)
-
-    def test_zero_valid_image(self, rng):
-        # A degenerate image extent clips every box to zero width/height:
-        # valid_box_mask rejects all lanes, every score masks to -inf, and
-        # both paths must agree that nothing survives.
-        scores, deltas, anchors = _fpn_inputs(rng)
-        kw = dict(self.KW, image_height=0.0, image_width=0.0)
-        r_f = generate_fpn_proposals(scores, deltas, anchors, **kw,
-                                     fused_middle=True, pallas_interpret=True)
-        r_d = generate_fpn_proposals(scores, deltas, anchors, **kw)
-        for x, y in zip(r_f, r_d):
-            _assert_bitwise(x, y)
-        assert not bool(jnp.any(r_f[2]))  # no valid rois either way
-
-    def test_sweep_cap_exactness_carries_over(self, rng):
-        # The kernel's greedy loop is always exact (N iterations); the
-        # dense path with sweep_cap >= N reaches the same fixed point —
-        # so fused must equal capped-dense bit for bit too (the PR-5
-        # sweep-cap guarantee composing with the fused path).
-        scores, deltas, anchors = _fpn_inputs(rng)
-        r_f = generate_fpn_proposals(scores, deltas, anchors, **self.KW,
-                                     fused_middle=True, pallas_interpret=True)
-        r_c = generate_fpn_proposals(scores, deltas, anchors, **self.KW,
-                                     nms_sweep_cap=257)
-        for x, y in zip(r_f, r_c):
-            _assert_bitwise(x, y)
-
-    def test_pallas_nms_impl_equals_xla(self, rng):
-        scores, deltas, anchors = _fpn_inputs(rng)
-        r_p = generate_fpn_proposals(scores, deltas, anchors, **self.KW,
-                                     nms_impl="pallas", pallas_interpret=True)
-        r_x = generate_fpn_proposals(scores, deltas, anchors, **self.KW)
-        for x, y in zip(r_p, r_x):
-            _assert_bitwise(x, y)
-
-    def test_nms_indices_pallas_equals_xla(self, rng):
-        n = 300
-        boxes = _random_anchors(rng, n, canvas=600)
-        scores = _tied_scores(rng, n)
-        i_x = nms_indices(boxes, scores, 0.5, 64)
-        i_p = nms_indices(boxes, scores, 0.5, 64, nms_impl="pallas",
-                          interpret=True)
-        for x, y in zip(i_x, i_p):
-            _assert_bitwise(x, y)
-
-    def test_bad_nms_impl_raises(self, rng):
-        n = 64
-        with pytest.raises(ValueError, match="nms_impl"):
-            nms_indices(_random_anchors(rng, n), jnp.zeros(n), 0.5, 8,
-                        nms_impl="wrong")
 
 
 # ---------------------------------------------------------------------------

@@ -51,6 +51,19 @@ class TestOverrides:
         with pytest.raises(ValueError):
             apply_overrides(cfg, ["model.rpn=1"])
 
+    @pytest.mark.parametrize("assignment", [
+        "model.rpn.nms_impl=pallas",
+        "model.rpn.fused_middle=true",
+        "model.rpn.nms_sweep_cap=8",
+        "model.test.nms_sweep_cap=8",
+        "serve.fused_middle=on",
+    ])
+    def test_deleted_middle_options_are_refused(self, assignment):
+        # PR 30 deleted the Pallas proposal paths and the sweep caps with
+        # their options: an old command line fails, it is not ignored.
+        with pytest.raises(AttributeError):
+            apply_overrides(get_config("tiny_synthetic"), [assignment])
+
 
 def _tiny(workdir, steps=3):
     cfg = get_config("tiny_synthetic", workdir=str(workdir))

@@ -6,8 +6,8 @@ path must be BIT-identical to the straightforward global implementation
 it replaces (the ``"exact"`` / ``assign_block=0`` / ``"dense"`` oracles
 kept alongside).  These tests pin that contract on the adversarial
 inputs: snapped-score ties, -inf masked lanes, non-dividing block sizes,
-zero-gt and all-ignore degeneracies, and the sweep-capped NMS's
-cap >= N exactness guarantee.
+zero-gt and all-ignore degeneracies.  The proposal chain as a whole is held
+to the one built from the dense oracle (``tests/oracles.py``).
 """
 
 from __future__ import annotations
@@ -18,11 +18,11 @@ import numpy as np
 import pytest
 from jax import lax
 
-from mx_rcnn_tpu.geometry import snap
+from mx_rcnn_tpu.geometry import clip_boxes, decode_boxes, snap, valid_box_mask
 from mx_rcnn_tpu.ops import assign_anchors, hierarchical_top_k
-from mx_rcnn_tpu.ops.nms import nms_indices, nms_mask
 from mx_rcnn_tpu.ops.proposals import generate_fpn_proposals, generate_proposals
 from mx_rcnn_tpu.ops.sampling import AnchorTargets, _select_random
+from oracles import nms_mask_dense
 
 
 def _assert_bitwise(a, b, msg=""):
@@ -182,7 +182,35 @@ class TestBlockedAssignment:
 
 
 # ---------------------------------------------------------------------------
-# proposals: hier == exact end-to-end; sweep cap >= N exact
+# proposals: hier == exact end-to-end; the FPN chain == the oracle's chain
+
+
+def _oracle_fpn_chain(level_scores, level_deltas, level_anchors, image_height,
+                      image_width, pre_nms_top_n, post_nms_top_n,
+                      nms_threshold, min_size):
+    """``generate_fpn_proposals`` the plain way: a level at a time, the
+    global ``lax.top_k``, the dense oracle's keep mask, and the ranking and
+    the final top-k in numpy (stable sorts: the lower index wins a tie)."""
+    rois, scores = [], []
+    for lvl in sorted(level_scores):
+        top_s, top_i = lax.top_k(snap(level_scores[lvl]),
+                                 min(pre_nms_top_n, level_scores[lvl].shape[0]))
+        boxes = decode_boxes(level_deltas[lvl][top_i], level_anchors[lvl][top_i])
+        boxes = snap(clip_boxes(boxes, image_height, image_width), bits=8)
+        masked = jnp.where(valid_box_mask(boxes, min_size=min_size), top_s, -jnp.inf)
+        keep = np.asarray(nms_mask_dense(boxes, masked, nms_threshold))
+        boxes, masked = np.asarray(boxes), np.asarray(masked)
+        order = np.argsort(np.where(keep, -masked, np.inf), kind="stable")
+        order = order[:min(int(keep.sum()), post_nms_top_n)]
+        rois.append(boxes[order])
+        scores.append(masked[order])
+    rois, scores = np.concatenate(rois), np.concatenate(scores)
+    best = np.argsort(-scores, kind="stable")[:post_nms_top_n]
+    n = len(best)
+    out_rois = np.zeros((post_nms_top_n, 4), np.float32)
+    out_scores = np.zeros(post_nms_top_n, np.float32)
+    out_rois[:n], out_scores[:n] = rois[best], scores[best]
+    return out_rois, out_scores, np.arange(post_nms_top_n) < n
 
 
 class TestProposalParity:
@@ -201,7 +229,7 @@ class TestProposalParity:
         for x, y in zip(r_h, r_e):
             _assert_bitwise(x, y)
 
-    def test_fpn_hier_equals_exact_and_cap_exact(self, rng):
+    def test_fpn_hier_equals_exact(self, rng):
         level_scores, level_deltas, level_anchors = {}, {}, {}
         for lvl, n in ((2, 6000), (3, 1500), (4, 400), (5, 100)):
             level_scores[lvl] = snap(jnp.asarray(rng.rand(n), jnp.float32))
@@ -217,13 +245,35 @@ class TestProposalParity:
                                      level_anchors, **kw, topk_impl="exact")
         for x, y in zip(r_h, r_e):
             _assert_bitwise(x, y)
-        # Sweep cap >= N: each sweep finalizes >= 1 box, so the capped
-        # while_loop reaches the same fixed point — bit-identical.
-        r_c = generate_fpn_proposals(level_scores, level_deltas,
-                                     level_anchors, **kw, topk_impl="hier",
-                                     topk_block=1024, nms_sweep_cap=1001)
-        for x, y in zip(r_h, r_c):
-            _assert_bitwise(x, y)
+
+    @pytest.mark.parametrize("case, overrides", [
+        ("tied_padded", {}),
+        ("min_size", {"min_size": 16.0}),
+        ("zero_valid", {"image_height": 0.0, "image_width": 0.0}),
+    ])
+    def test_fpn_chain_equals_dense_oracle_chain(self, rng, case, overrides):
+        # Five levels, three shorter than pre_nms_top_n (padded to the
+        # widest with -inf), heavy snapped ties and -inf masked lanes.
+        # "min_size" masks candidates after the decode; "zero_valid" clips
+        # every box to nothing, so no candidate is valid anywhere.
+        kw = dict(image_height=800.0, image_width=800.0, pre_nms_top_n=256,
+                  post_nms_top_n=128, nms_threshold=0.7, min_size=0.0)
+        kw.update(overrides)
+        level_scores, level_deltas, level_anchors = {}, {}, {}
+        for lvl, n in ((2, 3000), (3, 800), (4, 200), (5, 60), (6, 15)):
+            sc = jnp.round(snap(jnp.asarray(rng.rand(n), jnp.float32)) * 16) / 16
+            level_scores[lvl] = sc.at[::5].set(-jnp.inf)
+            level_deltas[lvl] = jnp.asarray(rng.randn(n, 4) * 0.1, jnp.float32)
+            level_anchors[lvl] = _random_anchors(rng, n, canvas=700)
+        got = generate_fpn_proposals(level_scores, level_deltas,
+                                     level_anchors, **kw)
+        want = _oracle_fpn_chain(level_scores, level_deltas, level_anchors, **kw)
+        for x, y, name in zip(got, want, got._fields):
+            _assert_bitwise(x, y, f"{case}: {name}")
+        if case == "zero_valid":
+            assert not bool(jnp.any(got.valid))
+        else:
+            assert 0 < int(jnp.sum(got.valid))
 
     def test_bad_topk_impl_raises(self, rng):
         a = 500
@@ -233,30 +283,6 @@ class TestProposalParity:
                 image_height=800.0, image_width=800.0,
                 pre_nms_top_n=100, post_nms_top_n=50, topk_impl="wrong",
             )
-
-
-class TestSweepCap:
-    def test_cap_at_least_n_is_exact(self, rng):
-        n = 200
-        boxes = _random_anchors(rng, n, canvas=600)
-        scores = jnp.asarray(rng.rand(n), jnp.float32)
-        m0 = nms_mask(boxes, scores, 0.5)
-        mc = nms_mask(boxes, scores, 0.5, sweep_cap=n)
-        _assert_bitwise(m0, mc)
-        i0 = nms_indices(boxes, scores, 0.5, 50)
-        ic = nms_indices(boxes, scores, 0.5, 50, sweep_cap=n)
-        for x, y in zip(i0, ic):
-            _assert_bitwise(x, y)
-
-    def test_small_cap_still_valid_mask(self, rng):
-        n = 100
-        boxes = _random_anchors(rng, n, canvas=400)
-        scores = jnp.asarray(rng.rand(n), jnp.float32)
-        m = nms_mask(boxes, scores, 0.5, sweep_cap=1)
-        assert m.shape == (n,) and m.dtype == bool
-        # The global top-scoring box has no higher-scored suppressor, so it
-        # survives ANY number of sweeps — capped or not.
-        assert bool(m[jnp.argmax(scores)])
 
 
 # ---------------------------------------------------------------------------

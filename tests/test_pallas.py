@@ -351,25 +351,6 @@ class TestPallasRoiAlign:
             )
 
 
-class TestPallasNms:
-    def test_matches_xla_nms(self, rng):
-        from mx_rcnn_tpu.ops.nms import nms_mask
-        from mx_rcnn_tpu.ops.pallas.nms import nms_mask_pallas
-
-        for n in (7, 64, 200, 513):
-            ctr = rng.rand(n, 2) * 300
-            wh = rng.rand(n, 2) * 80 + 2
-            boxes = jnp.asarray(np.concatenate([ctr - wh / 2, ctr + wh / 2], 1),
-                                jnp.float32)
-            scores = jnp.asarray(rng.rand(n), jnp.float32)
-            valid = jnp.asarray(rng.rand(n) > 0.2)
-            ref = np.asarray(nms_mask(boxes, scores, 0.5, valid))
-            out = np.asarray(
-                nms_mask_pallas(boxes, scores, 0.5, valid, interpret=True)
-            )
-            np.testing.assert_array_equal(out, ref)
-
-
 class TestNoHiddenFallback:
     """detection/graph.py::_pool_rois: a Pallas request that cannot be
     honoured RAISES when the backend is a TPU, and off-TPU the XLA gather

@@ -480,59 +480,6 @@ class TestFullNetworkQ8:
 
 
 # ---------------------------------------------------------------------------
-# fused inference middle through the serving programs (r16 tentpole)
-# ---------------------------------------------------------------------------
-
-
-class TestFusedServingMiddle:
-    @pytest.mark.slow
-    def test_fused_middle_bitwise_parity_per_program(
-        self, tiny_detector, monkeypatch
-    ):
-        # serve.fused_middle=on rewrites the model config EVERY serving
-        # program traces from; the fused Pallas middle is bit-identical
-        # to the dense chain, so each program's response must match the
-        # fused_middle=off build bitwise.  Interpret mode runs the real
-        # kernel on CPU (same contract as training).
-        from mx_rcnn_tpu.detection import graph as graph_mod
-        from mx_rcnn_tpu.serve.engine import DetectorRunner
-
-        monkeypatch.setenv("MX_RCNN_PALLAS_INTERPRET", "1")
-        cfg, _model, variables = tiny_detector
-
-        def build(mode):
-            c = apply_overrides(cfg, [f"serve.fused_middle={mode}"])
-            r = DetectorRunner(
-                c, variables, batch_size=1, with_proposals=False
-            )
-            r.warmup()
-            return r
-
-        off = build("off")
-        assert graph_mod.LAST_MIDDLE_IMPL == "xla"
-        on = build("on")
-        assert graph_mod.LAST_MIDDLE_IMPL == "fused"
-        img = np.random.RandomState(13).randint(
-            0, 255, (96, 128, 3), np.uint8
-        ).astype(np.float32)
-        for level in ("full", "reduced"):
-            a = on.run(level, on.buckets[0], [img])[0]
-            b = off.run(level, off.buckets[0], [img])[0]
-            for k in ("boxes", "scores", "classes"):
-                np.testing.assert_array_equal(
-                    np.asarray(a[k]), np.asarray(b[k]), err_msg=(level, k)
-                )
-
-    def test_fused_middle_knob_validates(self, tiny_detector):
-        from mx_rcnn_tpu.serve.engine import DetectorRunner
-
-        cfg, _model, variables = tiny_detector
-        bad = apply_overrides(cfg, ["serve.fused_middle=maybe"])
-        with pytest.raises(ValueError, match="fused_middle"):
-            DetectorRunner(bad, variables, batch_size=1)
-
-
-# ---------------------------------------------------------------------------
 # content-addressed result cache (r16 tentpole)
 # ---------------------------------------------------------------------------
 
