@@ -1,5 +1,7 @@
 """Kimi Delta Attention (KDA): the gated delta rule with a per-channel decay,
-as a chunked scan whose work is matmuls, in plain XLA.
+as a chunked scan whose work is matmuls: the scan over chunks in plain XLA,
+what happens inside a chunk as a Pallas kernel pair on the TPU
+(``ops/pallas/kda.py``) and in plain XLA everywhere else.
 
 Per head, with state ``S`` (Dk, Dv), ``alpha_t = exp(g_t)`` per key channel:
 
@@ -14,8 +16,9 @@ Per head, with state ``S`` (Dk, Dv), ``alpha_t = exp(g_t)`` per key channel:
     P[t,s] =        (q_t e^{G_t}) . (k_s e^{-G_s})   (s <= t)
     T = (I + A)^{-1},  W = T (beta k e^{G}),  U0 = T (beta v)
 
-are computed for every chunk at once, and a ``lax.scan`` over the chunks
-carries the state through three matmuls a chunk:
+are computed for every chunk at once (on the TPU with a chunk resident in VMEM:
+nothing of it but its inputs and these results crosses HBM), and a ``lax.scan``
+over the chunks carries the state through three matmuls a chunk:
 
     U = U0 - W S,   O = (q e^{G}) S + P U,   S' = e^{G_C} S + (k e^{G_C - G})^T U.
 
@@ -32,7 +35,9 @@ the triangular inverse by blocked forward substitution, W, U0) is float32 at
 ``highest``: it is a hundredth of the layer's FLOPs, and I + A is badly
 conditioned where keys look alike, so a bfloat16 A would be amplified.  The
 scan over chunks, where the work is, takes ``dtype`` operands; sums, decays
-and the state stay float32.  The backward is autodiff through the scan.
+and the state stay float32.  The backward is autodiff through the scan over
+chunks; the chunk-local part's is a hand-written kernel on the TPU and
+autodiff of the XLA form elsewhere, each recomputing the chunk from its inputs.
 """
 
 from __future__ import annotations
@@ -40,6 +45,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from mx_rcnn_tpu.ops.pallas import kda as kda_kernel
 
 HI = lax.Precision.HIGHEST
 # Positions per chunk of the scan: a program choice that follows from the
@@ -136,6 +143,47 @@ def _inverse_bwd(t, dt):
 _unit_lower_inverse.defvjp(_inverse_fwd, _inverse_bwd)
 
 
+def _intra(q, k, v, g, beta, sub, far, dtype):
+    """What happens inside a chunk, for every chunk at once, in plain XLA: q, k,
+    v, g (B, H, N, C, D), beta (B, H, N, C, 1) -> the scan's six operands, each
+    (B, H, N, ...).  The path of every shape and platform the kernel of
+    ``ops/pallas/kda.py`` is not written for, and that kernel's oracle."""
+    b, h, n, chunk, dk = k.shape
+    r = chunk // sub
+    with jax.named_scope("chunk"):
+        f32 = lambda x: x.astype(jnp.float32)
+        gc = jnp.cumsum(g, axis=3)                            # G_t, inclusive
+        # The cumulative decay at the MIDDLE of each sub-chunk: rows and
+        # columns of a sub-chunk then lie within half its length of it.
+        g0 = gc[:, :, :, sub // 2 - 1::sub]                   # (B, H, N, r, Dk)
+        rows = jnp.exp(gc.reshape(b, h, n, r, sub, dk) - g0[:, :, :, :, None, :])
+        cols = jnp.exp(jnp.minimum(
+            g0[:, :, :, :, None, :] - gc[:, :, :, None, :, :], far
+        ))                                                    # (B, H, N, r, C, Dk)
+        k_cols = f32(k)[:, :, :, None] * cols
+        split = lambda x: f32(x).reshape(b, h, n, r, sub, dk)
+        inner = lambda x: jnp.einsum(
+            "bhnrik,bhnrjk->bhnrij", split(x) * rows, k_cols, precision=HI
+        ).reshape(b, h, n, chunk, chunk)
+        a, p = inner(k), inner(q)
+        pos = jnp.arange(chunk)
+        a = jnp.where(pos[:, None] > pos[None, :], a * beta, 0.0)
+        p = jnp.where(pos[:, None] >= pos[None, :], p, 0.0)
+        tri = _unit_lower_inverse(a)
+        decay = jnp.exp(gc)                                   # e^{G_t} <= 1
+        w = jnp.matmul(tri, beta * f32(k) * decay, precision=HI)
+        u0 = jnp.matmul(tri, beta * f32(v), precision=HI)
+        g_end = gc[:, :, :, -1:, :]                           # G_C
+        xs = (w, u0, f32(q) * decay, p, f32(k) * jnp.exp(g_end - gc))
+        return tuple(x.astype(dtype) for x in xs) + (jnp.exp(g_end[:, :, :, 0, :]),)
+
+
+def _takes_kernel(chunk: int, sub: int, dk: int, dv: int) -> bool:
+    """The chunk-local part runs as the Pallas kernel pair where there is a TPU
+    to run it and the shapes are the ones it is written for."""
+    return jax.default_backend() == "tpu" and kda_kernel.supported(chunk, sub, dk, dv)
+
+
 def kda_chunked(q, k, v, g, beta, chunk: int = CHUNK, sub: int = 16, dtype=jnp.bfloat16,
                 lower_bound: float = -5.0):
     """Chunked form of :func:`kda_recurrent` (same arguments and result).
@@ -143,17 +191,16 @@ def kda_chunked(q, k, v, g, beta, chunk: int = CHUNK, sub: int = 16, dtype=jnp.b
     ``-lower_bound * sub / 2`` is the largest exponent that counts (columns
     past it are above the diagonal, masked, and clamped one above it, so a
     value in range never sits ON the clamp, where ``minimum`` halves
-    gradients).  q, k, v and what the scan is handed are kept in ``dtype``; the
-    part before the scan and the scan's body are each under ``jax.checkpoint``,
-    so the backward keeps the chunked inputs, the scan's operands and one state
-    a chunk."""
+    gradients).  q, k, v and what the scan is handed are kept in ``dtype``.
+    The backward keeps the chunk-local part's inputs, the scan's operands and
+    one state a chunk: the kernel pair recomputes a chunk in VMEM, the XLA
+    form and the scan's body are each under ``jax.checkpoint``."""
     if chunk % sub or chunk & (chunk - 1):
         raise ValueError(f"chunk {chunk} must be a power of two and a multiple of sub {sub}")
     b, t, h, dk = k.shape
     dv = v.shape[-1]
     n = -(-t // chunk)
     pad = n * chunk - t
-    r = chunk // sub
     far = -lower_bound * sub / 2 + 1.0
 
     def chunks(x, kind):  # (B, T, H, ...) -> (B, H, N, C, ...)
@@ -168,35 +215,6 @@ def kda_chunked(q, k, v, g, beta, chunk: int = CHUNK, sub: int = 16, dtype=jnp.b
             )
 
     @jax.checkpoint
-    def intra(q, k, v, g, beta):
-        with jax.named_scope("chunk"):
-            f32 = lambda x: x.astype(jnp.float32)
-            gc = jnp.cumsum(g, axis=3)                            # G_t, inclusive
-            # The cumulative decay at the MIDDLE of each sub-chunk: rows and
-            # columns of a sub-chunk then lie within half its length of it.
-            g0 = gc[:, :, :, sub // 2 - 1::sub]                   # (B, H, N, r, Dk)
-            rows = jnp.exp(gc.reshape(b, h, n, r, sub, dk) - g0[:, :, :, :, None, :])
-            cols = jnp.exp(jnp.minimum(
-                g0[:, :, :, :, None, :] - gc[:, :, :, None, :, :], far
-            ))                                                    # (B, H, N, r, C, Dk)
-            k_cols = f32(k)[:, :, :, None] * cols
-            split = lambda x: f32(x).reshape(b, h, n, r, sub, dk)
-            inner = lambda x: jnp.einsum(
-                "bhnrik,bhnrjk->bhnrij", split(x) * rows, k_cols, precision=HI
-            ).reshape(b, h, n, chunk, chunk)
-            a, p = inner(k), inner(q)
-            pos = jnp.arange(chunk)
-            a = jnp.where(pos[:, None] > pos[None, :], a * beta, 0.0)
-            p = jnp.where(pos[:, None] >= pos[None, :], p, 0.0)
-            tri = _unit_lower_inverse(a)
-            decay = jnp.exp(gc)                                   # e^{G_t} <= 1
-            w = jnp.matmul(tri, beta * f32(k) * decay, precision=HI)
-            u0 = jnp.matmul(tri, beta * f32(v), precision=HI)
-            g_end = gc[:, :, :, -1:, :]                           # G_C
-            xs = (w, u0, f32(q) * decay, p, f32(k) * jnp.exp(g_end - gc))
-            return tuple(x.astype(dtype) for x in xs) + (jnp.exp(g_end[:, :, :, 0, :]),)
-
-    @jax.checkpoint
     def step(s, xs):
         w_c, u0_c, q_c, p_c, k_c, d_c = xs
         u = u0_c - mm("bhck,bhkv->bhcv", w_c, s)
@@ -204,11 +222,17 @@ def kda_chunked(q, k, v, g, beta, chunk: int = CHUNK, sub: int = 16, dtype=jnp.b
         s = s * d_c[..., None] + mm("bhck,bhcv->bhkv", k_c, u)
         return s, o
 
+    kernel = _takes_kernel(chunk, sub, dk, dv)
     with jax.named_scope("intra"):
-        xs = intra(chunks(q, dtype), chunks(k, dtype), chunks(v, dtype),
-                   chunks(g, jnp.float32), chunks(beta, jnp.float32)[..., None])
+        if kernel:      # chunk-major already
+            xs = kda_kernel.kda_intra(q, k, v, g, beta, dtype, far)
+        else:
+            xs = jax.checkpoint(_intra, static_argnums=(5, 6, 7))(
+                chunks(q, dtype), chunks(k, dtype), chunks(v, dtype), chunks(g, jnp.float32),
+                chunks(beta, jnp.float32)[..., None], sub, far, dtype)
     with jax.named_scope("inter"):
-        xs = tuple(jnp.moveaxis(x, 2, 0) for x in xs)
+        if not kernel:
+            xs = tuple(jnp.moveaxis(x, 2, 0) for x in xs)
         _, o = lax.scan(step, jnp.zeros((b, h, dk, dv), jnp.float32), xs)
     o = jnp.moveaxis(o, 0, 2).reshape(b, h, n * chunk, dv)[:, :, :t]
     return jnp.moveaxis(o, 1, 2)

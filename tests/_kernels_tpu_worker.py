@@ -36,7 +36,14 @@ two facts about the runtime the benchmark's timing method leans on
 (``runtime``): what one dispatch costs, and whether ``block_until_ready``
 is a true barrier.
 
-Run directly: python tests/_kernels_tpu_worker.py
+- ``kda_intra[ling3_flash_vl_det.train_coco]`` — the chunk-local part of
+  the KDA scan as the Pallas kernel pair (``ops/pallas/kda.py``) at the
+  decoder cell's shape vs the XLA form, float32 against float32 and
+  bfloat16 against float32, on the inputs that broke the XLA form on this
+  chip, with the measured time of each.
+
+Run directly: python tests/_kernels_tpu_worker.py [word ...] (only the
+probes whose name holds one of the words)
 """
 
 from __future__ import annotations
@@ -362,6 +369,133 @@ def probe_nms_tiled(seed):
     }
 
 
+def probe_kda_intra(b, t, h):
+    """The chunk-local part of the KDA scan as the Pallas kernel pair
+    (``ops/pallas/kda.py``: ``kda_intra_fwd``, ``kda_intra_bwd``) at the
+    decoder cell's shape, q ``bf16[2, 4200, 32, 128]``: Mosaic compiles both;
+    the six results and the five gradients in float32 against the XLA form
+    (``ops/kda.py::_intra``), where both are float32 at ``highest`` and only
+    the order of sums differs; the whole ``kda_chunked`` in bfloat16, result
+    and gradients, against its float32 self on the XLA form; the residual of
+    the kernel's triangular inverse where keys look alike; and the time of
+    each form, forward and forward + backward.  The heads hold what broke
+    the XLA form on this chip (PERF.md section 6, PR 27): head 0 sits at the
+    safe gate's lower bound, head 1 at its upper, head 2 has keys that are
+    one vector but for 5 % (I + A near the all-ones triangle), the rest draw
+    the gate over its whole range."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.experimental import pallas as pl
+
+    from mx_rcnn_tpu.ops import kda
+    from mx_rcnn_tpu.ops.pallas import kda as kernel
+
+    d, far = 128, 41.0
+    ks = jax.random.split(jax.random.PRNGKey(31), 8)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    alike = unit(jax.random.normal(ks[5], (b, 1, 1, d))
+                 + 0.05 * jax.random.normal(ks[6], (b, t, 1, d)))
+    q = unit(jax.random.normal(ks[0], (b, t, h, d))) * d**-0.5
+    k = unit(jax.random.normal(ks[1], (b, t, h, d))).at[:, :, 2:3].set(alike)
+    v = jax.random.normal(ks[2], (b, t, h, d))
+    g = -5.0 * jax.nn.sigmoid(2.3 * jax.random.normal(ks[3], (b, t, h, d)))
+    g = g.at[:, :, 0].set(-5.0).at[:, :, 1:3].set(-1e-3)
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, t, h))).at[:, :, 2].set(0.97)
+    q, k, v = (x.astype(jnp.bfloat16) for x in (q, k, v))
+    args = (q, k, v, g, beta)
+    cot = jax.random.normal(ks[7], (b, t, h, d))
+    rel = lambda got, want: float(
+        jnp.linalg.norm(got.astype(jnp.float32) - want) / jnp.linalg.norm(want))
+
+    def xla_intra(dtype):
+        def fn(q, k, v, g, beta):
+            n = -(-t // kda.CHUNK)
+            ch = lambda x, kind: jnp.moveaxis(jnp.pad(
+                x.astype(kind), ((0, 0), (0, n * kda.CHUNK - t)) + ((0, 0),) * (x.ndim - 2)
+            ).reshape((b, n, kda.CHUNK) + x.shape[2:]), 3, 1)
+            xs = jax.checkpoint(kda._intra, static_argnums=(5, 6, 7))(
+                ch(q, dtype), ch(k, dtype), ch(v, dtype), ch(g, jnp.float32),
+                ch(beta, jnp.float32)[..., None], 16, far, dtype)
+            return tuple(jnp.moveaxis(x, 2, 0) for x in xs)
+        return fn
+
+    def with_grads(fn):
+        """The results and, against fixed cotangents, the five gradients."""
+        def loss(*a):
+            out = fn(*a)
+            return sum(jnp.sum(x.astype(jnp.float32) * jnp.cos(0.1 * i + x.astype(jnp.float32)))
+                       for i, x in enumerate(out)), out
+        return jax.jit(lambda *a: jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True)(*a))
+
+    # 1. float32 against float32: the kernel's arithmetic is the XLA form's.
+    parts = {}
+    wide = tuple(x.astype(jnp.float32) for x in args)
+    got = with_grads(lambda *a: kernel.kda_intra(*a, jnp.float32, far))(*wide)
+    want = with_grads(xla_intra(jnp.float32))(*wide)
+    for name, x, y in zip(("w", "u0", "qe", "p", "ke", "eg"), got[0][1], want[0][1]):
+        parts[name] = rel(x, y)
+    for name, x, y in zip(("dq", "dk", "dv", "dg", "dbeta"), got[1], want[1]):
+        parts[name] = rel(x, y.astype(jnp.float32))
+    del got, want
+
+    # 2. the whole op in bfloat16 against its float32 self on the XLA form.
+    def chunked(dtype, takes_kernel):
+        def fn(*a):
+            held, kda._takes_kernel = kda._takes_kernel, lambda *shape: takes_kernel
+            try:
+                return (kda.kda_chunked(*a, dtype=dtype),)
+            finally:
+                kda._takes_kernel = held
+        return fn
+
+    want = with_grads(chunked(jnp.float32, False))(*args)
+    whole = {}
+    for form, takes_kernel in (("kernel", True), ("xla", False)):
+        got = with_grads(chunked(jnp.bfloat16, takes_kernel))(*args)
+        whole[form] = {"o": rel(got[0][1][0], want[0][1][0])}
+        for name, x, y in zip(("dq", "dk", "dv", "dg", "dbeta"), got[1], want[1]):
+            whole[form][name] = rel(x, y.astype(jnp.float32))
+    del got, want
+
+    # 3. the kernel's inverse where keys look alike: |(I + A) T - I|.
+    kk = alike[0, :kernel.CHUNK, 0].astype(jnp.bfloat16).astype(jnp.float32)
+    pos = jnp.arange(kernel.CHUNK)
+    a = jnp.where(pos[:, None] > pos[None, :], 0.97 * jnp.dot(kk, kk.T, precision="highest"), 0.0)
+
+    def inverse(a_ref, t_ref):
+        row = jax.lax.broadcasted_iota(jnp.int32, a.shape, 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, a.shape, 1)
+        t_ref[...] = kernel._alone(kernel._unit_lower_inverse(a_ref[...], row, col))
+
+    tri = pl.pallas_call(inverse, out_shape=jax.ShapeDtypeStruct(a.shape, a.dtype),
+                         interpret=False)(a)
+    eye = np.eye(kernel.CHUNK)
+    residual = float(np.abs((eye + np.asarray(a, np.float64)) @ np.asarray(tri, np.float64) - eye).max())
+
+    # 4. the time of each form on the scan's own operands (bfloat16).
+    fwd = lambda fn: jax.jit(fn)
+    kern, xla = lambda *a: kernel.kda_intra(*a, jnp.bfloat16, far), xla_intra(jnp.bfloat16)
+    ms = {
+        "kernel_ms": _least_ms(fwd(kern), *args), "xla_ms": _least_ms(fwd(xla), *args),
+        "kernel_fwd_bwd_ms": _least_ms(with_grads(kern), *args),
+        "xla_fwd_bwd_ms": _least_ms(with_grads(xla), *args),
+    }
+    # float32 sums in another order: 1e-4 is a hundred roundings' room and a
+    # tenth of what one bfloat16 operand inside a chunk would read; the decay's
+    # gradient is a small difference of large terms where a channel decays hard.
+    # bfloat16 against float32: what the XLA form reads on the same inputs
+    # (0.15-0.22 % forward, 0.24-0.44 % on the gradients on plain draws on
+    # this chip, PERF.md section 6, PR 27) and a fifth more, for the roundings
+    # that fall otherwise.
+    ok = (all(x < (1e-3 if n == "dg" else 1e-4) for n, x in parts.items())
+          and all(x <= 1.2 * whole["xla"][n] for n, x in whole["kernel"].items())
+          and residual <= 1e-5)
+    return {"ok": ok, "rel_l2_f32_kernel_vs_xla": parts, "rel_l2_bf16_vs_f32": whole,
+            "inverse_residual": residual, **ms, "shape": [b, t, h, d],
+            "chunks_per_step": kernel._chunks_per_step(-(-t // kernel.CHUNK))}
+
+
 def real_step_candidates(seed, workload="vgg16_voc07.train_b16"):
     """The pre-NMS candidates of a real step: the benchmark cell's model with
     the weights and the first batch of ``--seed``, forward to the RPN, top-k,
@@ -474,6 +608,7 @@ PROBES = (
     ("roi_align_matmul[vgg16_voc07.train_b16]",
      probe_roi_align_matmul, (16, 38, 64, 512, 128)),
     ("nms_tiled[vgg16_voc07.train_b16,seed941]", probe_nms_tiled, (941,)),
+    ("kda_intra[ling3_flash_vl_det.train_coco]", probe_kda_intra, (2, 4200, 32)),
 )
 
 
@@ -485,7 +620,10 @@ def main() -> int:
     print("DEVICE " + json.dumps(out), flush=True)
     configure_cache()
     all_ok = True
+    only = sys.argv[1:]     # probes whose name holds one of these words; all if none
     for name, fn, args in PROBES:
+        if only and not any(word in name for word in only):
+            continue
         t0 = time.perf_counter()
         try:
             res = fn(*args)

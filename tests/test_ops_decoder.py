@@ -6,9 +6,21 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from mx_rcnn_tpu.ops import kda
 from mx_rcnn_tpu.ops.attention import causal_attention, causal_attention_dense
 from mx_rcnn_tpu.ops.kda import kda_chunked, kda_recurrent, short_conv
 from mx_rcnn_tpu.ops.moe import held_experts, route, segment_rows
+
+
+@pytest.fixture(params=["xla", "kernel"])
+def width(request, monkeypatch):
+    """The head width at which the chunk-local part runs as plain XLA (16), and
+    the one at which it runs as the Pallas kernel pair (128, chunk 64) once
+    ``kda_chunked`` finds a TPU: here the kernels are interpreted."""
+    if request.param == "xla":
+        return 16
+    monkeypatch.setattr(kda, "_takes_kernel", kda.kda_kernel.supported)
+    return 128
 
 
 def _kda_inputs(seed, b, t, h=2, d=16):
@@ -44,12 +56,12 @@ def test_chunked_kda_gradients_are_the_recurrence_s(length):
 
 
 @pytest.mark.parametrize("gate", [-5.0, -1e-3])
-def test_chunked_kda_holds_at_the_gate_s_bounds(gate):
+def test_chunked_kda_holds_at_the_gate_s_bounds(gate, width):
     """Every channel at the safe gate's bound for a whole chunk (and hardly
     decaying at all): the result AND the gradients are the recurrence's.  With
     the decay measured from a sub-chunk's start, e^-80 times a cotangent fell
     under float32's range and the decay's gradient came out 76 times off."""
-    q, k, v, g, beta = _kda_inputs(3, 1, 128)
+    q, k, v, g, beta = _kda_inputs(3, 1, 128, d=width)
     g = jnp.full_like(g, gate)
     want = kda_recurrent(q, k, v, g, beta)
     got = kda_chunked(q, k, v, g, beta, dtype=jnp.float32)
@@ -211,12 +223,12 @@ def test_held_experts_gradients_reach_weights_and_tokens():
 
 
 @pytest.mark.parametrize("chunk,noise", [(16, 0.0), (64, 0.0), (64, 0.05)])
-def test_chunked_kda_holds_where_tokens_look_alike(chunk, noise):
+def test_chunked_kda_holds_where_tokens_look_alike(chunk, noise, width):
     """Keys that are (nearly) one vector, beta near 1 and hardly any decay: I + A
     is a constant times the all-ones triangle, the case in which the
     multiplied-out series for its inverse cancels to noise (flat image
     background does this to every chunk)."""
-    b, t, h, d = 1, 128, 2, 16
+    b, t, h, d = 1, 128, 2, width
     ks = jax.random.split(jax.random.PRNGKey(5), 6)
     unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
     near = lambda k, shape: jax.random.normal(k, (b, 1) + shape) + noise * jax.random.normal(
@@ -230,5 +242,8 @@ def test_chunked_kda_holds_where_tokens_look_alike(chunk, noise):
     assert float(jnp.abs(chunked(q, k, v, g, beta) - want).max()) < 1e-5 * float(jnp.abs(want).max())
     gw = jax.grad(loss(kda_recurrent), argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
     gg = jax.grad(loss(chunked), argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
+    # 128 wide the recurrence's own float32 sums are eight times as long: the
+    # XLA form reads 1.7e-4 on beta's gradient there, the kernels 1.6e-4
+    tol = 1e-4 if width == 16 else 2.5e-4
     for w_, g_ in zip(gw, gg):
-        assert float(jnp.linalg.norm(g_ - w_)) < 1e-4 * float(jnp.linalg.norm(w_))
+        assert float(jnp.linalg.norm(g_ - w_)) < tol * float(jnp.linalg.norm(w_))
