@@ -45,7 +45,13 @@ class DecoderConfig:
     share: the published layers ``layers`` are held (a layer's kind follows
     from its published index), and of each layer's ``num_experts`` routed
     experts the ``experts_count`` from ``experts_first`` on; the router keeps
-    its published width."""
+    its published width.
+
+    Two families' patterns fit.  ``pattern`` empty: Ling's rule, every layer
+    a mixer and a feed-forward (``layer_group_size``, ``first_k_dense``).
+    ``pattern`` given (``NEMOTRON_TWOTOWER``): one letter a PUBLISHED layer,
+    each layer ONE sub-layer - ``M`` a Mamba-2 state-space mixer (``ssm_*``),
+    ``*`` grouped-query attention (``num_kv_heads``), ``E`` the expert layer."""
 
     hidden_size: int = 2560
     num_heads: int = 32
@@ -74,15 +80,41 @@ class DecoderConfig:
     kda_lower_bound: float = -5.0
     patch: int = 16               # stride-16 patchify convolution
     neck_channels: int = 256
+    pattern: str = ""             # "" = Ling's rule; else M | * | E a published layer
+    num_kv_heads: int = 32        # key/value heads of a ``*`` layer
+    ssm_heads: int = 64           # an ``M`` layer: heads x head_dim = the inner width,
+    ssm_head_dim: int = 64
+    ssm_groups: int = 8           # B and C shared by heads / groups heads, the gated
+    ssm_state: int = 128          # norm in as many groups; the state is head_dim x state
+    # An expert: "swiglu" (gate, up, down) or "relu2" (down relu(up x)^2).
+    expert_act: str = "swiglu"
+    shared_intermediate_size: int = 0   # the shared expert's width; 0 = the routed experts'
 
 
-DECODER_BACKBONES = ("ling3_flash_vl",)
+# Nemotron-Labs-TwoTower-30B-A3B's tower (huggingface.co/nvidia/
+# Nemotron-Labs-TwoTower-30B-A3B-Base-BF16 config.json, model_type nemotron_h)
+# at one chip's share: published layers 0-12 (the pattern's first two units,
+# MEMEM* + EMEMEM*: 6 Mamba-2, 5 expert, 2 attention layers) and 8 of the 128
+# routed experts a layer (one of 16 chips that share each layer).  The second
+# (denoiser) tower, its conditioning and the block-diffusion decode are not held.
+NEMOTRON_TWOTOWER = DecoderConfig(
+    hidden_size=2688, num_heads=32, head_dim=128, num_kv_heads=2,
+    layers=tuple(range(13)),
+    pattern="MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME",
+    ssm_heads=64, ssm_head_dim=64, ssm_groups=8, ssm_state=128, short_conv_kernel=4,
+    moe_intermediate_size=1856, shared_intermediate_size=3712, expert_act="relu2",
+    num_experts=128, experts_first=0, experts_count=8, num_experts_per_tok=6,
+    n_group=1, topk_group=1, routed_scaling_factor=2.5, rms_norm_eps=1.0e-5,
+)
+
+# Backbone name -> the decoder blocks it holds.
+DECODER_BACKBONES = {"ling3_flash_vl": DecoderConfig(), "nemotron_twotower": NEMOTRON_TWOTOWER}
 
 
 @dataclass(frozen=True)
 class BackboneConfig:
-    # resnet50 | resnet101 | vgg16 | ling3_flash_vl (decoder blocks as a
-    # plain backbone, sized by ``decoder``)
+    # resnet50 | resnet101 | vgg16 | ling3_flash_vl | nemotron_twotower
+    # (decoder blocks as a plain backbone, sized by ``decoder``)
     name: str = "resnet50"
     # Stages to freeze, counted like the reference's fixed_param_prefix
     # (conv1 + res2 frozen for ResNet; conv1_/conv2_ for VGG).
@@ -690,7 +722,8 @@ def _backbone(name: str) -> BackboneConfig:
         # Nothing frozen (no pretrained stem to protect); every block is
         # recomputed on the backward pass (12 bytes a parameter leave no
         # room for stored activations).
-        return BackboneConfig(name=name, freeze_stages=0, norm="none", remat=True)
+        return BackboneConfig(name=name, freeze_stages=0, norm="none", remat=True,
+                              decoder=DECODER_BACKBONES[name])
     return BackboneConfig(name=name)
 
 
@@ -802,10 +835,10 @@ _register(
         train=TrainConfig(per_device_batch=2),
     ),
 )
-def _ling3_flash_vl_det_model() -> ModelConfig:
-    m = _c4_model(81, "ling3_flash_vl")
+def _decoder_det_model(backbone: str) -> ModelConfig:
+    m = _c4_model(81, backbone)
     # Two images a call at test time too: 4,200 tokens an image through
-    # 802 M parameters leave no room for the default eight.
+    # 0.8 G parameters leave no room for the default eight.
     return _replace(
         m, rpn=_replace(m.rpn, channels=256), test=_replace(m.test, per_device_batch=2)
     )
@@ -818,7 +851,18 @@ _register(
     "ling3_flash_vl_det",
     lambda: Config(
         name="ling3_flash_vl_det",
-        model=_ling3_flash_vl_det_model(),
+        model=_decoder_det_model("ling3_flash_vl"),
+        data=DataConfig(dataset="coco"),
+        train=TrainConfig(per_device_batch=2),
+    ),
+)
+# Nemotron-Labs-TwoTower-30B-A3B's tower (Mamba-2 state-space layers,
+# 2-KV-head attention, relu^2 experts at one chip's share) the same way.
+_register(
+    "nemotron_twotower_det",
+    lambda: Config(
+        name="nemotron_twotower_det",
+        model=_decoder_det_model("nemotron_twotower"),
         data=DataConfig(dataset="coco"),
         train=TrainConfig(per_device_batch=2),
     ),

@@ -6,15 +6,19 @@ hidden states back on the (H/16, W/16) grid -> a two-conv neck: the
 plain-backbone pattern of Li et al. (arXiv:2203.16527) without its pyramid,
 emitted as a one-entry pyramid at level 4 like VGG's.
 
-The blocks are Ling-3.0-flash-VL's (``config.py::DecoderConfig``): pre-norm
-residual blocks with RMSNorm; the mixer is KDA linear attention
-(``ops/kda.py``) except in the last layer of each group of
-``layer_group_size``, where it is latent attention (MLA, ``ops/attention.py``);
-the feed-forward is a dense SwiGLU below ``first_k_dense`` and the routed
-expert layer (``ops/moe.py``) with its shared expert above.  The expert layer
-is told which experts it holds, routes over all of them and computes its own
-experts' part; what absent experts would have added is left out and that
-partial result goes on.
+A layer is a list of pre-norm residual sub-layers, ``x <- x + f(RMSNorm(x))``,
+whose kinds follow from the configuration (``config.py::DecoderConfig``,
+:func:`sublayers`), two families so far.  Ling-3.0-flash-VL's: a mixer, KDA
+linear attention (``ops/kda.py``) except in the last layer of each group of
+``layer_group_size``, where it is latent attention (MLA,
+``ops/attention.py``), then a feed-forward, a dense SwiGLU below
+``first_k_dense`` and the routed expert layer (``ops/moe.py``) with its shared
+expert above.  Nemotron-Labs-TwoTower's tower: ONE sub-layer a layer, by the
+published pattern's letter a Mamba-2 state-space mixer (``ops/ssd.py``),
+grouped-query attention or the expert layer (non-gated relu^2 experts).  The
+expert layer is told which experts it holds, routes over all of them and
+computes its own experts' part; what absent experts would have added is left
+out and that partial result goes on.
 
 The flax module only declares the leaves (one nested name per leaf, so the
 plan's family rule, the optimizer's decay rule by leaf name and a checkpoint
@@ -37,27 +41,48 @@ from jax import lax
 from mx_rcnn_tpu.config import DecoderConfig
 from mx_rcnn_tpu.ops.attention import causal_attention
 from mx_rcnn_tpu.ops.kda import kda_chunked, short_conv
-from mx_rcnn_tpu.ops.moe import held_experts, route, segment_rows
+from mx_rcnn_tpu.ops.moe import held_experts, route
+from mx_rcnn_tpu.ops.ssd import ssd_chunked
 
-def layer_kinds(cfg: DecoderConfig, layer: int) -> tuple[str, str]:
-    """(mixer, feed-forward) of a published layer index."""
+PATTERN_KINDS = {"M": "ssm", "*": "gqa", "E": "moe"}
+
+# The sorted dispatch's segment size went with the dispatch (PR 32); nothing
+# reads this.  ``tests/perfbench/_ling_tiny.py``, a benchmark file that only a
+# ``benchmark`` PR may edit, still sets the name: that PR drops both.
+segment_rows = None
+
+
+def layer_kinds(cfg: DecoderConfig, layer: int) -> tuple[str, ...]:
+    """The kinds of a published layer's sub-layers, in order: a letter of
+    ``pattern``, else Ling's (mixer, feed-forward)."""
+    if cfg.pattern:
+        return (PATTERN_KINDS[cfg.pattern[layer]],)
     mixer = "mla" if (layer + 1) % cfg.layer_group_size == 0 else "kda"
     return mixer, ("ffn" if layer < cfg.first_k_dense else "moe")
+
+
+def sublayers(cfg: DecoderConfig, layer: int) -> tuple[tuple[str, str], ...]:
+    """((norm leaf, kind), ...) of a published layer."""
+    kinds = layer_kinds(cfg, layer)
+    if len(kinds) == 1:
+        return (("norm", kinds[0]),)
+    return tuple((f"norm{i + 1}", kind) for i, kind in enumerate(kinds))
 
 
 # -- leaves --------------------------------------------------------------------
 
 
-def _swiglu_spec(d: int, f: int):
-    return (("gate", (("kernel", (d, f)),)), ("up", (("kernel", (d, f)),)),
-            ("down", (("kernel", (f, d)),)))
+def _mlp_spec(d: int, f: int, act: str = "swiglu"):
+    gate = (("gate", (("kernel", (d, f)),)),) if act == "swiglu" else ()
+    return gate + (("up", (("kernel", (d, f)),)), ("down", (("kernel", (f, d)),)))
 
 
 def leaf_spec(cfg: DecoderConfig):
     """The backbone's leaves as nested ((name, subtree or shape), ...).  A leaf
     named ``kernel`` is drawn lecun-normal over all but its last axis; ``scale``
     starts at 1, ``bias`` and the router's selection bias ``e_bias`` (a
-    constant, not a parameter) at 0."""
+    constant, not a parameter) at 0; a state-space mixer's ``A_log``,
+    ``dt_bias`` and ``D`` as :func:`_init` says."""
     d, h, hd = cfg.hidden_size, cfg.num_heads, cfg.head_dim
     lin = lambda i, o: (("kernel", (i, o)),)
     scale = lambda n: (("scale", (n,)),)
@@ -76,20 +101,29 @@ def leaf_spec(cfg: DecoderConfig):
         ("q_norm", scale(dq)), ("k_norm", scale(dq)), ("gate", lin(d, h)),
         ("o", lin(h * cfg.v_head_dim, d)),
     )
-    f = cfg.moe_intermediate_size
+    inner, bc = cfg.ssm_heads * cfg.ssm_head_dim, 2 * cfg.ssm_groups * cfg.ssm_state
+    ssm = (
+        ("in_proj", lin(d, 2 * inner + bc + cfg.ssm_heads)),        # z | x B C | dt
+        ("conv", (("kernel", (kc, inner + bc)), ("bias", (inner + bc,)))),
+        ("A_log", (cfg.ssm_heads,)), ("dt_bias", (cfg.ssm_heads,)), ("D", (cfg.ssm_heads,)),
+        ("norm", scale(inner)), ("out_proj", lin(inner, d)),
+    )
+    gqa = (("q", lin(d, h * hd)), ("k", lin(d, cfg.num_kv_heads * hd)),
+           ("v", lin(d, cfg.num_kv_heads * hd)), ("o", lin(h * hd, d)))
+    f, act = cfg.moe_intermediate_size, cfg.expert_act
     ids = range(cfg.experts_first, cfg.experts_first + cfg.experts_count)
     moe = (
         ("router", (("kernel", (d, cfg.num_experts)), ("e_bias", (cfg.num_experts,)))),
-        ("shared", _swiglu_spec(d, f)),
-        ("experts", tuple((f"e{e}", _swiglu_spec(d, f)) for e in ids)),
+        ("shared", _mlp_spec(d, cfg.shared_intermediate_size or f, act)),
+        ("experts", tuple((f"e{e}", _mlp_spec(d, f, act)) for e in ids)),
     )
+    kinds = {"kda": kda, "mla": mla, "ssm": ssm, "gqa": gqa, "moe": moe,
+             "ffn": _mlp_spec(d, cfg.intermediate_size)}
     out = [("patchify", (("kernel", (cfg.patch, cfg.patch, 3, d)), ("bias", (d,))))]
     for layer in cfg.layers:
-        mixer, ff = layer_kinds(cfg, layer)
-        out.append((f"l{layer}", (
-            ("norm1", scale(d)), (mixer, kda if mixer == "kda" else mla),
-            ("norm2", scale(d)),
-            (ff, _swiglu_spec(d, cfg.intermediate_size) if ff == "ffn" else moe),
+        out.append((f"l{layer}", tuple(
+            leaf for norm, kind in sublayers(cfg, layer)
+            for leaf in ((norm, scale(d)), (kind, kinds[kind]))
         )))
     c = cfg.neck_channels
     out += [
@@ -103,7 +137,14 @@ def leaf_spec(cfg: DecoderConfig):
 def _init(name: str):
     if name == "kernel":
         return lambda key, shape: jax.random.normal(key, shape) / math.sqrt(math.prod(shape[:-1]))
-    return nn.initializers.ones if name == "scale" else nn.initializers.zeros
+    if name == "A_log":     # A = -exp(A_log) in the family's 1-16
+        return lambda key, shape: jnp.log(jax.random.uniform(key, shape, minval=1.0, maxval=16.0))
+    if name == "dt_bias":   # softplus(dt_bias) log-uniform in time_step_min-max, 0.001-0.1
+        def dt_bias(key, shape):
+            dt = jnp.exp(jax.random.uniform(key, shape, minval=math.log(1e-3), maxval=math.log(0.1)))
+            return dt + jnp.log(-jnp.expm1(-dt))
+        return dt_bias
+    return nn.initializers.ones if name in ("scale", "D") else nn.initializers.zeros
 
 
 class _Leaves(nn.Module):
@@ -152,9 +193,13 @@ def _dense(x, p, dtype, out=jnp.float32):
         return jnp.dot(x.astype(dtype), p["kernel"].astype(dtype), preferred_element_type=out)
 
 
-def _swiglu(x, p, dtype):
-    return _dense(jax.nn.silu(_dense(x, p["gate"], dtype)) * _dense(x, p["up"], dtype),
-                  p["down"], dtype)
+def _mlp(x, p, dtype):
+    """SwiGLU where the leaves hold a gate, else ``down relu(up x)^2``."""
+    if "gate" in p:
+        hidden = jax.nn.silu(_dense(x, p["gate"], dtype)) * _dense(x, p["up"], dtype)
+    else:
+        hidden = jnp.square(jax.nn.relu(_dense(x, p["up"], dtype)))
+    return _dense(hidden, p["down"], dtype)
 
 
 def _l2(x):
@@ -247,6 +292,59 @@ def mla_mixer(cfg: DecoderConfig, p, x, dtype):
         return _dense(after(o, gate), p["o"], dtype)
 
 
+def ssm_mixer(cfg: DecoderConfig, p, x, dtype):
+    """A Mamba-2 mixer.  x (B, T, D) normed -> (B, T, D): ``in_proj`` to
+    z | x B C | dt, the causal depthwise conv with bias and SiLU over x B C,
+    ``dt = softplus(dt + dt_bias)``, the recurrence (``ops/ssd.py``), the
+    grouped RMSNorm of ``y * silu(z)``, ``out_proj``.  As :func:`kda_mixer`:
+    ``dtype`` activations between the matmuls (``dt`` float32: it is summed
+    over a chunk inside an ``exp``), the glue in float32 under ``jax.checkpoint``."""
+    b, t, _ = x.shape
+    h, hd, g, n = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state
+    inner = h * hd
+    f32 = lambda a: a.astype(jnp.float32)
+
+    @jax.checkpoint
+    def before(xbc, dt, conv, dt_bias):
+        xbc = jax.nn.silu(short_conv(f32(xbc), conv["kernel"]) + conv["bias"])
+        xs, bs, cs = jnp.split(xbc.astype(dtype), [inner, inner + g * n], axis=-1)
+        return (xs.reshape(b, t, h, hd), bs.reshape(b, t, g, n), cs.reshape(b, t, g, n),
+                jax.nn.softplus(dt + dt_bias))
+
+    @jax.checkpoint
+    def after(y, z, scale):
+        y = (y.reshape(b, t, inner) * jax.nn.silu(f32(z))).reshape(b, t, g, inner // g)
+        return _rms(y, scale.reshape(g, inner // g), cfg.rms_norm_eps).reshape(b, t, inner).astype(dtype)
+
+    with jax.named_scope("proj"):
+        kernel = p["in_proj"]["kernel"]
+        zxbc = _dense(x, {"kernel": kernel[:, :-h]}, dtype, out=dtype)
+        dt = _dense(x, {"kernel": kernel[:, -h:]}, dtype)
+    with jax.named_scope("conv"):
+        xs, bs, cs, dt = before(zxbc[..., inner:], dt, p["conv"], p["dt_bias"])
+    with jax.named_scope("scan"):
+        y = ssd_chunked(xs, dt, -jnp.exp(p["A_log"]), bs, cs, p["D"], dtype=dtype)
+    with jax.named_scope("norm"):
+        y = after(y, zxbc[..., :inner], p["norm"]["scale"])
+    with jax.named_scope("proj"):
+        return _dense(y, p["out_proj"], dtype)
+
+
+def gqa_mixer(cfg: DecoderConfig, p, x, dtype):
+    """Grouped-query causal attention, no bias, no rotary embedding (the
+    family's published description: the state-space layers carry position)."""
+    b, t, _ = x.shape
+    h, kv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    with jax.named_scope("proj"):
+        q = _dense(x, p["q"], dtype, out=dtype).reshape(b, t, h, hd)
+        k = _dense(x, p["k"], dtype, out=dtype).reshape(b, t, kv, hd)
+        v = _dense(x, p["v"], dtype, out=dtype).reshape(b, t, kv, hd)
+    with jax.named_scope("attn"):
+        o = causal_attention(q, k, v, hd ** -0.5, dtype=dtype)
+    with jax.named_scope("proj"):
+        return _dense(o.reshape(b, t, h * hd), p["o"], dtype)
+
+
 def moe_layer(cfg: DecoderConfig, p, x, dtype):
     """x (B, T, D) normed -> (the held experts' part + the shared expert, counters)."""
     b, t, d = x.shape
@@ -260,29 +358,34 @@ def moe_layer(cfg: DecoderConfig, p, x, dtype):
     stack = lambda name: jnp.stack(
         [p["experts"][f"e{e}"][name]["kernel"].astype(dtype) for e in ids]
     )
-    segment = segment_rows(b * t, cfg.num_experts_per_tok, cfg.experts_count, cfg.num_experts)
+    gated = "gate" in p["experts"][f"e{cfg.experts_first}"]     # as ``_mlp`` tells the two forms
     y, counters = held_experts(
-        flat, experts, weights, stack("gate"), stack("up"), stack("down"),
-        cfg.experts_first, segment, dtype=dtype,
+        flat, experts, weights, stack("gate") if gated else None, stack("up"), stack("down"),
+        cfg.experts_first, dtype=dtype,
     )
     with jax.named_scope("shared"):
-        y = y + _swiglu(flat, p["shared"], dtype)
+        y = y + _mlp(flat, p["shared"], dtype)
     return y.reshape(b, t, d), counters
 
 
+MIXERS = {"kda": kda_mixer, "mla": mla_mixer, "ssm": ssm_mixer, "gqa": gqa_mixer,
+          "ffn": lambda cfg, p, x, dtype: _mlp(x, p, dtype)}
+
+
 def _block(cfg: DecoderConfig, layer: int, dtype, p, x):
-    """One pre-norm residual block on the float32 stream."""
-    mixer, ff = layer_kinds(cfg, layer)
+    """One published layer on the float32 stream: its pre-norm residual
+    sub-layers in turn.  -> (x, the expert sub-layer's counters or {})."""
+    counters = {}
     with jax.named_scope(f"l{layer}"):
-        with jax.named_scope(mixer):
-            mix = kda_mixer if mixer == "kda" else mla_mixer
-            x = x + mix(cfg, p[mixer], _rms(x, p["norm1"]["scale"], cfg.rms_norm_eps), dtype)
-        with jax.named_scope(ff):
-            normed = _rms(x, p["norm2"]["scale"], cfg.rms_norm_eps)
-            if ff == "ffn":
-                return x + _swiglu(normed, p["ffn"], dtype), {}
-            y, counters = moe_layer(cfg, p["moe"], normed, dtype)
-            return x + y, counters
+        for norm, kind in sublayers(cfg, layer):
+            with jax.named_scope(kind):
+                normed = _rms(x, p[norm]["scale"], cfg.rms_norm_eps)
+                if kind == "moe":
+                    y, counters = moe_layer(cfg, p["moe"], normed, dtype)
+                else:
+                    y = MIXERS[kind](cfg, p[kind], normed, dtype)
+                x = x + y
+    return x, counters
 
 
 def merge_counters(per_layer: list[dict]) -> dict:

@@ -6,7 +6,11 @@ the backward recomputes its scores instead of keeping the probabilities.
 The loop over blocks is unrolled at trace time with static key prefixes, so
 only the triangle is computed at block granularity: (1 + 1/n) / 2 of the
 square for n blocks.  Scores, softmax and sums are float32; the two matmuls
-take ``dtype`` operands.  :func:`causal_attention_dense` is the oracle.
+take ``dtype`` operands.  Keys and values may have fewer heads than the
+queries (grouped-query attention): a key head then serves ``H / Hkv``
+consecutive query heads, which ride an axis of their own through both matmuls,
+so K and V cross HBM once a key head and are never repeated.
+:func:`causal_attention_dense` is the oracle (it repeats them).
 """
 
 from __future__ import annotations
@@ -22,8 +26,11 @@ BLOCK = 256
 
 
 def causal_attention_dense(q, k, v, scale: float):
-    """q, k (B, T, H, Dq), v (B, T, H, Dv), float32 -> (B, T, H, Dv)."""
-    t = q.shape[1]
+    """q (B, T, H, Dq), k (B, T, Hkv, Dq), v (B, T, Hkv, Dv), float32, Hkv
+    dividing H -> (B, T, H, Dv)."""
+    t, rep = q.shape[1], q.shape[2] // k.shape[2]
+    if rep > 1:
+        k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
     with jax.named_scope("dense_scores"):
         s = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision="highest") * scale
         s = jnp.where(jnp.arange(t)[:, None] >= jnp.arange(t)[None, :], s, -jnp.inf)
@@ -32,22 +39,29 @@ def causal_attention_dense(q, k, v, scale: float):
 
 def causal_attention(q, k, v, scale: float, block: int = BLOCK, dtype=jnp.bfloat16):
     """Blocked form of :func:`causal_attention_dense`."""
-    t = q.shape[1]
+    b, t, h, _ = q.shape
+    kv = k.shape[2]
     q, k, v = q.astype(dtype), k.astype(dtype), v.astype(dtype)
+    # With as many key heads as query heads the plain contraction; else the
+    # query heads of one key head on an axis g of their own.
+    scores, values = ("bqhd,bkhd->bhqk", "bhqk,bkhd->bqhd") if kv == h else \
+        ("bqhgd,bkhd->bhgqk", "bhgqk,bkhd->bqhgd")
+    if kv != h:
+        q = q.reshape(b, t, kv, h // kv, q.shape[-1])
 
     def rows(lo, hi, q, k, v):
         # The slices are taken INSIDE the checkpoint: what the backward keeps
         # is the whole q, k and v once, not a prefix of k and v a block.
         q_b, k_b, v_b = q[:, lo:hi], k[:, :hi], v[:, :hi]
         with jax.named_scope("rows"):
-            s = jnp.einsum("bqhd,bkhd->bhqk", q_b, k_b, preferred_element_type=jnp.float32) * scale
+            s = jnp.einsum(scores, q_b, k_b, preferred_element_type=jnp.float32) * scale
             row = lo + jnp.arange(hi - lo)
             s = jnp.where(row[:, None] >= jnp.arange(hi)[None, :], s, -jnp.inf)
             p = jax.nn.softmax(s, axis=-1).astype(dtype)
-            return jnp.einsum("bhqk,bkhd->bqhd", p, v_b, preferred_element_type=jnp.float32)
+            return jnp.einsum(values, p, v_b, preferred_element_type=jnp.float32)
 
     out = [
         jax.checkpoint(partial(rows, lo, min(lo + block, t)))(q, k, v)
         for lo in range(0, t, block)
     ]
-    return jnp.concatenate(out, axis=1)
+    return jnp.concatenate(out, axis=1).reshape(b, t, h, v.shape[-1])

@@ -155,14 +155,17 @@ def quantize_network(variables) -> dict:
 
     def one(path, leaf):
         keys = _path_keys(path)
-        if "router" in keys or "kda" in keys:
+        if "router" in keys or "kda" in keys or "ssm" in keys:
             # A decoder backbone (models/decoder.py): int8 weights move the
-            # router's top-k picks and the KDA decay gate, and the repo has
-            # no calibration or parity check for either.
+            # router's top-k picks, the KDA decay gate and a state-space
+            # scan's decays (A_log, dt_bias, D, the conv and the projection
+            # dt comes from), and the repo has no calibration or parity check
+            # for any of them.
             raise NotImplementedError(
                 "full-network int8 (the full_q8n level) is not defined for a "
                 f"decoder backbone: leaf {'/'.join(map(str, keys))!r} belongs to an "
-                "expert router or a KDA mixer, whose selection and decay gate "
+                "expert router, a KDA mixer or a state-space (ssm) mixer, whose "
+                "selection, decay gate and scan (A_log, dt_bias, D, its conv) "
                 "need a calibrated quantization this repo does not have; "
                 "serve it at the float levels"
             )

@@ -74,6 +74,10 @@ def frozen_mask(params, freeze_prefixes: tuple[str, ...]) -> dict:
     )
 
 
+# Leaf names (anywhere on a leaf's path) that weight decay skips.
+NO_DECAY = ("bias", "scale", "A_log", "dt_bias", "D")
+
+
 def make_optimizer(
     cfg: TrainConfig,
     params,
@@ -84,16 +88,14 @@ def make_optimizer(
 
     Weight decay skips biases and norm scales (standard detection recipe;
     the reference applies wd uniformly but modern schedules that hit the
-    BASELINE north star do not).
+    BASELINE north star do not) and a state-space mixer's ``A_log``,
+    ``dt_bias`` and ``D`` (``NO_DECAY``: the Mamba family's recipe).
     """
     schedule = make_schedule(cfg.schedule, lr_scale)
 
     def decay_mask(p):
         return jax.tree_util.tree_map_with_path(
-            lambda path, _: not any(
-                getattr(k, "key", None) in ("bias", "scale") for k in path
-            ),
-            p,
+            lambda path, _: not any(getattr(k, "key", None) in NO_DECAY for k in path), p,
         )
 
     tx = optax.chain(

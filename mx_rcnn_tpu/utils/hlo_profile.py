@@ -50,6 +50,8 @@ COMPONENT_PATTERNS: tuple[tuple[str, tuple[str, ...]], ...] = (
     ("patchify", ("backbone/patchify",)),
     ("KDA", ("/kda/",)),
     ("MLA", ("/mla/",)),
+    ("SSM", ("/ssm/",)),
+    ("GQA", ("/gqa/",)),
     ("MoE", ("/moe/",)),
     ("dense-FFN", ("/ffn/",)),
     ("neck", ("backbone/neck",)),

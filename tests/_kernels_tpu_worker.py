@@ -42,6 +42,12 @@ is a true barrier.
   bfloat16 against float32, on the inputs that broke the XLA form on this
   chip, with the measured time of each.
 
+- ``ssd[nemotron_twotower_det.train_coco]`` — the chunked state-space scan
+  (``ops/ssd.py::ssd_chunked``, plain XLA) alone at the state-space cell's
+  shape vs the plain reference's token-by-token recurrence, bfloat16 and
+  float32 at ``highest``, with the measured time forward and forward +
+  backward.
+
 Run directly: python tests/_kernels_tpu_worker.py [word ...] (only the
 probes whose name holds one of the words)
 """
@@ -496,6 +502,77 @@ def probe_kda_intra(b, t, h):
             "chunks_per_step": kernel._chunks_per_step(-(-t // kernel.CHUNK))}
 
 
+def probe_ssd(b, t):
+    """The chunked state-space scan (``ops/ssd.py::ssd_chunked``, plain XLA)
+    alone at the state-space cell's shape, x ``bf16[2, 4200, 64, 64]``, B and
+    C ``bf16[2, 4200, 8, 128]``, chunk 128: result and the six gradients in
+    bfloat16 against the float32 token-by-token oracle (the plain
+    reference's ``recurrence``, a scan of checkpointed scans: the backward of
+    ``ssd_recurrent`` would keep 4,200 states of 4 MB an image),
+    the chunked form in float32 against the same (order of sums only), and
+    the time forward and forward + backward.  The heads hold the ends of the
+    published ranges: head 0 ``dt`` at ``time_step_min`` with A 1 (a chunk
+    keeps 88 % of its state: the carry is everything), head 1 ``dt`` at
+    ``time_step_max`` with A 16 (forgets within a few tokens), head 2 sees
+    tokens that are one vector but for 5 % (a flat image's), the rest draw
+    ``dt`` log-uniform and A uniform over the ranges."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mx_rcnn_tpu.ops.ssd import ssd_chunked
+    from perfbench.reference.backbone_nemotron_twotower import recurrence
+
+    h, p, g, n = 64, 64, 8, 128
+    ks = jax.random.split(jax.random.PRNGKey(32), 8)
+    x = jax.random.normal(ks[0], (b, t, h, p))
+    alike = jax.random.normal(ks[6], (b, 1, 1, p)) + 0.05 * jax.random.normal(ks[7], (b, t, 1, p))
+    x = x.at[:, :, 2:3].set(alike)
+    bm, cm = jax.random.normal(ks[1], (b, t, g, n)), jax.random.normal(ks[2], (b, t, g, n))
+    lo, hi = np.log(1e-3), np.log(0.1)
+    dt = jnp.exp(lo + (hi - lo) * jax.random.uniform(ks[3], (b, t, h)))
+    dt = dt.at[:, :, 0].set(1e-3).at[:, :, 1].set(0.1)
+    a = -jax.random.uniform(ks[4], (h,), minval=1.0, maxval=16.0).at[0].set(1.0).at[1].set(16.0)
+    d = jax.random.uniform(ks[5], (h,), minval=0.7, maxval=1.0)
+    # what the mixer hands over: x, B, C already rounded to bfloat16
+    x, bm, cm = (m.astype(jnp.bfloat16).astype(jnp.float32) for m in (x, bm, cm))
+    args = (x, dt, a, bm, cm, d)
+    cot = jax.random.normal(jax.random.PRNGKey(33), (b, t, h, p))
+    rel = lambda got, want: float(
+        jnp.linalg.norm(got.astype(jnp.float32) - want) / jnp.linalg.norm(want))
+    names = ("dx", "ddt", "da", "db", "dc", "dd")
+
+    def with_grads(fn):
+        loss = lambda *m: (jnp.sum(fn(*m) * cot), fn(*m))
+        return jax.jit(lambda *m: jax.value_and_grad(loss, argnums=range(6), has_aux=True)(*m))
+
+    def oracle(x, dt, a, bm, cm, d):
+        heads = lambda m: jnp.repeat(m, h // g, axis=1)
+        one = lambda x, dt, bm, cm: recurrence(x, dt, a, heads(bm), heads(cm))
+        return jax.vmap(one)(x, dt, bm, cm) + d[:, None] * x
+
+    want = with_grads(oracle)(*args)
+    out, finite = {}, True
+    for form, dtype in (("f32", jnp.float32), ("bf16", jnp.bfloat16)):
+        # float32 operands at the chip's default precision are one bfloat16
+        # pass: the float32 form is held at ``highest``, as the oracle is.
+        with jax.default_matmul_precision("highest" if form == "f32" else "default"):
+            got = with_grads(lambda *m, dtype=dtype: ssd_chunked(*m, dtype=dtype))(*args)
+        out[form] = {"y": rel(got[0][1], want[0][1])}
+        for name, u, v in zip(names, got[1], want[1]):
+            out[form][name] = rel(u, v)
+        finite = finite and all(bool(jnp.isfinite(u).all()) for u in got[1])
+    del got, want
+    scan = lambda *m: ssd_chunked(*m, dtype=jnp.bfloat16)
+    ms = {"chunked_ms": _least_ms(jax.jit(scan), *args),
+          "chunked_fwd_bwd_ms": _least_ms(with_grads(scan), *args)}
+    # float32 sums in another order; bfloat16 operands read what the other
+    # decoder cell's scan does against its oracle (0.2-0.8 %, PERF.md section 6).
+    ok = finite and all(v < 1e-3 for v in out["f32"].values()) \
+        and all(v < 2e-2 for v in out["bf16"].values())
+    return {"ok": ok, "rel_l2_vs_recurrence": out, **ms, "shape": [b, t, h, p], "chunk": 128}
+
+
 def real_step_candidates(seed, workload="vgg16_voc07.train_b16"):
     """The pre-NMS candidates of a real step: the benchmark cell's model with
     the weights and the first batch of ``--seed``, forward to the RPN, top-k,
@@ -609,6 +686,7 @@ PROBES = (
      probe_roi_align_matmul, (16, 38, 64, 512, 128)),
     ("nms_tiled[vgg16_voc07.train_b16,seed941]", probe_nms_tiled, (941,)),
     ("kda_intra[ling3_flash_vl_det.train_coco]", probe_kda_intra, (2, 4200, 32)),
+    ("ssd[nemotron_twotower_det.train_coco]", probe_ssd, (2, 4200)),
 )
 
 

@@ -1,7 +1,8 @@
-"""The decoder backbone on the program's normal path at tiny widths on the
-CPU: the preset, the execution plan, the optimizer's decay rule, the ONE
+"""The decoder backbones on the program's normal path at tiny widths on the
+CPU: the presets, the execution plan, the optimizer's decay rule, the ONE
 jitted step through ``build_all``, inference, the CLI, and what the serving
-quantizer and the factory say when they cannot."""
+quantizer and the factory say when they cannot.  Ling's family first, then
+(``ssm_*``, ``nemotron``) the state-space family's."""
 
 import dataclasses
 import os
@@ -14,6 +15,7 @@ import pytest
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), "perfbench"))
 
+import _ssm_tiny  # noqa: E402
 from _ling_tiny import TINY_OVERRIDES, decoder_overrides, small_program_choices  # noqa: E402
 
 from mx_rcnn_tpu.config import BackboneConfig, apply_overrides, available_configs, get_config
@@ -209,6 +211,17 @@ def test_the_serving_quantizer_refuses_the_family_in_words(built):
     ("backbone/l0/ffn/dot_general", "dense-FFN"),
     ("backbone/patchify/conv_general_dilated", "patchify"),
     ("backbone/neck/conv_general_dilated", "neck"),
+    ("jit(step)/jvp(TwoStageDetector.features)/backbone/l0/ssm/proj/dot_general", "SSM"),
+    ("backbone/l2/ssm/conv/mul", "SSM"),
+    ("transpose(jvp(backbone))/l4/checkpoint/ssm/scan/intra/dot_general", "SSM"),
+    ("backbone/l4/ssm/scan/inter/while/body/mul", "SSM"),
+    ("backbone/l4/ssm/norm/rsqrt", "SSM"),
+    ("backbone/l5/gqa/proj/dot_general", "GQA"),
+    ("transpose(jvp(backbone))/l12/checkpoint/gqa/attn/rows/dot_general", "GQA"),
+    ("backbone/l1/moe/router/scores/dot_general", "MoE"),
+    ("backbone/l1/moe/dispatch/gather", "MoE"),
+    ("backbone/l1/moe/combine/scatter-add", "MoE"),
+    ("backbone/l1/moe/shared/dense/dot_general", "MoE"),
 ])
 def test_the_new_scopes_have_a_component(scope, component):
     from mx_rcnn_tpu.utils.hlo_profile import component_of
@@ -245,5 +258,168 @@ def test_the_cli_trains_checkpoints_and_resumes(tmp_path):
     with open(tmp_path / "ling3_flash_vl_det" / "metrics.jsonl") as f:
         rows = [json.loads(line) for line in f]
     assert [r["step"] for r in rows] == [1, 2, 3]
+    for r in rows:
+        assert np.isfinite(r["loss"]) and r["moe_dropped_slots"] == 0.0 and r["moe_slots_here"] > 0
+
+
+# -- the state-space family (preset nemotron_twotower_det) ---------------------
+
+
+def ssm_tiny_cfg(*extra):
+    return apply_overrides(
+        get_config("nemotron_twotower_det"),
+        _ssm_tiny.TINY_OVERRIDES + _ssm_tiny.decoder_overrides()
+        + ["train.per_device_batch=2", *extra],
+    )
+
+
+@pytest.fixture(scope="module")
+def ssm_built():
+    from mx_rcnn_tpu.train.loop import build_all
+
+    with _ssm_tiny.small_program_choices():
+        cfg = ssm_tiny_cfg()
+        model, tx, state, step_fn, global_batch = build_all(cfg, None)
+        yield cfg, model, state, step_fn, global_batch
+
+
+def test_the_nemotron_preset_holds_the_published_widths_and_the_chips_share():
+    cfg = get_config("nemotron_twotower_det")
+    d = cfg.model.backbone.decoder
+    assert "nemotron_twotower_det" in available_configs()
+    assert cfg.model.backbone.name == "nemotron_twotower"
+    assert (d.hidden_size, d.num_heads, d.head_dim, d.num_kv_heads) == (2688, 32, 128, 2)
+    assert (d.ssm_heads, d.ssm_head_dim, d.ssm_groups, d.ssm_state) == (64, 64, 8, 128)
+    assert d.short_conv_kernel == 4 and d.rms_norm_eps == 1e-5
+    assert (d.num_experts, d.num_experts_per_tok, d.n_group, d.topk_group) == (128, 6, 1, 1)
+    assert (d.experts_first, d.experts_count, d.routed_scaling_factor) == (0, 8, 2.5)
+    assert (d.moe_intermediate_size, d.shared_intermediate_size, d.expert_act) == (1856, 3712, "relu2")
+    assert len(d.pattern) == 52 and d.layers == tuple(range(13))
+    kinds = [layer_kinds(d, l) for l in d.layers]
+    assert "".join({"ssm": "M", "moe": "E", "gqa": "*"}[k] for (k,) in kinds) == "MEMEM*EMEMEM*"
+    assert cfg.model.backbone.remat and cfg.model.backbone.freeze_stages == 0
+    assert not cfg.model.fpn.enabled and cfg.model.rpn.channels == 256
+    assert cfg.train.per_device_batch == 2 and cfg.data.image_size == (800, 1344)
+    # the other family's preset still holds its own blocks
+    assert get_config("ling3_flash_vl_det").model.backbone.decoder.pattern == ""
+
+
+def test_the_nemotron_layers_count_their_parameters():
+    d = get_config("nemotron_twotower_det").model.backbone.decoder
+
+    def count(spec):
+        return sum(count(s) if isinstance(s[0], tuple) else int(np.prod(s)) for _, s in spec)
+
+    spec = dict(leaf_spec(d))
+    assert count(spec["l0"]) / 1e6 == pytest.approx(38.7, abs=0.05)     # Mamba-2, ISSUE 32
+    assert count(spec["l5"]) / 1e6 == pytest.approx(23.4, abs=0.05)     # attention, 2 KV heads
+    moe = dict(spec["l1"])["moe"]
+    expert = dict(dict(moe)["experts"])["e0"]
+    assert count(expert) / 1e6 == pytest.approx(9.98, abs=0.01)
+    assert (count(spec["l1"]) - 8 * count(expert)) / 1e6 == pytest.approx(20.3, abs=0.05)
+    layers = sum(count(v) for k, v in spec.items() if k.startswith("l"))
+    assert layers / 1e6 == pytest.approx(779.9, abs=0.5)
+    assert sum(count(v) for v in spec.values()) / 1e6 == pytest.approx(783, abs=1)
+
+
+def test_ssm_every_leaf_resolves_in_the_plan_and_nothing_is_frozen(ssm_built):
+    from mx_rcnn_tpu.parallel.plan import ExecutionPlan
+    from perfbench.program import momentum_trace
+
+    cfg, model, state, _, _ = ssm_built
+    ExecutionPlan.for_model(model).state_specs(state)   # raises on an unmatched leaf
+    names = [n for n, _ in leaf_paths(state.params)]
+    for leaf in ("l0/ssm/A_log", "l0/ssm/dt_bias", "l0/ssm/D", "l0/ssm/conv/bias",
+                 "l0/ssm/norm/scale", "l3/gqa/k/kernel", "l1/moe/experts/e3/down/kernel"):
+        assert f"backbone/{leaf}" in names
+    assert not any("/gate/" in n for n in names)           # two matrices an expert
+    assert len(momentum_trace(state.opt_state)) == len(names)
+    a = np.exp(np.asarray(state.params["backbone"]["l0"]["ssm"]["A_log"]))
+    dt = np.log1p(np.exp(np.asarray(state.params["backbone"]["l0"]["ssm"]["dt_bias"])))
+    assert a.min() >= 1.0 and a.max() <= 16.0 and dt.min() >= 1e-3 * 0.999 and dt.max() <= 0.1001
+
+
+def test_the_scan_s_leaves_and_the_grouped_norm_do_not_decay(ssm_built):
+    from mx_rcnn_tpu.train.optim import NO_DECAY, make_optimizer
+
+    cfg, _, state, _, _ = ssm_built
+    zero = jax.tree_util.tree_map(jnp.zeros_like, state.params)
+    sched = dataclasses.replace(cfg.train.schedule, warmup_steps=0, warmup_factor=1.0)
+    tx, _ = make_optimizer(dataclasses.replace(cfg.train, schedule=sched), state.params)
+    updates, _ = tx.update(zero, tx.init(state.params), state.params)
+    moved = {n: float(jnp.abs(u).max()) > 0 for n, u in leaf_paths(updates)}
+    for name, did in moved.items():
+        assert did == (name.rsplit("/", 1)[1] not in NO_DECAY), name
+    for leaf in ("A_log", "dt_bias", "D", "norm/scale", "conv/bias"):
+        assert not moved[f"backbone/l0/ssm/{leaf}"]
+    assert moved["backbone/l0/ssm/conv/kernel"] and moved["backbone/l0/ssm/in_proj/kernel"]
+
+
+def test_the_one_jitted_step_trains_the_nemotron_preset(ssm_built):
+    _, _, state, step_fn, global_batch = ssm_built
+    assert global_batch == 2
+    state = jax.tree_util.tree_map(lambda x: jnp.array(x, copy=True), state)
+    first = jax.tree_util.tree_map(np.asarray, state.params["backbone"]["l0"]["ssm"])
+    losses = []
+    for _ in range(3):
+        state, m = step_fn(state, tiny_batch())
+        losses.append(float(m["loss"]))
+    assert all(np.isfinite(losses)) and float(m["nonfinite"]) == 0.0
+    assert step_fn._cache_size() == 1                          # one program, no retrace
+    assert float(m["moe_dropped_slots"]) == 0.0
+    tokens, k, layers = 2 * 64, 3, 2
+    assert 0 < float(m["moe_slots_here"]) <= tokens * k * layers
+    for leaf in ("A_log", "dt_bias", "D"):                     # the scan's leaves train
+        assert float(np.abs(np.asarray(state.params["backbone"]["l0"]["ssm"][leaf]) - first[leaf]).max()) > 0
+
+
+def test_an_image_s_ssm_features_do_not_depend_on_its_batch_mates():
+    with _ssm_tiny.small_program_choices():
+        bb = build_backbone(ssm_tiny_cfg().model.backbone, out_levels=(4,), dtype=jnp.float32)
+        x = jax.random.normal(jax.random.PRNGKey(0), (2, 128, 128, 3))
+        variables = bb.init(jax.random.PRNGKey(1), x[:1])
+        both = bb.apply(variables, x)[4]
+        alone = bb.apply(variables, x[1:])[4]
+    np.testing.assert_allclose(both[1:], alone, atol=1e-5)
+    assert both.shape == (2, 8, 8, 32)
+
+
+def test_the_serving_quantizer_refuses_the_scan_s_leaves_in_words(ssm_built):
+    from mx_rcnn_tpu.parallel.step import eval_variables
+    from mx_rcnn_tpu.serve.quantize import quantize_network
+
+    with pytest.raises(NotImplementedError, match="state-space.*A_log, dt_bias, D"):
+        quantize_network(eval_variables(ssm_built[2]))
+
+
+def test_the_nemotron_step_s_flops_leave_no_other_bucket(ssm_built):
+    from mx_rcnn_tpu.utils.hlo_profile import attribute_flops
+
+    _, _, state, step_fn, _ = ssm_built
+    acc = attribute_flops(step_fn, state, tiny_batch())
+    total = sum(v["flops"] for v in acc.values())
+    assert acc.get("other", {"flops": 0.0})["flops"] <= 0.01 * total
+    assert {"SSM", "GQA", "MoE", "patchify", "neck"} <= set(acc)
+    assert not {"KDA", "MLA", "dense-FFN"} & set(acc)
+
+
+def test_the_cli_trains_the_nemotron_preset(tmp_path):
+    """``train.py --config nemotron_twotower_det`` at tiny overrides: the
+    normal path, no option of its own."""
+    import json
+
+    from mx_rcnn_tpu.cli import train_cli
+
+    sets = []
+    for o in _ssm_tiny.TINY_OVERRIDES + _ssm_tiny.decoder_overrides() + [
+        "train.per_device_batch=2", "train.log_every=1",
+    ]:
+        sets += ["--set", o]
+    with _ssm_tiny.small_program_choices():
+        train_cli.main(["--config", "nemotron_twotower_det", "--workdir", str(tmp_path),
+                        "--no-eval", "--steps", "2"] + sets)
+    with open(tmp_path / "nemotron_twotower_det" / "metrics.jsonl") as f:
+        rows = [json.loads(line) for line in f]
+    assert [r["step"] for r in rows] == [1, 2]
     for r in rows:
         assert np.isfinite(r["loss"]) and r["moe_dropped_slots"] == 0.0 and r["moe_slots_here"] > 0
