@@ -1,16 +1,30 @@
 """Causal softmax attention over a few thousand positions that never writes
-the (heads, T, T) scores: query rows are taken ``block`` at a time against
-the keys up to the block's last row, each block under ``jax.checkpoint`` so
-the backward recomputes its scores instead of keeping the probabilities.
+the (heads, T, T) scores to HBM.  Three forms of one function:
 
-The loop over blocks is unrolled at trace time with static key prefixes, so
-only the triangle is computed at block granularity: (1 + 1/n) / 2 of the
-square for n blocks.  Scores, softmax and sums are float32; the two matmuls
-take ``dtype`` operands.  Keys and values may have fewer heads than the
-queries (grouped-query attention): a key head then serves ``H / Hkv``
-consecutive query heads, which ride an axis of their own through both matmuls,
-so K and V cross HBM once a key head and are never repeated.
-:func:`causal_attention_dense` is the oracle (it repeats them).
+- :func:`causal_attention_dense` — the whole score matrix in float32 at
+  ``highest``, K and V repeated for grouped queries: the oracle of both others.
+- the blocked XLA form (:func:`causal_attention` off the TPU and at every
+  shape the kernel is not written for): query rows are taken ``block`` at a
+  time against the keys up to the block's last row, each block under
+  ``jax.checkpoint`` so the backward recomputes its scores instead of keeping
+  the probabilities.  The loop over blocks is unrolled at trace time with
+  static key prefixes, so only the triangle is computed at block granularity:
+  (1 + 1/n) / 2 of the square for n blocks.  A block's scores still cross HBM
+  (the first matmul writes them, the softmax reads and rewrites them).  It is
+  the kernel's second oracle: the same arithmetic in another order of sums.
+- the Pallas kernel pair (``ops/pallas/attention.py``; :func:`causal_attention`
+  on a TPU where ``supported`` says the shapes are the kernel's): a head's
+  sequence resident in VMEM, the scores a tile at a time with the running max
+  and sum, a hand-written backward from q, k, v, o and one log-sum-exp a row.
+
+In all three, scores, softmax and sums are float32; in the last two the
+matmuls take ``dtype`` operands and the probabilities are cast to ``dtype``
+only as the second matmul's operand.  Keys and values may have fewer heads
+than the queries (grouped-query attention): a key head then serves
+``H / Hkv`` consecutive query heads and K and V cross HBM once a key head,
+never repeated (the blocked form gives the group an axis of its own through
+both matmuls, the kernel runs the group's heads one after the other over K
+and V blocks that do not move).
 """
 
 from __future__ import annotations
@@ -19,6 +33,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+
+from mx_rcnn_tpu.ops.pallas import attention as attention_kernel
 
 # Query rows per block: a program choice (at 4,200 positions a block's scores
 # are 32 x 256 x 4,200 float32 = 138 MB an image), not an option; tests pass others.
@@ -37,10 +53,20 @@ def causal_attention_dense(q, k, v, scale: float):
         return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v, precision="highest")
 
 
+def _takes_kernel(t: int, h: int, hkv: int, dk: int, dv: int, dtype) -> bool:
+    """The Pallas kernel pair runs where there is a TPU to run it and the
+    shapes are the ones it is written for."""
+    return jax.default_backend() == "tpu" and attention_kernel.supported(t, h, hkv, dk, dv, dtype)
+
+
 def causal_attention(q, k, v, scale: float, block: int = BLOCK, dtype=jnp.bfloat16):
-    """Blocked form of :func:`causal_attention_dense`."""
+    """:func:`causal_attention_dense` with ``dtype`` operands, float32 out: the
+    kernel pair where :func:`_takes_kernel` says so, else the blocked XLA form
+    (``block`` is that form's rows)."""
     b, t, h, _ = q.shape
     kv = k.shape[2]
+    if _takes_kernel(t, h, kv, q.shape[3], v.shape[3], dtype):
+        return attention_kernel.flash_attention(q, k, v, scale, dtype)
     q, k, v = q.astype(dtype), k.astype(dtype), v.astype(dtype)
     # With as many key heads as query heads the plain contraction; else the
     # query heads of one key head on an axis g of their own.

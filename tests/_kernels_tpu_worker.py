@@ -48,6 +48,14 @@ is a true barrier.
   float32 at ``highest``, with the measured time forward and forward +
   backward.
 
+- ``flash_attention[<cell>]`` — causal attention as the Pallas kernel pair
+  (``ops/pallas/attention.py``) at each decoder cell's shape, q
+  ``bf16[2, 4200, 32, 128]`` on 2 key heads and ``bf16[2, 4200, 32, 192]`` on
+  32 with 128-wide values, vs the blocked XLA form it replaces on the TPU:
+  float32 at ``highest`` both sides, bfloat16 of both against that float32,
+  with the measured time of each form forward and forward + backward; and at
+  a length whose last tile is narrower and ends at T exactly (2,304).
+
 Run directly: python tests/_kernels_tpu_worker.py [word ...] (only the
 probes whose name holds one of the words)
 """
@@ -502,6 +510,93 @@ def probe_kda_intra(b, t, h):
             "chunks_per_step": kernel._chunks_per_step(-(-t // kernel.CHUNK))}
 
 
+def probe_flash_attention(b, t, h, hkv, dk, dv):
+    """Causal attention as the Pallas kernel pair (``ops/pallas/attention.py``:
+    ``flash_attention_fwd``, ``flash_attention_bwd``) at a decoder cell's
+    shape: Mosaic compiles both; result and the three gradients in float32
+    against the blocked XLA form (``ops/attention.py::causal_attention`` as
+    every other platform runs it), where both multiply at ``highest`` and only
+    the order of sums differs; bfloat16 operands, kernel and XLA form, against
+    that float32 (the same roundings: one of q, k, v, one of the probabilities
+    and of the scores' cotangents as a matmul's operand); and the time of each
+    form, forward and forward + backward.  Query head 0 (and key head 0) of
+    every image holds a flat image's patch tokens: queries, keys and values
+    that are each one vector but for 5 %, so the softmax is near uniform over
+    thousands of keys, the scores' cotangents sum to nothing over a row and dq
+    is the small remainder (``dq_alike``, read on that head alone: the number
+    that caught a kernel whose sum(o * do) saw another rounding of do than its
+    dp, PERF.md section 6, PR 33); query head 1 is ten times the size (a
+    softmax near one-hot: the running max moves at every tile)."""
+    import jax
+    import jax.numpy as jnp
+
+    from mx_rcnn_tpu.ops import attention
+    from mx_rcnn_tpu.ops.pallas import attention as kernel
+
+    ks = jax.random.split(jax.random.PRNGKey(33), 6)
+    alike = lambda key, d: (jax.random.normal(jax.random.fold_in(key, 0), (b, 1, d))
+                            + 0.05 * jax.random.normal(jax.random.fold_in(key, 1), (b, t, d)))
+    q = jax.random.normal(ks[0], (b, t, h, dk)).at[:, :, 1].multiply(10.0)
+    q = q.at[:, :, 0].set(alike(ks[4], dk))
+    k = jax.random.normal(ks[1], (b, t, hkv, dk)).at[:, :, 0].set(alike(ks[5], dk))
+    v = jax.random.normal(ks[2], (b, t, hkv, dv)).at[:, :, 0].set(alike(ks[0], dv))
+    # what the mixers hand over: q, k, v already rounded to bfloat16
+    args = tuple(x.astype(jnp.bfloat16).astype(jnp.float32) for x in (q, k, v))
+    cot = jax.random.normal(ks[3], (b, t, h, dv))
+    scale = dk ** -0.5
+    rel = lambda got, want: float(
+        jnp.linalg.norm(got.astype(jnp.float32) - want) / jnp.linalg.norm(want))
+    assert kernel.supported(t, h, hkv, dk, dv, jnp.bfloat16)
+
+    def form(takes_kernel, dtype):
+        def fn(*a):
+            held, attention._takes_kernel = attention._takes_kernel, lambda *shape: takes_kernel
+            try:
+                return attention.causal_attention(*a, scale, dtype=dtype)
+            finally:
+                attention._takes_kernel = held
+        return fn
+
+    def with_grads(fn):
+        loss = lambda *a: (jnp.sum(fn(*a) * cot), fn(*a))
+        return jax.jit(lambda *a: jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(*a))
+
+    def readings(got, want):
+        out = {"o": rel(got[0][1], want[0][1])}
+        out.update({n: rel(x, y) for n, x, y in zip(("dq", "dk", "dv"), got[1], want[1])})
+        out["dq_alike"] = rel(got[1][0][:, :, 0], want[1][0][:, :, 0])
+        return out
+
+    # float32 operands at the chip's default precision are one bfloat16 pass:
+    # the XLA form is held at ``highest``, as the kernel holds itself.
+    with jax.default_matmul_precision("highest"):
+        want = with_grads(form(False, jnp.float32))(*args)
+    wide = readings(with_grads(form(True, jnp.float32))(*args), want)
+    half = {name: readings(with_grads(form(takes, jnp.bfloat16))(*args), want)
+            for name, takes in (("kernel", True), ("xla", False))}
+    finite = all(bool(jnp.isfinite(x).all()) for x in with_grads(form(True, jnp.bfloat16))(*args)[1])
+    del want
+    narrow = tuple(x.astype(jnp.bfloat16) for x in args)
+    ms = {}
+    for name, takes in (("kernel", True), ("xla", False)):
+        fn = form(takes, jnp.bfloat16)
+        ms[name + "_ms"] = _least_ms(jax.jit(fn), *narrow)
+        ms[name + "_fwd_bwd_ms"] = _least_ms(with_grads(fn), *narrow)
+    # float32 sums in another order: 1e-4 is a hundred roundings' room and a
+    # twentieth of what one bfloat16 operand reads.  bfloat16 against float32:
+    # what the XLA form reads on the same inputs and a fifth more, for the
+    # roundings that fall otherwise (the kernel casts the probabilities before
+    # the division by their sum, the XLA form after it); twice on the alike
+    # head's dq, a remainder a hundred times smaller than its terms (a kernel
+    # that breaks the cancellation reads ten to two hundred times).
+    # ``dq_alike`` in float32 is a small difference of large sums on both sides.
+    ok = (finite and all(x < (1e-2 if n == "dq_alike" else 1e-4) for n, x in wide.items())
+          and all(x <= (2.0 if n == "dq_alike" else 1.2) * half["xla"][n]
+                  for n, x in half["kernel"].items()))
+    return {"ok": ok, "rel_l2_f32_kernel_vs_xla": wide, "rel_l2_bf16_vs_f32": half, **ms,
+            "shape": [b, t, h, hkv, dk, dv], "tile": kernel.TILE}
+
+
 def probe_ssd(b, t):
     """The chunked state-space scan (``ops/ssd.py::ssd_chunked``, plain XLA)
     alone at the state-space cell's shape, x ``bf16[2, 4200, 64, 64]``, B and
@@ -687,6 +782,14 @@ PROBES = (
     ("nms_tiled[vgg16_voc07.train_b16,seed941]", probe_nms_tiled, (941,)),
     ("kda_intra[ling3_flash_vl_det.train_coco]", probe_kda_intra, (2, 4200, 32)),
     ("ssd[nemotron_twotower_det.train_coco]", probe_ssd, (2, 4200)),
+    ("flash_attention[nemotron_twotower_det.train_coco]",
+     probe_flash_attention, (2, 4200, 32, 2, 128, 128)),
+    ("flash_attention[ling3_flash_vl_det.train_coco]",
+     probe_flash_attention, (2, 4200, 32, 32, 192, 128)),
+    # a 768 x 768 canvas's 2,304 positions: four whole tiles and one of 256 that
+    # ends at T exactly, the case a backward's loop over whole tiles must stop short of
+    ("flash_attention[768x768,last_tile_narrow]",
+     probe_flash_attention, (1, 2304, 4, 2, 128, 128)),
 )
 
 

@@ -45,10 +45,11 @@ def test_every_pallas_kernel_compiles_and_matches_on_tpu():
     assert lines, (proc.stdout[-2000:], proc.stderr[-4000:])
     out = json.loads(lines[-1][len("RESULT "):])
     assert out["platform"] == "tpu", out
-    # All four pallas_call sites compiled and matched on the installed
+    # All six pallas_call sites compiled and matched on the installed
     # libtpu: ROIAlign forward and backward in PR 21, the KDA scan's
-    # chunk-local pair (ops/pallas/kda.py) in PR 31 (PERF.md); a refusal
-    # here is a regression.
+    # chunk-local pair (ops/pallas/kda.py) in PR 31, causal attention's pair
+    # (ops/pallas/attention.py) in PR 33 (PERF.md); a refusal here is a
+    # regression.
     failed = {
         name: res for name, res in out["probes"].items() if not res["ok"]
     }
