@@ -47,11 +47,18 @@ class DecoderConfig:
     experts the ``experts_count`` from ``experts_first`` on; the router keeps
     its published width.
 
-    Two families' patterns fit.  ``pattern`` empty: Ling's rule, every layer
+    Three families' patterns fit.  ``pattern`` empty: Ling's rule, every layer
     a mixer and a feed-forward (``layer_group_size``, ``first_k_dense``).
     ``pattern`` given (``NEMOTRON_TWOTOWER``): one letter a PUBLISHED layer,
     each layer ONE sub-layer - ``M`` a Mamba-2 state-space mixer (``ssm_*``),
-    ``*`` grouped-query attention (``num_kv_heads``), ``E`` the expert layer."""
+    ``*`` grouped-query attention (``num_kv_heads``), ``E`` the expert layer.
+    ``mb_per_layer`` given (``PHI4_MINI_FLASH``): SambaY's rule against the
+    published depth ``num_hidden_layers`` = L - every ``mb_per_layer``-th layer
+    a Mamba-1 mixer (``mamba_*``) up to L/2 and a Gated Memory Unit after, the
+    others differential attention under ``sliding_window`` below L/2, over the
+    whole prefix at L/2 + 1, and cross attention to that layer's keys and
+    values after; a dense SwiGLU (``intermediate_size``) in every layer,
+    LayerNorm with bias (``rms_norm_eps`` is its epsilon)."""
 
     hidden_size: int = 2560
     num_heads: int = 32
@@ -89,6 +96,12 @@ class DecoderConfig:
     # An expert: "swiglu" (gate, up, down) or "relu2" (down relu(up x)^2).
     expert_act: str = "swiglu"
     shared_intermediate_size: int = 0   # the shared expert's width; 0 = the routed experts'
+    mb_per_layer: int = 0         # > 0: a SambaY decoder, a Mamba-1 layer every so many
+    num_hidden_layers: int = 0    # its PUBLISHED depth: the rule counts from the middle
+    sliding_window: int = 0       # positions a window layer's query sees, itself included
+    mamba_expand: int = 2         # a Mamba-1 mixer: inner width = expand x hidden,
+    mamba_d_state: int = 16       # states a channel,
+    mamba_dt_rank: int = 160      # and the step size's low rank; its conv: short_conv_kernel
 
 
 # Nemotron-Labs-TwoTower-30B-A3B's tower (huggingface.co/nvidia/
@@ -107,13 +120,28 @@ NEMOTRON_TWOTOWER = DecoderConfig(
     n_group=1, topk_group=1, routed_scaling_factor=2.5, rms_norm_eps=1.0e-5,
 )
 
+# Phi-4-mini-flash-reasoning's SambaY decoder (huggingface.co/microsoft/
+# Phi-4-mini-flash-reasoning config.json, model_type phi4flash; arXiv:2507.06607)
+# cut to 8 of its 32 layers by its own rule: published layers 0-3 (Mamba,
+# window, Mamba, window) and 16-19 (the Mamba layer that hands on its memory,
+# the full-attention layer that hands on its keys and values, a Gated Memory
+# Unit, cross attention).  Dense: whole layers here, the others on further
+# chips.  The Mamba sizes are its configuration class's defaults.
+PHI4_MINI_FLASH = DecoderConfig(
+    hidden_size=2560, num_heads=40, num_kv_heads=20, head_dim=64,
+    layers=(0, 1, 2, 3, 16, 17, 18, 19), num_hidden_layers=32, mb_per_layer=2,
+    sliding_window=512, intermediate_size=10240, rms_norm_eps=1.0e-5, short_conv_kernel=4,
+    mamba_expand=2, mamba_d_state=16, mamba_dt_rank=160,
+)
+
 # Backbone name -> the decoder blocks it holds.
-DECODER_BACKBONES = {"ling3_flash_vl": DecoderConfig(), "nemotron_twotower": NEMOTRON_TWOTOWER}
+DECODER_BACKBONES = {"ling3_flash_vl": DecoderConfig(), "nemotron_twotower": NEMOTRON_TWOTOWER,
+                     "phi4_mini_flash": PHI4_MINI_FLASH}
 
 
 @dataclass(frozen=True)
 class BackboneConfig:
-    # resnet50 | resnet101 | vgg16 | ling3_flash_vl | nemotron_twotower
+    # resnet50 | resnet101 | vgg16 | ling3_flash_vl | nemotron_twotower | phi4_mini_flash
     # (decoder blocks as a plain backbone, sized by ``decoder``)
     name: str = "resnet50"
     # Stages to freeze, counted like the reference's fixed_param_prefix
@@ -863,6 +891,18 @@ _register(
     lambda: Config(
         name="nemotron_twotower_det",
         model=_decoder_det_model("nemotron_twotower"),
+        data=DataConfig(dataset="coco"),
+        train=TrainConfig(per_device_batch=2),
+    ),
+)
+# Phi-4-mini-flash-reasoning's SambaY decoder (Mamba-1 selective scan, window
+# and full differential attention, Gated Memory Units and shared-K/V cross
+# attention; 8 of 32 layers) the same way.
+_register(
+    "phi4_mini_flash_det",
+    lambda: Config(
+        name="phi4_mini_flash_det",
+        model=_decoder_det_model("phi4_mini_flash"),
         data=DataConfig(dataset="coco"),
         train=TrainConfig(per_device_batch=2),
     ),

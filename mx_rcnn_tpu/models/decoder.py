@@ -6,9 +6,9 @@ hidden states back on the (H/16, W/16) grid -> a two-conv neck: the
 plain-backbone pattern of Li et al. (arXiv:2203.16527) without its pyramid,
 emitted as a one-entry pyramid at level 4 like VGG's.
 
-A layer is a list of pre-norm residual sub-layers, ``x <- x + f(RMSNorm(x))``,
+A layer is a list of pre-norm residual sub-layers, ``x <- x + f(norm(x))``,
 whose kinds follow from the configuration (``config.py::DecoderConfig``,
-:func:`sublayers`), two families so far.  Ling-3.0-flash-VL's: a mixer, KDA
+:func:`sublayers`), three families so far.  Ling-3.0-flash-VL's: a mixer, KDA
 linear attention (``ops/kda.py``) except in the last layer of each group of
 ``layer_group_size``, where it is latent attention (MLA,
 ``ops/attention.py``), then a feed-forward, a dense SwiGLU below
@@ -18,7 +18,14 @@ published pattern's letter a Mamba-2 state-space mixer (``ops/ssd.py``),
 grouped-query attention or the expert layer (non-gated relu^2 experts).  The
 expert layer is told which experts it holds, routes over all of them and
 computes its own experts' part; what absent experts would have added is left
-out and that partial result goes on.
+out and that partial result goes on.  Phi-4-mini-flash's SambaY
+(``mb_per_layer``; arXiv:2507.06607): LayerNorm with bias, a mixer and a plain
+SwiGLU in every layer; the mixer by the published index a Mamba-1 selective
+scan (``ops/selective_scan.py``), differential attention (arXiv:2410.05258)
+under a window or over the whole prefix, and past the middle a Gated Memory
+Unit that reads the middle Mamba layer's scan result or differential cross
+attention that reads the full-attention layer's keys and values: those three
+tensors (``shared``) pass from block to block beside ``x``.
 
 The flax module only declares the leaves (one nested name per leaf, so the
 plan's family rule, the optimizer's decay rule by leaf name and a checkpoint
@@ -42,6 +49,7 @@ from mx_rcnn_tpu.config import DecoderConfig
 from mx_rcnn_tpu.ops.attention import causal_attention
 from mx_rcnn_tpu.ops.kda import kda_chunked, short_conv
 from mx_rcnn_tpu.ops.moe import held_experts, route
+from mx_rcnn_tpu.ops.selective_scan import selective_scan_chunked
 from mx_rcnn_tpu.ops.ssd import ssd_chunked
 
 PATTERN_KINDS = {"M": "ssm", "*": "gqa", "E": "moe"}
@@ -54,9 +62,15 @@ segment_rows = None
 
 def layer_kinds(cfg: DecoderConfig, layer: int) -> tuple[str, ...]:
     """The kinds of a published layer's sub-layers, in order: a letter of
-    ``pattern``, else Ling's (mixer, feed-forward)."""
+    ``pattern``; SambaY's (mixer, feed-forward) by the layer's place against
+    the published depth's middle; else Ling's (mixer, feed-forward)."""
     if cfg.pattern:
         return (PATTERN_KINDS[cfg.pattern[layer]],)
+    if cfg.mb_per_layer:
+        middle = cfg.num_hidden_layers // 2     # the self-decoder ends at middle + 1
+        if layer % cfg.mb_per_layer == 0:
+            return ("mamba" if layer <= middle else "gmu"), "ffn"
+        return ("swa" if layer < middle else "full" if layer == middle + 1 else "xattn"), "ffn"
     mixer = "mla" if (layer + 1) % cfg.layer_group_size == 0 else "kda"
     return mixer, ("ffn" if layer < cfg.first_k_dense else "moe")
 
@@ -82,10 +96,14 @@ def leaf_spec(cfg: DecoderConfig):
     named ``kernel`` is drawn lecun-normal over all but its last axis; ``scale``
     starts at 1, ``bias`` and the router's selection bias ``e_bias`` (a
     constant, not a parameter) at 0; a state-space mixer's ``A_log``,
-    ``dt_bias`` and ``D`` as :func:`_init` says."""
+    ``dt_bias`` and ``D`` and differential attention's ``lambda`` as
+    :func:`_init` says."""
     d, h, hd = cfg.hidden_size, cfg.num_heads, cfg.head_dim
     lin = lambda i, o: (("kernel", (i, o)),)
+    affine = lambda i, o: (("kernel", (i, o)), ("bias", (o,)))
     scale = lambda n: (("scale", (n,)),)
+    # SambaY's norms are LayerNorms with a bias; the other families' RMSNorms.
+    norm = (lambda n: (("scale", (n,)), ("bias", (n,)))) if cfg.mb_per_layer else scale
     kc = cfg.short_conv_kernel
     kda = (
         ("q", lin(d, h * hd)), ("k", lin(d, h * hd)), ("v", lin(d, h * hd)),
@@ -110,6 +128,19 @@ def leaf_spec(cfg: DecoderConfig):
     )
     gqa = (("q", lin(d, h * hd)), ("k", lin(d, cfg.num_kv_heads * hd)),
            ("v", lin(d, cfg.num_kv_heads * hd)), ("o", lin(h * hd, d)))
+    wide, n1, rank = cfg.mamba_expand * d, cfg.mamba_d_state, cfg.mamba_dt_rank
+    mamba = (
+        ("in_proj", lin(d, 2 * wide)),                              # x | z
+        ("conv", (("kernel", (kc, wide)), ("bias", (wide,)))),
+        ("x_proj", lin(wide, rank + 2 * n1)),                       # delta | B | C
+        ("dt_proj", lin(rank, wide)), ("dt_bias", (wide,)),
+        ("A_log", (wide, n1)), ("D", (wide,)), ("out_proj", lin(wide, d)),
+    )
+    # lambda's rows: q1, k1, q2, k2; a cross layer projects its queries alone
+    diff = lambda qkv: (("Wqkv", affine(d, qkv)), ("lambda", (4, hd)), ("subln", scale(2 * hd)),
+                        ("out_proj", affine(h * hd, d)))
+    self_attn = diff((h + 2 * cfg.num_kv_heads) * hd)
+    gmu = (("in_proj", lin(d, wide)), ("out_proj", lin(wide, d)))
     f, act = cfg.moe_intermediate_size, cfg.expert_act
     ids = range(cfg.experts_first, cfg.experts_first + cfg.experts_count)
     moe = (
@@ -118,16 +149,17 @@ def leaf_spec(cfg: DecoderConfig):
         ("experts", tuple((f"e{e}", _mlp_spec(d, f, act)) for e in ids)),
     )
     kinds = {"kda": kda, "mla": mla, "ssm": ssm, "gqa": gqa, "moe": moe,
-             "ffn": _mlp_spec(d, cfg.intermediate_size)}
+             "mamba": mamba, "swa": self_attn, "full": self_attn, "xattn": diff(h * hd),
+             "gmu": gmu, "ffn": _mlp_spec(d, cfg.intermediate_size)}
     out = [("patchify", (("kernel", (cfg.patch, cfg.patch, 3, d)), ("bias", (d,))))]
     for layer in cfg.layers:
         out.append((f"l{layer}", tuple(
-            leaf for norm, kind in sublayers(cfg, layer)
-            for leaf in ((norm, scale(d)), (kind, kinds[kind]))
+            leaf for name, kind in sublayers(cfg, layer)
+            for leaf in ((name, norm(d)), (kind, kinds[kind]))
         )))
     c = cfg.neck_channels
     out += [
-        ("final_norm", scale(d)),
+        ("final_norm", norm(d)),
         ("neck", (("conv1", (("kernel", (1, 1, d, c)), ("bias", (c,)))),
                   ("conv2", (("kernel", (3, 3, c, c)), ("bias", (c,)))))),
     ]
@@ -144,6 +176,8 @@ def _init(name: str):
             dt = jnp.exp(jax.random.uniform(key, shape, minval=math.log(1e-3), maxval=math.log(0.1)))
             return dt + jnp.log(-jnp.expm1(-dt))
         return dt_bias
+    if name == "lambda":    # differential attention's four vectors: normal 0.1
+        return lambda key, shape: 0.1 * jax.random.normal(key, shape)
     return nn.initializers.ones if name in ("scale", "D") else nn.initializers.zeros
 
 
@@ -188,9 +222,21 @@ def _rms(x, scale, eps):
     return x * lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * scale
 
 
+def _norm(x, p, eps):
+    """LayerNorm where the leaves hold a bias, else RMSNorm."""
+    if "bias" not in p:
+        return _rms(x, p["scale"], eps)
+    x = x.astype(jnp.float32)
+    x = x - jnp.mean(x, axis=-1, keepdims=True)
+    return x * lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * p["scale"] + p["bias"]
+
+
 def _dense(x, p, dtype, out=jnp.float32):
+    """x W, plus the bias where the leaves hold one (added in float32)."""
     with jax.named_scope("dense"):
-        return jnp.dot(x.astype(dtype), p["kernel"].astype(dtype), preferred_element_type=out)
+        wide = jnp.float32 if "bias" in p else out
+        y = jnp.dot(x.astype(dtype), p["kernel"].astype(dtype), preferred_element_type=wide)
+        return (y + p["bias"]).astype(out) if "bias" in p else y
 
 
 def _mlp(x, p, dtype):
@@ -368,24 +414,145 @@ def moe_layer(cfg: DecoderConfig, p, x, dtype):
     return y.reshape(b, t, d), counters
 
 
-MIXERS = {"kda": kda_mixer, "mla": mla_mixer, "ssm": ssm_mixer, "gqa": gqa_mixer,
-          "ffn": lambda cfg, p, x, dtype: _mlp(x, p, dtype)}
+def mamba_mixer(cfg: DecoderConfig, p, x, dtype):
+    """A Mamba-1 mixer.  x (B, T, D) normed -> ((B, T, D), the scan's result
+    before the gate (B, T, expand D) float32): ``in_proj`` to x | z, the causal
+    depthwise conv with bias and SiLU over x, ``x_proj`` to delta | B | C,
+    ``dt = softplus(dt_proj(delta) + dt_bias)``, the selective scan
+    (``ops/selective_scan.py``), ``out_proj(y * silu(z))``.  As
+    :func:`kda_mixer`: ``dtype`` activations between the matmuls, the glue in
+    float32 under ``jax.checkpoint``; ``dt``, B and C stay float32."""
+    wide, n, rank = cfg.mamba_expand * cfg.hidden_size, cfg.mamba_d_state, cfg.mamba_dt_rank
+    f32 = lambda a: a.astype(jnp.float32)
+
+    @jax.checkpoint
+    def before(xs, conv):
+        return jax.nn.silu(short_conv(f32(xs), conv["kernel"]) + conv["bias"]).astype(dtype)
+
+    @jax.checkpoint
+    def step_size(delta, dt_proj, dt_bias):
+        return jax.nn.softplus(_dense(delta, dt_proj, dtype) + dt_bias)
+
+    @jax.checkpoint
+    def gate(y, z):
+        return (y * jax.nn.silu(f32(z))).astype(dtype)
+
+    with jax.named_scope("proj"):
+        xz = _dense(x, p["in_proj"], dtype, out=dtype)
+    with jax.named_scope("conv"):
+        xs = before(xz[..., :wide], p["conv"])
+    with jax.named_scope("proj"):
+        delta, b, c = jnp.split(_dense(xs, p["x_proj"], dtype), [rank, rank + n], axis=-1)
+        dt = step_size(delta, p["dt_proj"], p["dt_bias"])
+    with jax.named_scope("scan"):
+        y = selective_scan_chunked(xs, dt, -jnp.exp(p["A_log"]), b, c, p["D"])
+    with jax.named_scope("proj"):
+        return _dense(gate(y, xz[..., wide:]), p["out_proj"], dtype), y
 
 
-def _block(cfg: DecoderConfig, layer: int, dtype, p, x):
+def gmu_mixer(cfg: DecoderConfig, p, x, dtype, memory):
+    """A Gated Memory Unit: ``out_proj(silu(in_proj(x)) * memory)``, ``memory``
+    the middle Mamba layer's scan result (B, T, expand D)."""
+    @jax.checkpoint
+    def gate(u, memory):
+        return (jax.nn.silu(u.astype(jnp.float32)) * memory.astype(jnp.float32)).astype(dtype)
+
+    with jax.named_scope("proj"):
+        return _dense(gate(_dense(x, p["in_proj"], dtype, out=dtype), memory), p["out_proj"], dtype)
+
+
+def lambda_init(layer: int) -> float:
+    """Differential attention's constant part of lambda, by PUBLISHED depth."""
+    return 0.8 - 0.6 * math.exp(-0.3 * layer)
+
+
+def diff_mixer(cfg: DecoderConfig, layer: int, p, x, dtype, window=None, kv=None):
+    """Differential attention (arXiv:2410.05258), no positional encoding.  x
+    (B, T, D) normed -> ((B, T, D), (k, v)).  Query heads pair up (2j, 2j + 1)
+    into q1, q2, key heads into k1, k2, and a pair's value heads side by side
+    are ONE value of twice the width: a_i = softmax(q_i k_i^T / sqrt(hd)) v
+    under the causal mask (and ``window``), a query pair on key pair j // group;
+    out = out_proj(RMSNorm(a_1 - lambda a_2) (1 - lambda_init)), lambda =
+    exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init.  With ``kv`` (a cross
+    layer) the keys and values are another layer's, and ``Wqkv`` projects the
+    queries alone."""
+    b, t, _ = x.shape
+    h, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    first = lambda_init(layer)
+
+    @jax.checkpoint
+    def after(a1, a2, lam, scale):
+        lam = jnp.exp(jnp.sum(lam[0] * lam[1])) - jnp.exp(jnp.sum(lam[2] * lam[3])) + first
+        o = _rms(a1 - lam * a2, scale, cfg.rms_norm_eps) * (1.0 - first)
+        return o.reshape(b, t, h * hd).astype(dtype)
+
+    with jax.named_scope("proj"):
+        qkv = _dense(x, p["Wqkv"], dtype, out=dtype)
+        q = qkv[..., : h * hd].reshape(b, t, h // 2, 2, hd)
+        if kv is None:
+            kv = (qkv[..., h * hd: (h + hkv) * hd].reshape(b, t, hkv // 2, 2, hd),
+                  qkv[..., (h + hkv) * hd:].reshape(b, t, hkv // 2, 2 * hd))
+    k, v = kv
+    with jax.named_scope("attn"):
+        a1, a2 = (causal_attention(q[..., i, :], k[..., i, :], v, hd ** -0.5, dtype=dtype,
+                                   window=window) for i in (0, 1))
+    with jax.named_scope("diff"):
+        o = after(a1, a2, p["lambda"], p["subln"]["scale"])
+    with jax.named_scope("proj"):
+        return _dense(o, p["out_proj"], dtype), kv
+
+
+def _alone(mixer):
+    """A mixer that reads nothing of another layer and hands nothing on."""
+    return lambda cfg, layer, p, x, dtype, shared: (mixer(cfg, p, x, dtype), shared)
+
+
+def _mamba(cfg: DecoderConfig, layer: int, p, x, dtype, shared: dict):
+    """The middle Mamba layer hands on its scan result ``m`` (in ``dtype``)."""
+    y, m = mamba_mixer(cfg, p, x, dtype)
+    return y, dict(shared, m=m.astype(dtype)) if layer == cfg.num_hidden_layers // 2 else shared
+
+
+def _gmu(cfg: DecoderConfig, layer: int, p, x, dtype, shared: dict):
+    return gmu_mixer(cfg, p, x, dtype, shared["m"]), shared
+
+
+def _swa(cfg: DecoderConfig, layer: int, p, x, dtype, shared: dict):
+    return diff_mixer(cfg, layer, p, x, dtype, window=cfg.sliding_window)[0], shared
+
+
+def _full(cfg: DecoderConfig, layer: int, p, x, dtype, shared: dict):
+    """The full-attention layer hands on its ``k`` and ``v`` (in ``dtype``)."""
+    y, (k, v) = diff_mixer(cfg, layer, p, x, dtype)
+    return y, dict(shared, k=k, v=v)
+
+
+def _xattn(cfg: DecoderConfig, layer: int, p, x, dtype, shared: dict):
+    return diff_mixer(cfg, layer, p, x, dtype, kv=(shared["k"], shared["v"]))[0], shared
+
+
+# kind -> (cfg, layer, p, x, dtype, shared) -> (y, shared): ``shared`` holds the
+# tensors an earlier layer handed on to later ones (SambaY's ``m``, ``k``, ``v``).
+MIXERS = {"kda": _alone(kda_mixer), "mla": _alone(mla_mixer), "ssm": _alone(ssm_mixer),
+          "gqa": _alone(gqa_mixer), "ffn": _alone(lambda cfg, p, x, dtype: _mlp(x, p, dtype)),
+          "mamba": _mamba, "gmu": _gmu, "swa": _swa, "full": _full, "xattn": _xattn}
+
+
+def _block(cfg: DecoderConfig, layer: int, dtype, p, x, shared):
     """One published layer on the float32 stream: its pre-norm residual
-    sub-layers in turn.  -> (x, the expert sub-layer's counters or {})."""
+    sub-layers in turn.  -> (x, the expert sub-layer's counters or {}, the
+    tensors later layers read: ``shared`` as it came, but for two SambaY layers)."""
     counters = {}
     with jax.named_scope(f"l{layer}"):
         for norm, kind in sublayers(cfg, layer):
             with jax.named_scope(kind):
-                normed = _rms(x, p[norm]["scale"], cfg.rms_norm_eps)
+                normed = _norm(x, p[norm], cfg.rms_norm_eps)
                 if kind == "moe":
                     y, counters = moe_layer(cfg, p["moe"], normed, dtype)
                 else:
-                    y = MIXERS[kind](cfg, p[kind], normed, dtype)
+                    y, shared = MIXERS[kind](cfg, layer, p[kind], normed, dtype, shared)
                 x = x + y
-    return x, counters
+    return x, counters, shared
 
 
 def merge_counters(per_layer: list[dict]) -> dict:
@@ -418,13 +585,13 @@ def features(cfg: DecoderConfig, leaves: dict, images, dtype=jnp.bfloat16, remat
         x = conv(images, leaves["patchify"], cfg.patch, 0)
     b, gh, gw, d = x.shape
     x = x.reshape(b, gh * gw, d)
-    counters = []
+    counters, shared = [], {}
     for layer in cfg.layers:
         fn = partial(_block, cfg, layer, dtype)
-        x, c = (jax.checkpoint(fn) if remat else fn)(leaves[f"l{layer}"], x)
+        x, c, shared = (jax.checkpoint(fn) if remat else fn)(leaves[f"l{layer}"], x, shared)
         if c:
             counters.append(c)
     with jax.named_scope("neck"):
-        x = _rms(x, leaves["final_norm"]["scale"], cfg.rms_norm_eps).reshape(b, gh, gw, d)
+        x = _norm(x, leaves["final_norm"], cfg.rms_norm_eps).reshape(b, gh, gw, d)
         x = conv(conv(x, leaves["neck"]["conv1"], 1, 0), leaves["neck"]["conv2"], 1, 1)
     return {4: x.astype(dtype)}, merge_counters(counters)

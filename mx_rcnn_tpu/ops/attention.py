@@ -24,7 +24,11 @@ than the queries (grouped-query attention): a key head then serves
 ``H / Hkv`` consecutive query heads and K and V cross HBM once a key head,
 never repeated (the blocked form gives the group an axis of its own through
 both matmuls, the kernel runs the group's heads one after the other over K
-and V blocks that do not move).
+and V blocks that do not move).  Under a ``window`` a query sees itself and
+the ``window - 1`` positions before it: the oracle masks the rest, the blocked
+form takes a block's keys from the band's first position on (static ranges)
+and the kernel pair visits only the key tiles that meet the band; a window
+that holds the whole sequence is no window, in all three.
 """
 
 from __future__ import annotations
@@ -41,15 +45,18 @@ from mx_rcnn_tpu.ops.pallas import attention as attention_kernel
 BLOCK = 256
 
 
-def causal_attention_dense(q, k, v, scale: float):
+def causal_attention_dense(q, k, v, scale: float, window: int | None = None):
     """q (B, T, H, Dq), k (B, T, Hkv, Dq), v (B, T, Hkv, Dv), float32, Hkv
-    dividing H -> (B, T, H, Dv)."""
+    dividing H -> (B, T, H, Dv).  ``window``: a query sees itself and the
+    ``window - 1`` positions before it."""
     t, rep = q.shape[1], q.shape[2] // k.shape[2]
     if rep > 1:
         k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
     with jax.named_scope("dense_scores"):
         s = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision="highest") * scale
-        s = jnp.where(jnp.arange(t)[:, None] >= jnp.arange(t)[None, :], s, -jnp.inf)
+        ahead = jnp.arange(t)[:, None] - jnp.arange(t)[None, :]
+        seen = ahead >= 0 if window is None else (ahead >= 0) & (ahead < window)
+        s = jnp.where(seen, s, -jnp.inf)
         return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v, precision="highest")
 
 
@@ -59,14 +66,18 @@ def _takes_kernel(t: int, h: int, hkv: int, dk: int, dv: int, dtype) -> bool:
     return jax.default_backend() == "tpu" and attention_kernel.supported(t, h, hkv, dk, dv, dtype)
 
 
-def causal_attention(q, k, v, scale: float, block: int = BLOCK, dtype=jnp.bfloat16):
+def causal_attention(q, k, v, scale: float, block: int = BLOCK, dtype=jnp.bfloat16,
+                     window: int | None = None):
     """:func:`causal_attention_dense` with ``dtype`` operands, float32 out: the
     kernel pair where :func:`_takes_kernel` says so, else the blocked XLA form
-    (``block`` is that form's rows)."""
+    (``block`` is that form's rows).  Under a ``window`` neither form visits
+    the keys wholly before a block's (a tile's) band."""
     b, t, h, _ = q.shape
     kv = k.shape[2]
+    if window is not None and window >= t:
+        window = None       # the band holds the whole triangle
     if _takes_kernel(t, h, kv, q.shape[3], v.shape[3], dtype):
-        return attention_kernel.flash_attention(q, k, v, scale, dtype)
+        return attention_kernel.flash_attention(q, k, v, scale, dtype, window)
     q, k, v = q.astype(dtype), k.astype(dtype), v.astype(dtype)
     # With as many key heads as query heads the plain contraction; else the
     # query heads of one key head on an axis g of their own.
@@ -78,11 +89,17 @@ def causal_attention(q, k, v, scale: float, block: int = BLOCK, dtype=jnp.bfloat
     def rows(lo, hi, q, k, v):
         # The slices are taken INSIDE the checkpoint: what the backward keeps
         # is the whole q, k and v once, not a prefix of k and v a block.
-        q_b, k_b, v_b = q[:, lo:hi], k[:, :hi], v[:, :hi]
+        first = 0 if window is None else max(0, lo - window + 1)
+        q_b, k_b, v_b = q[:, lo:hi], k[:, first:hi], v[:, first:hi]
         with jax.named_scope("rows"):
             s = jnp.einsum(scores, q_b, k_b, preferred_element_type=jnp.float32) * scale
             row = lo + jnp.arange(hi - lo)
-            s = jnp.where(row[:, None] >= jnp.arange(hi)[None, :], s, -jnp.inf)
+            # (the columns' iota is written twice so that, without a window, the
+            # lowered program is to the letter what it was before windows)
+            seen = row[:, None] >= jnp.arange(first, hi)[None, :]
+            if window is not None:
+                seen &= row[:, None] - jnp.arange(first, hi)[None, :] < window
+            s = jnp.where(seen, s, -jnp.inf)
             p = jax.nn.softmax(s, axis=-1).astype(dtype)
             return jnp.einsum(values, p, v_b, preferred_element_type=jnp.float32)
 

@@ -13,7 +13,12 @@ matmuls a tile where two kernels, one for dq and one for dk and dv, take
 seven), with keys on the rows of a tile so that only dq's matmul wants a
 transposed operand.  Tiles wholly above the diagonal are never visited, the
 diagonal's are masked, and the last tile of a sequence that is no multiple
-of a tile is a narrower one whose rows past the end read as zeros.
+of a tile is a narrower one whose rows past the end read as zeros.  Under a
+``window`` (a query sees itself and the ``window - 1`` positions before it)
+the key tiles wholly before a query tile's band are never visited either and
+those that meet its leading edge are masked as the diagonal's are
+(``_band``, ``_seen``): two key tiles a query tile at a window of one tile,
+in the forward and in the backward; without a window the walk is what it was.
 
 The arithmetic is ``ops/attention.py::causal_attention``'s: operands in
 ``dtype``, scores, max, sum, log-sum-exp and every accumulator float32, the
@@ -113,18 +118,41 @@ def _loader(lo, rows, length):
         _iota((rows, ref.shape[2]), 0) < length - lo, ref[0, at, :], jnp.zeros((), ref.dtype))
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, length, tile, scale):
+def _band(window, tile: int, n: int) -> tuple[int, int]:
+    """Of the ``n`` tiles before (after) a tile's diagonal, counted from it:
+    (how many lie wholly inside the band of ``window``, how many meet it at
+    all).  Tiles a whole tile apart hold pairs k tile - (tile - 1) ..
+    k tile + (tile - 1) positions apart, and a pair is seen under window - 1."""
+    if window is None:
+        return n, n
+    return min(n, max(window // tile - 1, 0)), min(n, (window - 2) // tile + 1)
+
+
+def _seen(shape, queries: int, apart: int, window, diagonal: bool):
+    """The pairs of a tile of scores that see each other, or None where all do.
+    ``queries`` is the axis the queries lie on, ``apart`` how far the tile's
+    first query is past its first key."""
+    seen = (_iota(shape, queries) >= _iota(shape, 1 - queries)) if diagonal else None
+    if window is not None and apart + shape[queries] > window:
+        edge = _iota(shape, queries) - _iota(shape, 1 - queries) < window - apart
+        seen = edge if seen is None else seen & edge
+    return seen
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, length, tile, scale, window):
     kind = q_ref.dtype
 
     for n, (lo, rows) in enumerate(_tiles(length, tile)):
         q = _loader(lo, rows, length)(q_ref)
+        inside, met = _band(window, tile, n)
 
-        def keys(at, cols, carry, diagonal, q=q):
+        def keys(at, cols, carry, diagonal, q=q, lo=lo):
             m, l, acc = carry
             load = _loader(at, cols, length)
             s = _dot(q, load(k_ref), _NT) * scale
-            if diagonal:
-                s = jnp.where(_iota(s.shape, 0) >= _iota(s.shape, 1), s, _MASKED)
+            seen = None if not isinstance(at, int) else _seen(s.shape, 0, lo - at, window, diagonal)
+            if seen is not None:
+                s = jnp.where(seen, s, _MASKED)
             m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
             alpha = jnp.exp(m - m_new)
             # The sum is of the probabilities AS THE MATMUL TAKES THEM: o is then
@@ -137,10 +165,16 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, length, tile, scale):
 
         carry = (jnp.full((rows, 1), _MASKED, _F32), jnp.zeros((rows, 1), _F32),
                  jnp.zeros((rows, v_ref.shape[2]), _F32))
-        # the tiles wholly below the diagonal, then the diagonal's
-        if n:
+        # The tiles that meet the band's leading edge (under a window: what a row
+        # without a key there gathers is wiped by its first key's ``alpha`` = 0),
+        # the tiles wholly inside the band (every one below the diagonal without
+        # a window), then the diagonal's.
+        for j in range(n - met, n - inside):
+            carry = keys(j * tile, tile, carry, False)
+        if inside:
             carry = lax.fori_loop(
-                0, n, lambda j, c: keys(pl.multiple_of(j * tile, tile), tile, c, False), carry)
+                n - inside, n,
+                lambda j, c: keys(pl.multiple_of(j * tile, tile), tile, c, False), carry)
         m, l, acc = keys(lo, rows, carry, True)
         o_ref[0, pl.ds(lo, rows), :] = acc / l
         lse = jnp.broadcast_to(m + jnp.log(l), (rows, LANES))
@@ -148,7 +182,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, length, tile, scale):
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref, dk_ref, dv_ref,
-                dq_acc, dk_acc, dv_acc, di_ref, *, length, tile, scale, group):
+                dq_acc, dk_acc, dv_acc, di_ref, *, length, tile, scale, window, group):
     kind = q_ref.dtype
     member = pl.program_id(2)
     tiles = _tiles(length, tile)
@@ -174,14 +208,15 @@ def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref, dk_ref, dv_
         load = _loader(lo, cols, length)
         k, v = load(k_ref), load(v_ref)
 
-        def queries(i, at, rows, carry, diagonal, k=k, v=v):
+        def queries(i, at, rows, carry, diagonal, k=k, v=v, lo=lo):
             dk, dv = carry
             load = _loader(at, rows, length)
             q, do = load(q_ref), load(do_ref).astype(kind)
             # keys on the rows, queries on the lanes
             p = jnp.exp(_dot(k, q, _NT) * scale - lse_ref[0, 0, i, 0:1, pl.ds(0, rows)])
-            if diagonal:
-                p = jnp.where(_iota(p.shape, 1) >= _iota(p.shape, 0), p, 0.0)
+            seen = None if not isinstance(at, int) else _seen(p.shape, 1, at - lo, window, diagonal)
+            if seen is not None:
+                p = jnp.where(seen, p, 0.0)
             dv = dv + _dot(p.astype(kind), do, _NN)
             ds = (p * (_dot(v, do, _NT) - di_ref[i, 0:1, pl.ds(0, rows)]) * scale).astype(kind)
             dk = dk + _dot(ds, q, _NN)
@@ -190,13 +225,19 @@ def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref, dk_ref, dv_
 
         carry = (jnp.zeros((cols, k.shape[1]), _F32), jnp.zeros((cols, v.shape[1]), _F32))
         carry = queries(n, lo, cols, carry, True)
-        # the query tiles wholly below it: whole ones, then the sequence's last
-        if n + 1 < whole:
+        # the query tiles below it wholly inside the band (every one without a
+        # window): whole ones, then the sequence's last; then those that meet the
+        # band's leading edge
+        inside, met = _band(window, tile, last - n)
+        stop = min(whole, n + inside + 1)
+        if n + 1 < stop:
             carry = lax.fori_loop(
-                n + 1, whole,
+                n + 1, stop,
                 lambda i, c: queries(i, pl.multiple_of(i * tile, tile), tile, c, False), carry)
-        if n < last and whole == last:
+        if n < last and whole == last and last <= n + inside:
             carry = queries(last, *tiles[last], carry, False)
+        for i in range(n + inside + 1, n + met + 1):
+            carry = queries(i, *tiles[i], carry, False)
         dk, dv = carry
         at = pl.ds(lo, cols)
 
@@ -219,8 +260,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref, dk_ref, dv_
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
-def _call(backward, heads, scale, tile, interpret, q, k, v, *residuals):
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5))
+def _call(backward, heads, scale, tile, interpret, window, q, k, v, *residuals):
     """One of the two kernels over q (B, T, H * Dk), k (B, T, Hkv * Dk) and
     v (B, T, Hkv * Dv).  Under ``jax.jit`` so that a model's layers share one
     trace and one lowering of each kernel."""
@@ -249,7 +290,7 @@ def _call(backward, heads, scale, tile, interpret, q, k, v, *residuals):
         out_shape = [jax.ShapeDtypeStruct((b, t, h * dv), _F32), stats_shape]
         scratch, semantics = [], ("parallel", "parallel", "parallel")
     return pl.pallas_call(
-        functools.partial(kernel, length=t, tile=tile, scale=scale),
+        functools.partial(kernel, length=t, tile=tile, scale=scale, window=window),
         grid=(b, hkv, group),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -263,28 +304,29 @@ def _call(backward, heads, scale, tile, interpret, q, k, v, *residuals):
     )(q, k, v, *residuals)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _attention(q, k, v, heads, scale, tile, interpret):
-    return _call(False, heads, scale, tile, interpret, q, k, v)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _attention(q, k, v, heads, scale, tile, interpret, window):
+    return _call(False, heads, scale, tile, interpret, window, q, k, v)[0]
 
 
-def _attention_fwd(q, k, v, heads, scale, tile, interpret):
-    o, lse = _call(False, heads, scale, tile, interpret, q, k, v)
+def _attention_fwd(q, k, v, heads, scale, tile, interpret, window):
+    o, lse = _call(False, heads, scale, tile, interpret, window, q, k, v)
     return o, (q, k, v, o, lse)
 
 
-def _attention_bwd(heads, scale, tile, interpret, residuals, do):
+def _attention_bwd(heads, scale, tile, interpret, window, residuals, do):
     q, k, v, o, lse = residuals
-    return tuple(_call(True, heads, scale, tile, interpret, q, k, v, o, do, lse))
+    return tuple(_call(True, heads, scale, tile, interpret, window, q, k, v, o, do, lse))
 
 
 _attention.defvjp(_attention_fwd, _attention_bwd)
 
 
-def flash_attention(q, k, v, scale: float, dtype=jnp.bfloat16):
+def flash_attention(q, k, v, scale: float, dtype=jnp.bfloat16, window: int | None = None):
     """q (B, T, H, Dk), k (B, T, Hkv, Dk), v (B, T, Hkv, Dv), Hkv dividing H
-    -> (B, T, H, Dv) float32, as ``ops/attention.py::causal_attention``.  Off
-    the TPU the same kernels run interpreted."""
+    -> (B, T, H, Dv) float32, as ``ops/attention.py::causal_attention``
+    (``window``: a query sees itself and the ``window - 1`` positions before
+    it).  Off the TPU the same kernels run interpreted."""
     b, t, h, dk = q.shape
     hkv, dv = k.shape[2], v.shape[3]
     pad = _up(dk, LANES) - dk
@@ -294,5 +336,5 @@ def flash_attention(q, k, v, scale: float, dtype=jnp.bfloat16):
         return x.reshape(b, t, -1)
 
     o = _attention(flat(q, pad), flat(k, pad), flat(v), (h, hkv), scale, TILE,
-                   jax.default_backend() != "tpu")
+                   jax.default_backend() != "tpu", window)
     return o.reshape(b, t, h, dv)

@@ -155,19 +155,22 @@ def quantize_network(variables) -> dict:
 
     def one(path, leaf):
         keys = _path_keys(path)
-        if "router" in keys or "kda" in keys or "ssm" in keys:
+        if any(k in keys for k in ("router", "kda", "ssm", "mamba", "lambda")):
             # A decoder backbone (models/decoder.py): int8 weights move the
-            # router's top-k picks, the KDA decay gate and a state-space
-            # scan's decays (A_log, dt_bias, D, the conv and the projection
-            # dt comes from), and the repo has no calibration or parity check
-            # for any of them.
+            # router's top-k picks, the KDA decay gate, a state-space scan's
+            # decays (A_log, dt_bias, D, the conv and the projections dt, B
+            # and C come from: a Mamba-2 ``ssm`` mixer's in_proj, a Mamba-1
+            # ``mamba`` mixer's x_proj and dt_proj) and differential
+            # attention's lambda, and the repo has no calibration or parity
+            # check for any of them.
             raise NotImplementedError(
                 "full-network int8 (the full_q8n level) is not defined for a "
                 f"decoder backbone: leaf {'/'.join(map(str, keys))!r} belongs to an "
-                "expert router, a KDA mixer or a state-space (ssm) mixer, whose "
-                "selection, decay gate and scan (A_log, dt_bias, D, its conv) "
-                "need a calibrated quantization this repo does not have; "
-                "serve it at the float levels"
+                "expert router, a KDA mixer, a state-space (ssm, mamba) mixer or "
+                "differential attention's lambda, whose selection, decay gate, "
+                "scan (A_log, dt_bias, D, x_proj, dt_proj, its conv) and "
+                "difference need a calibrated quantization this repo does not "
+                "have; serve it at the float levels"
             )
         leaf = jnp.asarray(leaf)
         if keys and keys[0] == "params" and keys[-1] == "kernel" \

@@ -75,7 +75,7 @@ def frozen_mask(params, freeze_prefixes: tuple[str, ...]) -> dict:
 
 
 # Leaf names (anywhere on a leaf's path) that weight decay skips.
-NO_DECAY = ("bias", "scale", "A_log", "dt_bias", "D")
+NO_DECAY = ("bias", "scale", "A_log", "dt_bias", "D", "lambda")
 
 
 def make_optimizer(
@@ -89,7 +89,8 @@ def make_optimizer(
     Weight decay skips biases and norm scales (standard detection recipe;
     the reference applies wd uniformly but modern schedules that hit the
     BASELINE north star do not) and a state-space mixer's ``A_log``,
-    ``dt_bias`` and ``D`` (``NO_DECAY``: the Mamba family's recipe).
+    ``dt_bias`` and ``D`` (``NO_DECAY``: the Mamba family's recipe) and
+    differential attention's ``lambda`` vectors.
     """
     schedule = make_schedule(cfg.schedule, lr_scale)
 

@@ -52,6 +52,11 @@ COMPONENT_PATTERNS: tuple[tuple[str, tuple[str, ...]], ...] = (
     ("MLA", ("/mla/",)),
     ("SSM", ("/ssm/",)),
     ("GQA", ("/gqa/",)),
+    ("Mamba", ("/mamba/",)),
+    ("SWA", ("/swa/",)),
+    ("full-attn", ("/full/",)),
+    ("cross-attn", ("/xattn/",)),
+    ("GMU", ("/gmu/",)),
     ("MoE", ("/moe/",)),
     ("dense-FFN", ("/ffn/",)),
     ("neck", ("backbone/neck",)),

@@ -54,7 +54,15 @@ is a true barrier.
   32 with 128-wide values, vs the blocked XLA form it replaces on the TPU:
   float32 at ``highest`` both sides, bfloat16 of both against that float32,
   with the measured time of each form forward and forward + backward; and at
-  a length whose last tile is narrower and ends at T exactly (2,304).
+  a length whose last tile is narrower and ends at T exactly (2,304); and at
+  the SambaY cell's shape (20 query pairs on 10 key pairs, keys 64, values
+  128) over the whole prefix and under the window of 512, the windowed kernel
+  also against the dense oracle.
+
+- ``selective_scan[phi4_mini_flash_det.train_coco]`` — the chunked selective
+  scan (``ops/selective_scan.py``, plain XLA) alone at the SambaY cell's shape
+  vs the plain reference's token-by-token recurrence, with the measured time
+  forward and forward + backward.
 
 Run directly: python tests/_kernels_tpu_worker.py [word ...] (only the
 probes whose name holds one of the words)
@@ -510,7 +518,7 @@ def probe_kda_intra(b, t, h):
             "chunks_per_step": kernel._chunks_per_step(-(-t // kernel.CHUNK))}
 
 
-def probe_flash_attention(b, t, h, hkv, dk, dv):
+def probe_flash_attention(b, t, h, hkv, dk, dv, window=None):
     """Causal attention as the Pallas kernel pair (``ops/pallas/attention.py``:
     ``flash_attention_fwd``, ``flash_attention_bwd``) at a decoder cell's
     shape: Mosaic compiles both; result and the three gradients in float32
@@ -526,7 +534,10 @@ def probe_flash_attention(b, t, h, hkv, dk, dv):
     is the small remainder (``dq_alike``, read on that head alone: the number
     that caught a kernel whose sum(o * do) saw another rounding of do than its
     dp, PERF.md section 6, PR 33); query head 1 is ten times the size (a
-    softmax near one-hot: the running max moves at every tile)."""
+    softmax near one-hot: the running max moves at every tile).  Under a
+    ``window`` both forms take it, and the kernel in float32 is also held to
+    the DENSE oracle (the whole masked score matrix at ``highest``) on the
+    first two key heads and their query heads (``rel_l2_f32_kernel_vs_dense``)."""
     import jax
     import jax.numpy as jnp
 
@@ -552,12 +563,12 @@ def probe_flash_attention(b, t, h, hkv, dk, dv):
         def fn(*a):
             held, attention._takes_kernel = attention._takes_kernel, lambda *shape: takes_kernel
             try:
-                return attention.causal_attention(*a, scale, dtype=dtype)
+                return attention.causal_attention(*a, scale, dtype=dtype, window=window)
             finally:
                 attention._takes_kernel = held
         return fn
 
-    def with_grads(fn):
+    def with_grads(fn, cot=cot):
         loss = lambda *a: (jnp.sum(fn(*a) * cot), fn(*a))
         return jax.jit(lambda *a: jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(*a))
 
@@ -576,6 +587,13 @@ def probe_flash_attention(b, t, h, hkv, dk, dv):
             for name, takes in (("kernel", True), ("xla", False))}
     finite = all(bool(jnp.isfinite(x).all()) for x in with_grads(form(True, jnp.bfloat16))(*args)[1])
     del want
+    dense = None
+    if window is not None:
+        few = 2 * (h // hkv)
+        part = (args[0][:, :, :few], args[1][:, :, :2], args[2][:, :, :2])
+        oracle = lambda *a: attention.causal_attention_dense(*a, scale, window=window)
+        dense = readings(with_grads(form(True, jnp.float32), cot[:, :, :few])(*part),
+                         with_grads(oracle, cot[:, :, :few])(*part))
     narrow = tuple(x.astype(jnp.bfloat16) for x in args)
     ms = {}
     for name, takes in (("kernel", True), ("xla", False)):
@@ -590,11 +608,72 @@ def probe_flash_attention(b, t, h, hkv, dk, dv):
     # head's dq, a remainder a hundred times smaller than its terms (a kernel
     # that breaks the cancellation reads ten to two hundred times).
     # ``dq_alike`` in float32 is a small difference of large sums on both sides.
-    ok = (finite and all(x < (1e-2 if n == "dq_alike" else 1e-4) for n, x in wide.items())
+    near = lambda found: all(x < (1e-2 if n == "dq_alike" else 1e-4) for n, x in found.items())
+    ok = (finite and near(wide) and (dense is None or near(dense))
           and all(x <= (2.0 if n == "dq_alike" else 1.2) * half["xla"][n]
                   for n, x in half["kernel"].items()))
-    return {"ok": ok, "rel_l2_f32_kernel_vs_xla": wide, "rel_l2_bf16_vs_f32": half, **ms,
-            "shape": [b, t, h, hkv, dk, dv], "tile": kernel.TILE}
+    out = {"ok": ok, "rel_l2_f32_kernel_vs_xla": wide, "rel_l2_bf16_vs_f32": half, **ms,
+           "shape": [b, t, h, hkv, dk, dv], "tile": kernel.TILE}
+    if window is not None:
+        out.update(window=window, rel_l2_f32_kernel_vs_dense=dense)
+    return out
+
+
+def probe_selective_scan(b, t):
+    """The chunked selective scan (``ops/selective_scan.py::selective_scan_chunked``,
+    plain XLA) alone at the SambaY cell's shape, x ``bf16[2, 4200, 5120]``, dt
+    ``f32[2, 4200, 5120]``, B and C ``f32[2, 4200, 16]``, chunk 128: result and
+    the six gradients against the float32 token-by-token oracle (the plain
+    reference's ``recurrence``, a scan of checkpointed scans), and the time
+    forward and forward + backward.  The channels hold the ends of the ranges:
+    channel 0 ``dt`` 1e-3 with A 1 (a chunk keeps 88 % of its state: the carry
+    is everything), channel 1 ``dt`` 8 with A 1-16 (forgets within a token:
+    the exponents a quotient form would overflow on), channel 2 sees tokens
+    that are one value but for 5 % (a flat image's); the rest draw ``dt``
+    log-uniform in 1e-3..0.5 and A uniform in 1..16."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mx_rcnn_tpu.ops.selective_scan import CHUNK, selective_scan_chunked
+    from perfbench.reference.backbone_phi4_mini_flash import recurrence
+
+    ch, n = 5120, 16
+    ks = jax.random.split(jax.random.PRNGKey(34), 8)
+    x = jax.random.normal(ks[0], (b, t, ch))
+    x = x.at[:, :, 2].set(1.0 + 0.05 * jax.random.normal(ks[6], (b, t)))
+    bm, cm = jax.random.normal(ks[1], (b, t, n)), jax.random.normal(ks[2], (b, t, n))
+    lo, hi = np.log(1e-3), np.log(0.5)
+    dt = jnp.exp(lo + (hi - lo) * jax.random.uniform(ks[3], (b, t, ch)))
+    dt = dt.at[:, :, 0].set(1e-3).at[:, :, 1].set(8.0)
+    a = -jax.random.uniform(ks[4], (ch, n), minval=1.0, maxval=16.0).at[0].set(1.0)
+    d = jax.random.uniform(ks[5], (ch,), minval=0.7, maxval=1.0)
+    x = x.astype(jnp.bfloat16).astype(jnp.float32)      # what the mixer hands over
+    args = (x, dt, a, bm, cm, d)
+    cot = jax.random.normal(jax.random.PRNGKey(35), (b, t, ch))
+    rel = lambda got, want: float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+    names = ("dx", "ddt", "da", "db", "dc", "dd")
+
+    def with_grads(fn):
+        loss = lambda *m: (jnp.sum(fn(*m) * cot), fn(*m))
+        return jax.jit(lambda *m: jax.value_and_grad(loss, argnums=range(6), has_aux=True)(*m))
+
+    def oracle(x, dt, a, bm, cm, d):
+        one = lambda x, dt, bm, cm: recurrence(x, dt, a, bm, cm)
+        return jax.lax.map(lambda m: one(*m), (x, dt, bm, cm)) + d * x
+
+    want = with_grads(oracle)(*args)
+    got = with_grads(selective_scan_chunked)(*args)
+    out = {"y": rel(got[0][1], want[0][1])}
+    out.update({name: rel(u, v) for name, u, v in zip(names, got[1], want[1])})
+    finite = all(bool(jnp.isfinite(u).all()) for u in got[1])
+    del got, want
+    narrow = (x.astype(jnp.bfloat16),) + args[1:]
+    ms = {"chunked_ms": _least_ms(jax.jit(selective_scan_chunked), *narrow),
+          "chunked_fwd_bwd_ms": _least_ms(with_grads(selective_scan_chunked), *narrow)}
+    # float32 on both sides, sums in another order
+    ok = finite and all(v < 1e-4 for v in out.values())
+    return {"ok": ok, "rel_l2_vs_recurrence": out, **ms, "shape": [b, t, ch, n], "chunk": CHUNK}
 
 
 def probe_ssd(b, t):
@@ -790,6 +869,13 @@ PROBES = (
     # ends at T exactly, the case a backward's loop over whole tiles must stop short of
     ("flash_attention[768x768,last_tile_narrow]",
      probe_flash_attention, (1, 2304, 4, 2, 128, 128)),
+    # one map of the SambaY cell's differential attention: 20 query pairs on 10 key
+    # pairs, keys of 64 (padded to 128), values of 128; the whole prefix, then the window
+    ("flash_attention[phi4_mini_flash_det.train_coco,full]",
+     probe_flash_attention, (2, 4200, 20, 10, 64, 128)),
+    ("flash_attention[phi4_mini_flash_det.train_coco,window512]",
+     probe_flash_attention, (2, 4200, 20, 10, 64, 128, 512)),
+    ("selective_scan[phi4_mini_flash_det.train_coco]", probe_selective_scan, (2, 4200)),
 )
 
 

@@ -16,6 +16,7 @@ from mx_rcnn_tpu.ops.pallas import attention as kernel
 
 GQA = (32, 2, 128, 128)      # nemotron_twotower_det: heads, key heads, Dk, Dv
 MLA = (32, 32, 192, 128)     # ling3_flash_vl_det
+DIFF = (20, 10, 64, 128)     # phi4_mini_flash_det: one map of the differential attention
 TILE = 128                   # the least the kernels take: several tiles in 300 positions
 
 
@@ -214,24 +215,29 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("heads,t,dtype", [(GQA, 4200, jnp.bfloat16), (MLA, 4200, jnp.bfloat16),
-                                           ((4, 2, 128, 128), 1100, jnp.float32),
-                                           ((4, 2, 128, 128), 2304, jnp.bfloat16)],
-                         ids=["nemotron_twotower_det", "ling3_flash_vl_det", "float32",
-                              "768x768_last_tile_narrow"])
-def test_mosaic_compiles_both_kernels_at_the_decoder_cells_shapes(one_chip, heads, t, dtype):
+@pytest.mark.parametrize("heads,t,dtype,window", [
+    (GQA, 4200, jnp.bfloat16, None), (MLA, 4200, jnp.bfloat16, None),
+    ((4, 2, 128, 128), 1100, jnp.float32, None), ((4, 2, 128, 128), 2304, jnp.bfloat16, None),
+    (DIFF, 4200, jnp.bfloat16, None), (DIFF, 4200, jnp.bfloat16, 512),
+    ((4, 2, 128, 128), 2304, jnp.bfloat16, 700),
+], ids=["nemotron_twotower_det", "ling3_flash_vl_det", "float32", "768x768_last_tile_narrow",
+        "phi4_mini_flash_det_full", "phi4_mini_flash_det_window", "window_of_no_whole_tiles"])
+def test_mosaic_compiles_both_kernels_at_the_decoder_cells_shapes(one_chip, heads, t, dtype, window):
     """q ``[2, 4200, 32 * 128]`` on k ``[2, 4200, 2 * 128]``, and q, k
     ``[2, 4200, 32 * 256]`` (192 padded) on v ``[2, 4200, 32 * 128]``, at the
     program's tile; float32 operands (six passes a matmul) at a shorter
     sequence; a 768 x 768 canvas's 2,304 positions, whose last tile is narrower
-    and ends at T exactly.  Compiled for a described v5e, run nowhere."""
+    and ends at T exactly; one map of the differential attention (20 query
+    pairs on 10 key pairs, keys 64 padded to 128, values 128) over the whole
+    prefix and under the window of 512; a window that is no multiple of a
+    tile.  Compiled for a described v5e, run nowhere."""
     b = 2
     h, hkv, dk, dv = heads
     dk = -(-dk // kernel.LANES) * kernel.LANES
     spec = lambda width: jax.ShapeDtypeStruct((b, t, width), dtype, sharding=one_chip)
 
     def loss(q, k, v):
-        return jnp.sum(kernel._attention(q, k, v, (h, hkv), dk ** -0.5, kernel.TILE, False))
+        return jnp.sum(kernel._attention(q, k, v, (h, hkv), dk ** -0.5, kernel.TILE, False, window))
 
     compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
         spec(h * dk), spec(hkv * dk), spec(hkv * dv)).compile()
