@@ -14,6 +14,11 @@ same tests cover both backends.
   ``flash_attention_fwd`` / ``flash_attention_bwd`` (taken by
   ``ops/attention.py::causal_attention`` on a TPU; oracles
   ``causal_attention_dense`` and the blocked XLA form).
+- :mod:`.selective_scan` — the Mamba-1 selective scan with its state in VMEM,
+  ``selective_scan_fwd`` / ``selective_scan_bwd`` (taken by
+  ``ops/selective_scan.py::selective_scan_chunked`` on a TPU; oracles
+  ``selective_scan_recurrent``, token by token, and the chunked XLA form,
+  itself held to that recurrence).
 """
 
 from mx_rcnn_tpu.ops.pallas.roi_align import (

@@ -1,5 +1,5 @@
 """The Mamba-1 selective scan (a decay per channel AND state, input-dependent
-``dt``, ``B`` and ``C``) as a chunked scan in plain XLA.
+``dt``, ``B`` and ``C``).  Three forms of one function:
 
 Per channel ``c`` of the inner width and state ``n``, with ``A[c, n] < 0``,
 ``dt_t[c] > 0`` and the position's ``B_t``, ``C_t`` (N,):
@@ -9,26 +9,39 @@ Per channel ``c`` of the inner width and state ``n``, with ``A[c, n] < 0``,
 
 The decay differs per channel and state, so a chunk has no matmul form
 (``ops/ssd.py``'s needs one scalar a head): the work is elementwise over
-(T, channels, N).  :func:`selective_scan_recurrent` is the recurrence token by
-token (the oracle).  :func:`selective_scan_chunked` cuts the positions into
-chunks of ``chunk``:
+(T, channels, N).
 
-- ``intra``: the recurrence from a ZERO state inside every chunk at once, one
-  position of all chunks a step (``chunk`` steps over a (B, chunks, N, channels)
-  state instead of T steps over a (B, N, channels) one: the same bytes, a
-  thirty-third of the steps).  Its backward keeps the state every few steps
-  and recomputes between (:func:`_scan_in_blocks`), never a chunk's history.
-- ``inter``: the chunks one after the other, carrying the (B, N, channels)
-  state: a chunk's incoming state decayed to each of its positions gives the
-  rest of ``y``, and decayed to the chunk's end, plus what the chunk added,
-  is the next chunk's.  The step is under ``jax.checkpoint``: the backward
-  keeps the states at the chunk boundaries alone and recomputes a chunk.
+- :func:`selective_scan_recurrent` - the recurrence token by token in plain
+  XLA: the oracle of both others.
+- the chunked XLA form (:func:`selective_scan_chunked` off the TPU and at every
+  shape the kernel is not written for; the kernel's second oracle, the same
+  arithmetic in another order of sums).  It cuts the positions into chunks of
+  ``chunk``:
 
-Every exponent is ``A`` times a sum of ``dt`` over positions of one chunk,
-<= 0: no quotient of cumulative products (``dt A`` reaches -8 a token).  Rows
-past ``T`` in the last chunk are neutral (``dt`` = 0: no decay, no input).
-Everything is float32 whatever the inputs' types; channels ride the lanes.
-The backward is autodiff.
+  - ``intra``: the recurrence from a ZERO state inside every chunk at once, one
+    position of all chunks a step (``chunk`` steps over a (B, chunks, N,
+    channels) state instead of T steps over a (B, N, channels) one: the same
+    bytes, a thirty-third of the steps).  Its backward keeps the state every
+    few steps and recomputes between (:func:`_scan_in_blocks`), never a
+    chunk's history.
+  - ``inter``: the chunks one after the other, carrying the (B, N, channels)
+    state: a chunk's incoming state decayed to each of its positions gives the
+    rest of ``y``, and decayed to the chunk's end, plus what the chunk added,
+    is the next chunk's.  The step is under ``jax.checkpoint``: the backward
+    keeps the states at the chunk boundaries alone and recomputes a chunk.
+
+  Every state crosses HBM once a step of either scan; the backward is autodiff.
+- the Pallas kernel pair (``ops/pallas/selective_scan.py``;
+  :func:`selective_scan_chunked` on a TPU where ``supported`` says the shapes
+  are the kernel's): the positions in order with the (N, channels) state in
+  VMEM, no ``intra`` / ``inter`` split; a hand-written backward that walks the
+  chunks in reverse from the states kept at their boundaries.
+
+In all three every exponent is ``A`` times a sum of ``dt`` over positions of
+one chunk at most, <= 0: no quotient of cumulative products (``dt A`` reaches
+-8 a token).  Rows past ``T`` in the last chunk are neutral (``dt`` = 0: no
+decay, no input).  Everything is float32 whatever the inputs' types; channels
+ride the lanes.
 """
 
 from __future__ import annotations
@@ -38,6 +51,8 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from mx_rcnn_tpu.ops.pallas import selective_scan as scan_kernel
 
 HI = lax.Precision.HIGHEST
 # Positions per chunk: a program choice beside ``ssd.py::CHUNK``, not an option
@@ -76,10 +91,20 @@ def _scan_in_blocks(step, init, xs):
     return h, y.reshape((length,) + y.shape[2:])
 
 
+def _takes_kernel(t: int, channels: int, n: int, chunk: int) -> bool:
+    """The Pallas kernel pair runs where there is a TPU to run it and the
+    shapes are the ones it is written for."""
+    return jax.default_backend() == "tpu" and scan_kernel.supported(t, channels, n, chunk)
+
+
 def selective_scan_chunked(x, dt, a, b, c, d, chunk: int = CHUNK):
-    """Chunked form of :func:`selective_scan_recurrent`; y is float32."""
+    """Chunked form of :func:`selective_scan_recurrent`; y is float32.  The
+    kernel pair where :func:`_takes_kernel` says so, else the XLA form
+    (``chunk`` is that form's; the kernel's is its own, the same)."""
     bt, t, ch = x.shape
     n = a.shape[1]
+    if _takes_kernel(t, ch, n, chunk):
+        return scan_kernel.selective_scan(x, dt, a, b, c, d)
     nc = -(-t // chunk)
     f32 = jnp.float32
     a_t = a.astype(f32).T                                   # (N, C): channels on the lanes

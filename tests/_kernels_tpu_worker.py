@@ -59,10 +59,11 @@ is a true barrier.
   128) over the whole prefix and under the window of 512, the windowed kernel
   also against the dense oracle.
 
-- ``selective_scan[phi4_mini_flash_det.train_coco]`` — the chunked selective
-  scan (``ops/selective_scan.py``, plain XLA) alone at the SambaY cell's shape
-  vs the plain reference's token-by-token recurrence, with the measured time
-  forward and forward + backward.
+- ``selective_scan[phi4_mini_flash_det.train_coco]`` — the selective scan
+  (``ops/selective_scan.py``) alone at the SambaY cell's shape, as the Pallas
+  kernel pair (``selective_scan_fwd`` / ``selective_scan_bwd``) and as the
+  chunked XLA form, each vs the plain reference's token-by-token recurrence,
+  with the measured time of each forward and forward + backward.
 
 Run directly: python tests/_kernels_tpu_worker.py [word ...] (only the
 probes whose name holds one of the words)
@@ -620,12 +621,14 @@ def probe_flash_attention(b, t, h, hkv, dk, dv, window=None):
 
 
 def probe_selective_scan(b, t):
-    """The chunked selective scan (``ops/selective_scan.py::selective_scan_chunked``,
-    plain XLA) alone at the SambaY cell's shape, x ``bf16[2, 4200, 5120]``, dt
+    """The selective scan (``ops/selective_scan.py::selective_scan_chunked``) in
+    both its forms - the chunked XLA form and the Pallas kernel pair
+    (``ops/pallas/selective_scan.py``, what ``selective_scan_chunked`` takes on
+    a TPU) - alone at the SambaY cell's shape, x ``bf16[2, 4200, 5120]``, dt
     ``f32[2, 4200, 5120]``, B and C ``f32[2, 4200, 16]``, chunk 128: result and
-    the six gradients against the float32 token-by-token oracle (the plain
-    reference's ``recurrence``, a scan of checkpointed scans), and the time
-    forward and forward + backward.  The channels hold the ends of the ranges:
+    the six gradients of each against the float32 token-by-token oracle (the
+    plain reference's ``recurrence``, a scan of checkpointed scans), and each
+    one's time forward and forward + backward.  The channels hold the ends of the ranges:
     channel 0 ``dt`` 1e-3 with A 1 (a chunk keeps 88 % of its state: the carry
     is everything), channel 1 ``dt`` 8 with A 1-16 (forgets within a token:
     the exponents a quotient form would overflow on), channel 2 sees tokens
@@ -635,6 +638,8 @@ def probe_selective_scan(b, t):
     import jax.numpy as jnp
     import numpy as np
 
+    from mx_rcnn_tpu.ops import selective_scan as scan
+    from mx_rcnn_tpu.ops.pallas import selective_scan as kernel
     from mx_rcnn_tpu.ops.selective_scan import CHUNK, selective_scan_chunked
     from perfbench.reference.backbone_phi4_mini_flash import recurrence
 
@@ -662,18 +667,32 @@ def probe_selective_scan(b, t):
         one = lambda x, dt, bm, cm: recurrence(x, dt, a, bm, cm)
         return jax.lax.map(lambda m: one(*m), (x, dt, bm, cm)) + d * x
 
+    def xla_form(*m):       # the path off the TPU, whatever the backend
+        takes, scan._takes_kernel = scan._takes_kernel, lambda *_: False
+        try:
+            return selective_scan_chunked(*m)
+        finally:
+            scan._takes_kernel = takes
+
+    assert scan._takes_kernel(t, ch, n, CHUNK), "the cell's shape is one the kernel pair takes"
+    forms = {"chunked": xla_form, "kernel": selective_scan_chunked}
     want = with_grads(oracle)(*args)
-    got = with_grads(selective_scan_chunked)(*args)
-    out = {"y": rel(got[0][1], want[0][1])}
-    out.update({name: rel(u, v) for name, u, v in zip(names, got[1], want[1])})
-    finite = all(bool(jnp.isfinite(u).all()) for u in got[1])
-    del got, want
     narrow = (x.astype(jnp.bfloat16),) + args[1:]
-    ms = {"chunked_ms": _least_ms(jax.jit(selective_scan_chunked), *narrow),
-          "chunked_fwd_bwd_ms": _least_ms(with_grads(selective_scan_chunked), *narrow)}
-    # float32 on both sides, sums in another order
-    ok = finite and all(v < 1e-4 for v in out.values())
-    return {"ok": ok, "rel_l2_vs_recurrence": out, **ms, "shape": [b, t, ch, n], "chunk": CHUNK}
+    found, ms, finite = {}, {}, True
+    for name, form in forms.items():
+        got = with_grads(form)(*args)
+        found[name] = {"y": rel(got[0][1], want[0][1])}
+        found[name].update({k: rel(u, v) for k, u, v in zip(names, got[1], want[1])})
+        finite = finite and all(bool(jnp.isfinite(u).all()) for u in got[1])
+        del got
+        ms[name + "_ms"] = _least_ms(jax.jit(form), *narrow)
+        ms[name + "_fwd_bwd_ms"] = _least_ms(with_grads(form), *narrow)
+    # float32 on all sides, sums in another order
+    ok = finite and all(v < 1e-4 for one in found.values() for v in one.values())
+    return {"ok": ok, "rel_l2_vs_recurrence": found["chunked"],
+            "rel_l2_kernel_vs_recurrence": found["kernel"], **ms, "shape": [b, t, ch, n],
+            "chunk": CHUNK, "kernel_chunk": kernel.CHUNK, "kernel_block": kernel.BLOCK,
+            "kernel_unroll": kernel.UNROLL}
 
 
 def probe_ssd(b, t):
