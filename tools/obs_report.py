@@ -35,6 +35,14 @@ Outputs:
   ``drain``, ``checkpoint``, ``jit.trace``, ``jit.lower``,
   ``jit.compile``, the serving spans): count,
   total, median and longest, in milliseconds.
+* the report's ``device_parts`` (with ``--profile-dir``, where the XPlane
+  holds the program's ``Hlo Proto``) — the profiled steps' device time by
+  part, pass and owner, from the benchmark's own classification
+  (``perfbench/step_parts.py``): ms a step by kind x part x pass
+  (``["kda", "glue", "bwd", 41.2]``), the same by layer, XLA's own copies
+  by the layer that owns them, and the totals the per-layer metrics
+  ``mixer_proj_ms``, ``mixer_glue_ms``, ``remat_ms``, ``xla_copy_ms`` and
+  ``unowned_share`` read from the same rows (docs/observability.md).
 
 Usage:
     python tools/obs_report.py --obs-dir /tmp/run/obs \\
@@ -297,6 +305,27 @@ def merge_profile(profile_dir: str) -> list[dict]:
     return events
 
 
+def device_parts(profile_dir: str):
+    """The newest XPlane under ``profile_dir`` by part, pass and owner
+    (``perfbench/step_parts.py``, the benchmark's own reduction): ms a step
+    by kind x part x pass, the same by layer, XLA's copies by owner, and the
+    totals the benchmark's per-layer metrics read from the same rows.
+    None where the directory holds no XPlane or the XPlane no ``Hlo Proto``."""
+    planes = sorted(
+        glob.glob(os.path.join(profile_dir, "plugins", "profile", "*", "*.xplane.pb"))
+    ) or sorted(glob.glob(os.path.join(profile_dir, "*.xplane.pb")))
+    if not planes:
+        return None
+    from perfbench import step_parts
+
+    reading = step_parts.reading_of_xplane(planes[-1])
+    table = step_parts.table(reading)       # None: a CPU's profile, no Hlo Proto
+    if table is None:
+        return None
+    return dict(table, xplane=os.path.abspath(planes[-1]),
+                program=reading["program_name"])
+
+
 def build_report(
     obs_dir: str, stage_logs: tuple[str, ...] = ()
 ) -> tuple[dict, list[dict]]:
@@ -412,6 +441,13 @@ def main(argv=None) -> int:
         # The merged window replaces the raw span lines in the trace file:
         # they sit on another axis (the process's monotonic clock).
         spans = merged or spans
+        parts = device_parts(args.profile_dir)
+        if parts is None:
+            # The report stays byte for byte what it was without the section.
+            print("[obs_report] device_parts: none (no XPlane with an Hlo "
+                  "Proto under --profile-dir)", file=sys.stderr)
+        else:
+            report["device_parts"] = parts
     if trace_out != "none" and spans:
         with open(trace_out, "w") as f:
             json.dump({"traceEvents": spans}, f)
