@@ -19,6 +19,9 @@ same tests cover both backends.
   ``ops/selective_scan.py::selective_scan_chunked`` on a TPU; oracles
   ``selective_scan_recurrent``, token by token, and the chunked XLA form,
   itself held to that recurrence).
+- :mod:`.ssd` — the Mamba-2 chunked scan with its decay matrices and state in
+  VMEM, ``ssd_fwd`` / ``ssd_bwd`` (taken by ``ops/ssd.py::ssd_chunked`` on a
+  TPU; oracles ``ssd_recurrent``, token by token, and the chunked XLA form).
 """
 
 from mx_rcnn_tpu.ops.pallas.roi_align import (

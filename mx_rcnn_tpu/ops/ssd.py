@@ -25,7 +25,18 @@ quotient of cumulative products would put ``exp(-G_s)`` up to e^200 beside
 cotangents under float32's range.  Rows past ``T`` in the last chunk are
 neutral (``dt`` = 0: no decay, no input).  ``dt``, the decays, the cumulative
 sums and the state are float32; the matmuls take ``dtype`` operands and
-accumulate in float32.  The backward is autodiff.
+accumulate in float32.
+
+Two paths compute :func:`ssd_chunked`, with the same arithmetic:
+
+- the XLA form below (off the TPU and at every shape the kernel is not
+  written for; the kernel's second oracle): all chunks at once, the decay
+  matrices and the state through HBM.  Its backward is autodiff.
+- the Pallas kernel pair (``ops/pallas/ssd.py``, ``ssd_fwd`` / ``ssd_bwd``;
+  :func:`ssd_chunked` on a TPU where ``supported`` says the shapes are the
+  kernel's): a chunk a grid step with the decay matrices, ``C B^T`` and the
+  state in VMEM.  Its backward is written by hand under a ``jax.custom_vjp``
+  and walks the chunks in reverse from the states kept at their boundaries.
 """
 
 from __future__ import annotations
@@ -33,6 +44,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from mx_rcnn_tpu.ops.pallas import ssd as ssd_kernel
 
 HI = lax.Precision.HIGHEST
 # Positions per chunk: the published ``chunk_size``; a program choice, not an
@@ -59,10 +72,21 @@ def ssd_recurrent(x, dt, a, b, c, d):
     return jnp.moveaxis(y, 0, 1) + d[:, None] * x
 
 
+def _takes_kernel(t: int, heads: int, head_dim: int, groups: int, state: int, chunk: int) -> bool:
+    """The Pallas kernel pair runs where there is a TPU to run it and the
+    shapes are the ones it is written for."""
+    return jax.default_backend() == "tpu" and ssd_kernel.supported(
+        t, heads, head_dim, groups, state, chunk)
+
+
 def ssd_chunked(x, dt, a, b, c, d, chunk: int = CHUNK, dtype=jnp.bfloat16):
-    """Chunked form of :func:`ssd_recurrent`; y is float32."""
+    """Chunked form of :func:`ssd_recurrent`; y is float32.  The kernel pair
+    where :func:`_takes_kernel` says so, else the XLA form (``chunk`` is that
+    form's; the kernel's is its own, the same)."""
     bt, t, h, p = x.shape
     g, n = b.shape[2:]
+    if _takes_kernel(t, h, p, g, n, chunk):
+        return ssd_kernel.ssd(x, dt, a, b, c, d, dtype=dtype)
     r = h // g
     nc = -(-t // chunk)
     f32 = jnp.float32
