@@ -47,7 +47,7 @@ class DecoderConfig:
     experts the ``experts_count`` from ``experts_first`` on; the router keeps
     its published width.
 
-    Three families' patterns fit.  ``pattern`` empty: Ling's rule, every layer
+    Four families' patterns fit.  ``pattern`` empty: Ling's rule, every layer
     a mixer and a feed-forward (``layer_group_size``, ``first_k_dense``).
     ``pattern`` given (``NEMOTRON_TWOTOWER``): one letter a PUBLISHED layer,
     each layer ONE sub-layer - ``M`` a Mamba-2 state-space mixer (``ssm_*``),
@@ -58,7 +58,13 @@ class DecoderConfig:
     others differential attention under ``sliding_window`` below L/2, over the
     whole prefix at L/2 + 1, and cross attention to that layer's keys and
     values after; a dense SwiGLU (``intermediate_size``) in every layer,
-    LayerNorm with bias (``rms_norm_eps`` is its epsilon)."""
+    LayerNorm with bias (``rms_norm_eps`` is its epsilon).  ``layer_types``
+    given (``GRANITE4_H_MICRO``): one word a PUBLISHED layer, ``mamba`` a
+    Mamba-2 mixer or ``attention`` grouped-query attention, each followed by a
+    dense SwiGLU in the same layer; muP multipliers scale every sub-layer's
+    output (``residual_multiplier``), the patch tokens (``embedding_multiplier``)
+    and the attention's scores (``attention_multiplier``).  Their defaults
+    leave the other families' programs as they were: nothing is multiplied."""
 
     hidden_size: int = 2560
     num_heads: int = 32
@@ -102,6 +108,10 @@ class DecoderConfig:
     mamba_expand: int = 2         # a Mamba-1 mixer: inner width = expand x hidden,
     mamba_d_state: int = 16       # states a channel,
     mamba_dt_rank: int = 160      # and the step size's low rank; its conv: short_conv_kernel
+    layer_types: tuple[str, ...] = ()   # () = no such rule; else mamba | attention a published layer
+    residual_multiplier: float = 1.0    # every sub-layer's output, before it joins the stream
+    embedding_multiplier: float = 1.0   # the patch tokens, which stand in for the token embedding
+    attention_multiplier: float = 0.0   # a ``gqa`` layer's softmax scale; 0 = head_dim ** -0.5
 
 
 # Nemotron-Labs-TwoTower-30B-A3B's tower (huggingface.co/nvidia/
@@ -134,14 +144,30 @@ PHI4_MINI_FLASH = DecoderConfig(
     mamba_expand=2, mamba_d_state=16, mamba_dt_rank=160,
 )
 
+# Granite 4.0-H Micro's hybrid decoder (huggingface.co/ibm-granite/
+# granite-4.0-h-micro config.json, model_type granitemoehybrid) cut to one
+# period of its pattern: published layers 0-9 (nine Mamba-2 mixers with ONE
+# group of B and C for all 64 heads, grouped-query attention without
+# positional encoding at 5), a SwiGLU of 8192 after every mixer, muP
+# multipliers.  Dense: whole layers here, the other 30 on further chips.
+GRANITE4_H_MICRO = DecoderConfig(
+    hidden_size=2048, num_heads=32, num_kv_heads=8, head_dim=64,
+    layers=tuple(range(10)),
+    layer_types=tuple("attention" if l % 10 == 5 else "mamba" for l in range(40)),
+    ssm_heads=64, ssm_head_dim=64, ssm_groups=1, ssm_state=128, short_conv_kernel=4,
+    intermediate_size=8192, rms_norm_eps=1.0e-5,
+    residual_multiplier=0.22, embedding_multiplier=12.0, attention_multiplier=0.015625,
+)
+
 # Backbone name -> the decoder blocks it holds.
 DECODER_BACKBONES = {"ling3_flash_vl": DecoderConfig(), "nemotron_twotower": NEMOTRON_TWOTOWER,
-                     "phi4_mini_flash": PHI4_MINI_FLASH}
+                     "phi4_mini_flash": PHI4_MINI_FLASH, "granite4_h_micro": GRANITE4_H_MICRO}
 
 
 @dataclass(frozen=True)
 class BackboneConfig:
     # resnet50 | resnet101 | vgg16 | ling3_flash_vl | nemotron_twotower | phi4_mini_flash
+    # | granite4_h_micro
     # (decoder blocks as a plain backbone, sized by ``decoder``)
     name: str = "resnet50"
     # Stages to freeze, counted like the reference's fixed_param_prefix
@@ -903,6 +929,18 @@ _register(
     lambda: Config(
         name="phi4_mini_flash_det",
         model=_decoder_det_model("phi4_mini_flash"),
+        data=DataConfig(dataset="coco"),
+        train=TrainConfig(per_device_batch=2),
+    ),
+)
+# Granite 4.0-H Micro's hybrid decoder (Mamba-2 mixers with one group, NoPE
+# grouped-query attention, a SwiGLU after every mixer, muP multipliers; one
+# period of 10 of its 40 layers) the same way.
+_register(
+    "granite4_h_micro_det",
+    lambda: Config(
+        name="granite4_h_micro_det",
+        model=_decoder_det_model("granite4_h_micro"),
         data=DataConfig(dataset="coco"),
         train=TrainConfig(per_device_batch=2),
     ),

@@ -8,7 +8,7 @@ emitted as a one-entry pyramid at level 4 like VGG's.
 
 A layer is a list of pre-norm residual sub-layers, ``x <- x + f(norm(x))``,
 whose kinds follow from the configuration (``config.py::DecoderConfig``,
-:func:`sublayers`), three families so far.  Ling-3.0-flash-VL's: a mixer, KDA
+:func:`sublayers`), four families so far.  Ling-3.0-flash-VL's: a mixer, KDA
 linear attention (``ops/kda.py``) except in the last layer of each group of
 ``layer_group_size``, where it is latent attention (MLA,
 ``ops/attention.py``), then a feed-forward, a dense SwiGLU below
@@ -25,7 +25,11 @@ scan (``ops/selective_scan.py``), differential attention (arXiv:2410.05258)
 under a window or over the whole prefix, and past the middle a Gated Memory
 Unit that reads the middle Mamba layer's scan result or differential cross
 attention that reads the full-attention layer's keys and values: those three
-tensors (``shared``) pass from block to block beside ``x``.
+tensors (``shared``) pass from block to block beside ``x``.  Granite 4.0-H's
+hybrid (``layer_types``): by the published word a Mamba-2 mixer or
+grouped-query attention, then a dense SwiGLU, in every layer, each
+sub-layer's output scaled by the muP ``residual_multiplier`` before it joins
+the stream.
 
 The flax module only declares the leaves (one nested name per leaf, so the
 plan's family rule, the optimizer's decay rule by leaf name and a checkpoint
@@ -53,6 +57,7 @@ from mx_rcnn_tpu.ops.selective_scan import selective_scan_chunked
 from mx_rcnn_tpu.ops.ssd import ssd_chunked
 
 PATTERN_KINDS = {"M": "ssm", "*": "gqa", "E": "moe"}
+LAYER_TYPES = {"mamba": "ssm", "attention": "gqa"}
 
 # The sorted dispatch's segment size went with the dispatch (PR 32); nothing
 # reads this.  ``tests/perfbench/_ling_tiny.py``, a benchmark file that only a
@@ -62,10 +67,13 @@ segment_rows = None
 
 def layer_kinds(cfg: DecoderConfig, layer: int) -> tuple[str, ...]:
     """The kinds of a published layer's sub-layers, in order: a letter of
-    ``pattern``; SambaY's (mixer, feed-forward) by the layer's place against
-    the published depth's middle; else Ling's (mixer, feed-forward)."""
+    ``pattern``; a word of ``layer_types`` and the feed-forward after it;
+    SambaY's (mixer, feed-forward) by the layer's place against the published
+    depth's middle; else Ling's (mixer, feed-forward)."""
     if cfg.pattern:
         return (PATTERN_KINDS[cfg.pattern[layer]],)
+    if cfg.layer_types:
+        return LAYER_TYPES[cfg.layer_types[layer]], "ffn"
     if cfg.mb_per_layer:
         middle = cfg.num_hidden_layers // 2     # the self-decoder ends at middle + 1
         if layer % cfg.mb_per_layer == 0:
@@ -378,7 +386,8 @@ def ssm_mixer(cfg: DecoderConfig, p, x, dtype):
 
 def gqa_mixer(cfg: DecoderConfig, p, x, dtype):
     """Grouped-query causal attention, no bias, no rotary embedding (the
-    family's published description: the state-space layers carry position)."""
+    family's published description: the state-space layers carry position),
+    the scores scaled by ``attention_multiplier`` where one is given."""
     b, t, _ = x.shape
     h, kv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     with jax.named_scope("proj"):
@@ -386,7 +395,7 @@ def gqa_mixer(cfg: DecoderConfig, p, x, dtype):
         k = _dense(x, p["k"], dtype, out=dtype).reshape(b, t, kv, hd)
         v = _dense(x, p["v"], dtype, out=dtype).reshape(b, t, kv, hd)
     with jax.named_scope("attn"):
-        o = causal_attention(q, k, v, hd ** -0.5, dtype=dtype)
+        o = causal_attention(q, k, v, cfg.attention_multiplier or hd ** -0.5, dtype=dtype)
     with jax.named_scope("proj"):
         return _dense(o.reshape(b, t, h * hd), p["o"], dtype)
 
@@ -551,6 +560,8 @@ def _block(cfg: DecoderConfig, layer: int, dtype, p, x, shared):
                     y, counters = moe_layer(cfg, p["moe"], normed, dtype)
                 else:
                     y, shared = MIXERS[kind](cfg, layer, p[kind], normed, dtype, shared)
+                if cfg.residual_multiplier != 1.0:
+                    y = y * cfg.residual_multiplier
                 x = x + y
     return x, counters, shared
 
@@ -583,6 +594,8 @@ def features(cfg: DecoderConfig, leaves: dict, images, dtype=jnp.bfloat16, remat
 
     with jax.named_scope("patchify"):
         x = conv(images, leaves["patchify"], cfg.patch, 0)
+        if cfg.embedding_multiplier != 1.0:
+            x = x * cfg.embedding_multiplier
     b, gh, gw, d = x.shape
     x = x.reshape(b, gh * gw, d)
     counters, shared = [], {}

@@ -696,12 +696,13 @@ def probe_selective_scan(b, t):
             "kernel_unroll": kernel.UNROLL}
 
 
-def probe_ssd(b, t):
+def probe_ssd(b, t, g=8):
     """The chunked state-space scan (``ops/ssd.py::ssd_chunked``) in both its
     forms - the chunked XLA form and the Pallas kernel pair
-    (``ops/pallas/ssd.py``, what ``ssd_chunked`` takes on a TPU) - alone at the
+    (``ops/pallas/ssd.py``, what ``ssd_chunked`` takes on a TPU) - alone at a
     state-space cell's shape, x ``bf16[2, 4200, 64, 64]``, B and C
-    ``bf16[2, 4200, 8, 128]``, chunk 128: result and the six gradients of each
+    ``bf16[2, 4200, g, 128]`` (``g`` 8 on the nemotron cell, ONE group for all
+    64 heads on the granite cell), chunk 128: result and the six gradients of each
     form with bfloat16 operands, and in float32 at ``highest`` (order of sums
     only), against the float32 token-by-token oracle (the plain reference's
     ``recurrence``, a scan of checkpointed scans: the backward of
@@ -724,7 +725,7 @@ def probe_ssd(b, t):
     from mx_rcnn_tpu.ops.ssd import CHUNK, ssd_chunked
     from perfbench.reference.backbone_nemotron_twotower import recurrence
 
-    h, p, g, n = 64, 64, 8, 128
+    h, p, n = 64, 64, 128
     ks = jax.random.split(jax.random.PRNGKey(32), 8)
     x = jax.random.normal(ks[0], (b, t, h, p))
     alike = jax.random.normal(ks[6], (b, 1, 1, p)) + 0.05 * jax.random.normal(ks[7], (b, t, 1, p))
@@ -808,7 +809,7 @@ def probe_ssd(b, t):
         and all(found["kernel_bf16"][k] <= max(1.25 * found["chunked_bf16"][k], 1e-5)
                 for k in found["kernel_bf16"])
     return {"ok": ok, "rel_l2_vs_recurrence": found, "alike_head_rel_l2": alike_found, **ms,
-            "shape": [b, t, h, p], "chunk": CHUNK, "kernel_chunk": kernel.CHUNK}
+            "shape": [b, t, h, p], "groups": g, "chunk": CHUNK, "kernel_chunk": kernel.CHUNK}
 
 
 def real_step_candidates(seed, workload="vgg16_voc07.train_b16"):
@@ -925,6 +926,7 @@ PROBES = (
     ("nms_tiled[vgg16_voc07.train_b16,seed941]", probe_nms_tiled, (941,)),
     ("kda_intra[ling3_flash_vl_det.train_coco]", probe_kda_intra, (2, 4200, 32)),
     ("ssd[nemotron_twotower_det.train_coco]", probe_ssd, (2, 4200)),
+    ("ssd[granite4_h_micro_det.train_coco,one_group]", probe_ssd, (2, 4200, 1)),
     ("flash_attention[nemotron_twotower_det.train_coco]",
      probe_flash_attention, (2, 4200, 32, 2, 128, 128)),
     ("flash_attention[ling3_flash_vl_det.train_coco]",

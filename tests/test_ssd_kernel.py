@@ -1,6 +1,7 @@
 """The Mamba-2 chunked scan as the Pallas kernel pair (ops/pallas/ssd.py),
 interpreted on the CPU at the kernel's own widths (heads of 64, states of 128,
-chunks of 128, one or two groups of eight heads): result and all six
+chunks of 128, one or two groups of eight heads, one group of 64 heads as the
+granite cell has it): result and all six
 gradients against the recurrence token by token (ops/ssd.py::ssd_recurrent),
 which is also the oracle of the chunked XLA form that stays the path of every
 other platform and shape.  What Mosaic makes of the kernels is compiled here
@@ -66,9 +67,10 @@ def _xla_form(dtype):
 
 
 # one whole chunk; a part of one; two sequences, a chunk and a part, two
-# groups; three chunks, the last ragged
+# groups; three chunks, the last ragged; one group of 64 heads (B and C shared
+# by all of them), two chunks, the last ragged
 @pytest.mark.parametrize("batch,length,heads,groups", [
-    (1, 128, 8, 1), (2, 100, 8, 1), (2, 200, 16, 2), (1, 300, 16, 2)])
+    (1, 128, 8, 1), (2, 100, 8, 1), (2, 200, 16, 2), (1, 300, 16, 2), (1, 200, 64, 1)])
 def test_the_kernel_pair_is_the_recurrence(on_the_kernel, batch, length, heads, groups):
     args, cot = _inputs(length, batch, length, heads, groups)
     assert "pallas_call" in str(jax.make_jaxpr(_f32)(*args))
@@ -185,6 +187,7 @@ def test_off_the_tpu_the_chunked_form_lowers_to_what_it_lowered_to_before(shape)
 
 @pytest.mark.parametrize("t,heads,head_dim,groups,state,chunk,taken", [
     (4200, 64, 64, 8, 128, 128, True),      # the state-space cell's
+    (4200, 64, 64, 1, 128, 128, True),      # the granite cell's: one group of 64 heads
     (1, 8, 64, 1, 128, 128, True),
     (4200, 64, 64, 8, 128, 64, False),      # another chunk is the XLA form's to choose
     (4200, 64, 128, 8, 128, 128, False),    # heads of another width
@@ -219,12 +222,8 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
-def test_mosaic_compiles_both_kernels_at_the_state_space_cell_s_shape(one_chip, dtype):
-    """x ``[2, 4200, 64 x 64]``, B and C ``[2, 4200, 8 x 128]``: 33 chunks, the
-    last of 104 positions, eight groups of eight heads.  Compiled for a
-    described v5e, run nowhere."""
-    b, t, h, g = 2, 4200, 64, 8
+def _compiles_both_kernels(one_chip, dtype, g):
+    b, t, h = 2, 4200, 64
     spec = lambda shape, kind=jnp.float32: jax.ShapeDtypeStruct(shape, kind, sharding=one_chip)
     args = [spec((b, t, h * P), dtype), spec((b, t, h)), spec((b, t, g * N), dtype),
             spec((b, t, g * N), dtype), spec((1, h)), spec((h,))]
@@ -236,6 +235,21 @@ def test_mosaic_compiles_both_kernels_at_the_state_space_cell_s_shape(one_chip, 
     compiled = jax.jit(jax.value_and_grad(loss, argnums=range(6), has_aux=True)).lower(
         *args).compile()
     assert compiled.as_text().count("tpu_custom_call") == 2   # forward + backward
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_mosaic_compiles_both_kernels_at_the_state_space_cell_s_shape(one_chip, dtype):
+    """x ``[2, 4200, 64 x 64]``, B and C ``[2, 4200, 8 x 128]``: 33 chunks, the
+    last of 104 positions, eight groups of eight heads.  Compiled for a
+    described v5e, run nowhere."""
+    _compiles_both_kernels(one_chip, dtype, 8)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_mosaic_compiles_both_kernels_at_one_group_of_64_heads(one_chip, dtype):
+    """The granite cell's shape: B and C ``[2, 4200, 1 x 128]`` read by all 64
+    heads, the gated norm's one group of 4,096 outside the kernel."""
+    _compiles_both_kernels(one_chip, dtype, 1)
 
 
 # the step's paths as the chip's trace carries them (tests/perfbench/test_step_parts.py's FWD, BWD, REMAT)
